@@ -22,6 +22,8 @@ import sys
 import tempfile
 import time
 
+REPO_ROOT = os.path.dirname(os.path.abspath(__file__))
+
 
 def run(tpu_csp, ntxs: int = 1024, endorsements: int = 2) -> dict:
     from fabric_tpu.bccsp.sw import SWProvider
@@ -52,7 +54,7 @@ def run(tpu_csp, ntxs: int = 1024, endorsements: int = 2) -> dict:
     # a per-run table build
     warm_dir = os.environ.get(
         "BENCH_WARM_DIR",
-        os.path.expanduser("~/.cache/fabric_tpu_warmkeys"))
+        os.path.join(REPO_ROOT, ".cache", "warmkeys"))
     crypto_cache = os.path.join(warm_dir, "pipeline_crypto")
     import shutil
     if os.path.isdir(crypto_cache):

@@ -172,7 +172,8 @@ def _resolve_mesh(n_devices: Optional[int]):
     the box combs its slice of the batch); 1 = no mesh, the
     single-device pipeline bit-for-bit; N>1 = the first N devices.
     Availability first: a backend that cannot even enumerate devices
-    (mid-flight libtpu upgrade, broken tunnel) degrades to the
+    (mid-flight libtpu upgrade, a chip another process holds)
+    degrades to the
     single-device path with a warning instead of failing provider
     construction — the breaker handles the rest at dispatch time.
     `requested` is the multi-device ask that was NOT satisfied (the
@@ -221,10 +222,9 @@ def new_bccsp(opts: FactoryOpts) -> BCCSP:
         from fabric_tpu.bccsp.tpu import TPUProvider
         from fabric_tpu.common import jaxenv
         # compiled verify kernels are part of the node's warm state:
-        # key the persistent XLA cache under the warm-table dir so a
-        # restart (or the next bench process) skips the ~minutes
-        # compiles along with the table rebuilds
-        jaxenv.enable_cache_under(opts.tpu.warm_keys_dir)
+        # the persistent XLA cache lets a restart (or the next bench
+        # process) skip the ~minutes compiles
+        jaxenv.enable_compilation_cache()
         mesh, unmet = _resolve_mesh(opts.tpu.n_devices)
         return TPUProvider(ks, min_batch=opts.tpu.min_batch,
                            max_blocks=opts.tpu.max_blocks, mesh=mesh,

@@ -175,12 +175,12 @@ class GatewayClient:
         req.proposed_transaction.CopyFrom(sp)
         return self._evaluate(req, timeout=self._timeout).result
 
-    def submit_transaction(self, channel_id: str, cc_name: str,
-                           args: Sequence[bytes], transient=None,
-                           endorsing_organizations: Sequence[str] = (),
-                           timeout_s: float = 30.0) -> tuple[str, int]:
-        """endorse → sign → submit → wait for commit; returns
-        (tx_id, validation_code)."""
+    def endorse(self, channel_id: str, cc_name: str,
+                args: Sequence[bytes], transient=None,
+                endorsing_organizations: Sequence[str] = ()
+                ) -> tuple[str, common.Envelope]:
+        """Gather endorsements and sign the prepared transaction;
+        returns (tx_id, signed envelope) ready for `submit`."""
         from fabric_tpu.protoutil import protoutil as pu
         sp, tx_id = self._proposal(channel_id, cc_name, args, transient)
         req = gwpb.EndorseRequest(transaction_id=tx_id,
@@ -192,18 +192,36 @@ class GatewayClient:
         # client-side signature over the prepared payload
         payload = common.Payload()
         payload.ParseFromString(prepared.payload)
-        env = pu.sign_or_panic(self._signer, payload)
+        return tx_id, pu.sign_or_panic(self._signer, payload)
+
+    def submit(self, channel_id: str, tx_id: str,
+               env: common.Envelope) -> None:
+        """Hand an endorsed, signed envelope to the ordering service."""
         sreq = gwpb.SubmitRequest(transaction_id=tx_id,
                                   channel_id=channel_id)
         sreq.prepared_transaction.CopyFrom(env)
         self._submit(sreq, timeout=self._timeout)
+
+    def commit_status(self, channel_id: str, tx_id: str,
+                      timeout_s: float = 30.0) -> int:
+        """Block until `tx_id` commits; returns its validation code."""
         inner = gwpb.CommitStatusRequest(
             transaction_id=tx_id, channel_id=channel_id,
             identity=self._signer.serialize())
         creq = gwpb.SignedCommitStatusRequest(
             request=inner.SerializeToString())
-        code = self._status(creq, timeout=timeout_s).result
-        return tx_id, code
+        return self._status(creq, timeout=timeout_s).result
+
+    def submit_transaction(self, channel_id: str, cc_name: str,
+                           args: Sequence[bytes], transient=None,
+                           endorsing_organizations: Sequence[str] = (),
+                           timeout_s: float = 30.0) -> tuple[str, int]:
+        """endorse → sign → submit → wait for commit; returns
+        (tx_id, validation_code)."""
+        tx_id, env = self.endorse(channel_id, cc_name, args, transient,
+                                  endorsing_organizations)
+        self.submit(channel_id, tx_id, env)
+        return tx_id, self.commit_status(channel_id, tx_id, timeout_s)
 
     def chaincode_events(self, channel_id: str, cc_name: str,
                          from_genesis: bool = False,

@@ -19,7 +19,7 @@ States (the strings surfaced on /healthz and the breaker_state gauge):
                           failure re-opens for another cooldown
 
 A `deadline_ms` guard runs the dispatch on a watchdog thread: a stalled
-device (wedged PCIe/tunnel, a compile that never returns) counts as a
+device (wedged PCIe link, a compile that never returns) counts as a
 failure after the deadline instead of blocking validation forever. The
 abandoned call keeps running on its daemon thread and its eventual
 result is discarded. One thread is spawned per guarded dispatch —

@@ -1,11 +1,23 @@
-"""JAX environment knobs shared by node startup and benchmarks.
+"""JAX environment knobs shared by node startup, benchmarks, tools
+and tests.
 
 The reference pays its crypto setup cost per-signature at runtime; this
-framework pays it once at XLA compile time — which BENCH_r01 measured at
-~2 minutes per batch shape on a v5e. A persistent compilation cache
-makes that a once-per-binary cost instead of once-per-process: a peer
-restart (crash recovery, upgrade) must not stall block validation for
-minutes re-compiling a kernel that has not changed.
+framework pays it once at XLA compile time, and the verify programs
+take minutes to compile (tools/chip_compile.py prints the seconds per
+program). A persistent compilation cache makes that a once-per-checkout
+cost instead of once-per-process: a peer restart (crash recovery,
+upgrade) must not stall block validation re-compiling a kernel that has
+not changed.
+
+ONE cache rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own
+handling of it is the whole story and this module sets no directory;
+otherwise the cache is ``<checkout>/.cache/xla`` (git-ignored) — the
+same directory for nodes, bench, tools, tests and chip_smoke.py. The
+path is part of a cache entry's key, so a directory that moves never
+hits. The other derived state the framework keeps across processes
+(host-built constant tables, bench warm keys) lives beside it under
+``<checkout>/.cache`` (`local_cache`): nothing is read from or written
+to the user's home directory.
 """
 
 from __future__ import annotations
@@ -15,85 +27,74 @@ import os
 
 logger = logging.getLogger("common.jaxenv")
 
-_ENV = "FABRIC_TPU_XLA_CACHE"
-_DEFAULT = os.path.join(os.path.expanduser("~"), ".cache", "fabric_tpu_xla")
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_LOCAL = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache")
+_DEFAULT = os.path.join(_LOCAL, "xla")
 _done = False
-_cache_dir: str | None = None
+
+
+def local_cache(*parts: str) -> str:
+    """A path under the checkout's git-ignored ``.cache`` directory."""
+    return os.path.join(_LOCAL, *parts)
 
 
 def cache_dir() -> str | None:
-    """The enabled persistent-compile-cache directory, or None. The
-    round-16 compile seam (common/devicecost.py) probes this dir's
-    entry count around each compile: a cold compile WRITES an entry,
-    a warm load only reads — the cache-hit-vs-miss signal."""
-    return _cache_dir
+    """The persistent-compile-cache directory once enabled, or None.
+    The compile seam (common/devicecost.py) probes this dir's entry
+    count around each compile: a cold compile WRITES an entry, a warm
+    load only reads — the cache-hit-vs-miss signal."""
+    if not _done:
+        return None
+    return os.environ.get(_ENV, _DEFAULT) or None
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Point jax at a persistent on-disk compilation cache.
-
-    Resolution order: explicit arg > $FABRIC_TPU_XLA_CACHE > ~/.cache.
-    Setting the env var to an empty string disables the cache. Safe to
-    call repeatedly; must run before the first jit compilation to help.
-    """
-    global _done, _cache_dir
+def enable_compilation_cache() -> str | None:
+    """Turn on JAX's persistent on-disk compilation cache (see the
+    module docstring for where it lives). Safe to call repeatedly;
+    must run before the first jit compilation to help."""
+    global _done
     if _done:
-        return None
-    cache = path if path is not None else os.environ.get(_ENV, _DEFAULT)
-    if not cache:
-        return None
+        return cache_dir()
     try:
         import jax
 
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
+        if os.environ.get(_ENV) is None:
+            os.makedirs(_DEFAULT, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", _DEFAULT)
         # cache every program regardless of compile time or size
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         _done = True
-        _cache_dir = cache
-        logger.info("XLA compilation cache at %s", cache)
-        return cache
+        logger.info("XLA compilation cache at %s", cache_dir())
+        return cache_dir()
     except Exception:
         logger.exception("could not enable the XLA compilation cache")
         return None
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable `shard_map` wrapper for the sharded verify
-    pipeline.
-
-    jax >= 0.6 exposes `jax.shard_map(..., check_vma=)`; the 0.4.x
-    line this container ships has only
-    `jax.experimental.shard_map.shard_map(..., check_rep=)`. Either
-    way replication checking is disabled: the flagship comb pipeline
-    contains a pallas_call custom call the checker cannot see
-    through, and the tables really are replicated by construction
+    """`jax.shard_map` for the sharded verify pipeline, with
+    replication checking off: the comb pipeline may contain a
+    pallas_call custom call the checker cannot see through, and the
+    tables really are replicated by construction
     (`TPUProvider._resolve_tables` places them with an empty
     PartitionSpec)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _esm
-    return _esm(fn, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pallas_interpret() -> bool:
     """Whether Pallas programs should run under ``interpret=True``.
 
-    The wheel-free CI environment has no Mosaic backend, so every
-    Pallas kernel (ops/ptree.py, ops/fused_verify.py) runs interpreted
-    there — same program, traced through XLA on CPU — and compiles for
-    real only when a TPU backend is actually attached. FTPU_PALLAS_
-    INTERPRET=0/1 overrides the autodetect for A/B runs on real chips.
+    On a CPU backend (tier-1 tests, rehearsals) every Pallas kernel
+    (ops/ptree.py, ops/fused_verify.py) runs interpreted — same
+    program, traced through XLA on CPU — and compiles through Mosaic
+    only when a TPU backend is attached. FTPU_PALLAS_INTERPRET=0/1
+    overrides the autodetect for A/B runs on real chips.
     """
     # ftpu-check: allow-retrace(compile-time config by design: the
     # interpret flag is pinned for the process, read once per trace)
@@ -103,17 +104,3 @@ def pallas_interpret() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
-
-
-def enable_cache_under(warm_dir: str | None) -> str | None:
-    """Key the persistent compilation cache under a provider's warm
-    state directory (``<warm_dir>/xla_cache``) so the ~minutes kernel
-    compiles are paid once per MACHINE, not once per process — compiled
-    programs live beside the warm Q-table bytes they serve.
-
-    An explicit $FABRIC_TPU_XLA_CACHE (including the empty string,
-    which disables caching) still wins; with no warm dir this falls
-    back to the ~/.cache default."""
-    if os.environ.get(_ENV) is not None or not warm_dir:
-        return enable_compilation_cache()
-    return enable_compilation_cache(os.path.join(warm_dir, "xla_cache"))

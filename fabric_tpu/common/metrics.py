@@ -101,6 +101,14 @@ BCCSP_PIPELINE_OVERLAP_RATIO_OPTS = GaugeOpts(
          "in the most recent overlapped verify batch: 0 = fully "
          "serial, (chunks-1)/chunks = fully pipelined.")
 
+BCCSP_DEVICE_INFO_OPTS = GaugeOpts(
+    namespace="bccsp", subsystem="device", name="info",
+    help="Device count of the JAX backend the TPU verify provider "
+         "runs on, labeled with the platform and device kind JAX "
+         "reports (the breaker's `device` health state reads the same "
+         "on a CPU backend; this series tells them apart).",
+    label_names=("platform", "device_kind"))
+
 BCCSP_SHARD_DEVICES_OPTS = GaugeOpts(
     namespace="bccsp", subsystem="shard", name="devices",
     help="Device-mesh size the TPU verify provider shards the batch "

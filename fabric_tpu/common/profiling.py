@@ -286,6 +286,19 @@ def publish_provider_stats(metrics_provider, csp, poll_s: float = 5.0):
             }
         except Exception:
             scheme_gauges = None
+    # which backend serves: bccsp_device_info{platform,device_kind}
+    device_info = getattr(csp, "device_info", None)
+    if callable(device_info):
+        try:
+            d = device_info()
+            metrics_provider.new_gauge(
+                metrics_mod.BCCSP_DEVICE_INFO_OPTS).with_labels(
+                    "platform", d["platform"],
+                    "device_kind", d["device_kind"]).set(
+                        float(d["count"]))
+        except Exception as e:          # noqa: BLE001
+            logger.warning("bccsp device info gauge publish failed: "
+                           "%s", e)
     breaker = getattr(csp, "_breaker", None)
     fallback_state = fallback_trips = None
     if breaker is not None:

@@ -47,8 +47,7 @@ class OrdererNode:
     def start(self) -> None:
         cfg = self.cfg
         from fabric_tpu.common import jaxenv
-        jaxenv.enable_compilation_cache(
-            cfg.get("General.XLACompilationCacheDir"))
+        jaxenv.enable_compilation_cache()
         provider = metrics_mod.provider_from_config(
             cfg.get("Metrics.Provider", "prometheus"),
             statsd_address=cfg.get("Metrics.Statsd.Address",

@@ -86,10 +86,9 @@ class PeerNode:
     def start(self) -> None:
         cfg = self.cfg
         # persistent XLA cache: a restarting peer must not recompile the
-        # verify kernel before its first big block (BENCH_r01: ~2 min)
+        # verify programs (minutes) before its first big block
         from fabric_tpu.common import jaxenv
-        jaxenv.enable_compilation_cache(
-            cfg.get("peer.xlaCompilationCacheDir"))
+        jaxenv.enable_compilation_cache()
         provider = metrics_mod.provider_from_config(
             cfg.get("metrics.provider", "prometheus"),
             statsd_address=cfg.get("metrics.statsd.address",
@@ -285,6 +284,14 @@ class PeerNode:
         health = getattr(csp, "health", None)
         if callable(health):
             self.ops.register_checker("bccsp", health)
+        # ...and WHICH backend that `device` is: platform:kind:count
+        # as JAX reports it (a TPU provider on a CPU-only JAX also
+        # answers `device`)
+        device_info = getattr(csp, "device_info", None)
+        if callable(device_info):
+            self.ops.register_checker(
+                "bccsp_device", lambda: "{platform}:{device_kind}:"
+                "{count}".format(**device_info()))
         # overload state (ok | shedding:<stages>): shedding is
         # degraded-but-serving — load past capacity refused cleanly,
         # never a failed health check

@@ -112,17 +112,19 @@ def g_tables() -> np.ndarray:
 
     Entry j=0 is the point at infinity (0 : 1 : 0). Built once over
     Python ints (exact), lru-cached in process and persisted to
-    $FABRIC_TPU_GTAB_CACHE (default ~/.cache/fabric_tpu/gtab8.npy,
+    $FABRIC_TPU_GTAB_CACHE (default <checkout>/.cache/gtab8.npy,
     empty string disables) — the 8k host bigint point ops are a
     measurable slice of restart-to-first-validated-block, and G is a
     universal constant."""
     import os
+
+    from fabric_tpu.common import jaxenv
     # ftpu-check: allow-retrace(compile-time config by design: the G
     # table cache path is pinned for the process and only gates a
     # host-side np.load, never a traced value)
     cache = os.environ.get(
         "FABRIC_TPU_GTAB_CACHE",
-        os.path.expanduser("~/.cache/fabric_tpu/gtab8.npy"))
+        jaxenv.local_cache("gtab8.npy"))
     if cache:
         try:
             if verify_digest_sidecar(cache) is not False:
@@ -187,20 +189,33 @@ def g16_tables():
 
         g8 = jnp.asarray(g_tables())        # (32*256, 3, L)
 
-        def build(g8):
-            idx = jnp.arange(NENT_G16, dtype=jnp.int32)
-            lo, hi = idx & 255, idx >> 8
-            outs = []
-            for i in range(NWIN_G16):
-                a = jnp.take(g8, (2 * i) * NENT + lo, axis=0)
-                b = jnp.take(g8, (2 * i + 1) * NENT + hi, axis=0)
-                X, Y, Z = cadd((a[:, 0], a[:, 1], a[:, 2]),
-                               (b[:, 0], b[:, 1], b[:, 2]))
-                outs.append(jnp.stack([X, Y, Z], axis=1))
-            return jnp.concatenate(outs, axis=0)
-
-        _g16_cache.append(jax.jit(build)(g8))
+        _g16_cache.append(jax.jit(_combine_windows)(
+            g8, jnp.arange(NWIN_G16, dtype=jnp.int32) * 2 * NENT,
+            NENT))
         return _g16_cache[0]
+
+
+def _combine_windows(t8, base, stride: int):
+    """Pairwise 8-bit -> 16-bit window combining, one table row-block
+    per scan step: out[b*65536 + j] = t8[base[b] + (j & 255)]
+                                     + t8[base[b] + stride + (j >> 8)].
+
+    A `lax.map` over the blocks, NOT a Python loop: the program holds
+    ONE complete-add body whatever the block count. Unrolled, the
+    TPU compiler took minutes per table (16 bodies for G, 16*K for a
+    key set; tools/chip_compile.py) for a program that runs once."""
+    idx = jnp.arange(NENT_G16, dtype=jnp.int32)
+    lo, hi = idx & 255, idx >> 8
+
+    def block(b0):
+        a = jnp.take(t8, b0 + lo, axis=0)
+        b = jnp.take(t8, b0 + stride + hi, axis=0)
+        X, Y, Z = cadd((a[:, 0], a[:, 1], a[:, 2]),
+                       (b[:, 0], b[:, 1], b[:, 2]))
+        return jnp.stack([X, Y, Z], axis=1)
+
+    out = lax.map(block, base)              # (blocks, 65536, 3, L)
+    return out.reshape(-1, 3, L)
 
 
 # ---------------------------------------------------------------------------
@@ -217,18 +232,10 @@ def build_q16_tables(q_flat, K: int):
     makes this a once-per-channel-config cost, not a per-block one.
     Layout: flat16[(i * K + k) * 65536 + j].
     """
-    idx = jnp.arange(NENT_G16, dtype=jnp.int32)
-    lo, hi = idx & 255, idx >> 8
-    outs = []
-    for i in range(NWIN_G16):
-        for k in range(K):
-            a = jnp.take(q_flat, ((2 * i) * K + k) * NENT + lo, axis=0)
-            b = jnp.take(q_flat, ((2 * i + 1) * K + k) * NENT + hi,
-                         axis=0)
-            X, Y, Z = cadd((a[:, 0], a[:, 1], a[:, 2]),
-                           (b[:, 0], b[:, 1], b[:, 2]))
-            outs.append(jnp.stack([X, Y, Z], axis=1))
-    return jnp.concatenate(outs, axis=0)
+    i = jnp.arange(NWIN_G16, dtype=jnp.int32)[:, None]
+    k = jnp.arange(K, dtype=jnp.int32)[None, :]
+    base = (((2 * i) * K + k) * NENT).reshape(-1)   # i-major, k-minor
+    return _combine_windows(q_flat, base, K * NENT)
 
 
 def build_q_tables(qx, qy):
@@ -245,12 +252,14 @@ def build_q_tables(qx, qy):
     zeros = jnp.zeros((K, L), dtype=jnp.int32)
     q1 = (qx, qy, ones)
 
-    def dbl8(pt, _):
-        for _ in range(WBITS):
-            pt = cdbl(pt)
+    def dbl(pt, _):
+        pt = cdbl(pt)
         return pt, pt
 
-    _, shifted = lax.scan(dbl8, q1, None, length=NWIN - 1)
+    # one doubling per step (ONE cdbl body to compile, not WBITS of
+    # them); every WBITS-th result is a window base
+    _, doubled = lax.scan(dbl, q1, None, length=WBITS * (NWIN - 1))
+    shifted = tuple(d[WBITS - 1::WBITS] for d in doubled)
     # bases: (NWIN, K, L) per coordinate
     bases = tuple(
         jnp.concatenate([q1[c][None], shifted[c]], axis=0) for c in range(3)

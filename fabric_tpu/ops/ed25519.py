@@ -138,14 +138,15 @@ def b_tables() -> np.ndarray:
     Montgomery-domain extended coordinates (Z = mont(1), T = X*Y).
     Entry j=0 is the identity. Built once over Python ints (exact),
     persisted beside the G tables ($FABRIC_TPU_EDTAB_CACHE, default
-    ~/.cache/fabric_tpu/edtab8.npy, empty string disables) with the
+    <checkout>/.cache/edtab8.npy, empty string disables) with the
     same sha256 sidecar/verify-on-load/rebuild contract as
     `comb.g_tables` — a corrupt table must rebuild, never feed the
     kernel wrong points."""
     import os
+
+    from fabric_tpu.common import jaxenv
     cache = os.environ.get(
-        "FABRIC_TPU_EDTAB_CACHE",
-        os.path.expanduser("~/.cache/fabric_tpu/edtab8.npy"))
+        "FABRIC_TPU_EDTAB_CACHE", jaxenv.local_cache("edtab8.npy"))
     if cache:
         try:
             if comb.verify_digest_sidecar(cache) is not False:

@@ -56,10 +56,8 @@ LANE_ALIGN = ptree.LANE_ALIGN
 
 # VMEM byte budget for the resident variant's pinned tables: the g8 +
 # q8 comb tables cost ~1.97 MB per key slot, so 64 MB holds ~31 keys
-# with working-set headroom inside the 100 MB compiler limit below.
+# with working-set headroom inside ptree.VMEM_LIMIT.
 RESIDENT_TABLE_BUDGET = 64 * 1024 * 1024
-
-_VMEM_LIMIT = 100 * 1024 * 1024
 
 
 @functools.lru_cache(maxsize=None)
@@ -70,12 +68,21 @@ def _fnk() -> ptree.KMod:
 
 
 def _sha_consts() -> np.ndarray:
-    """(72, 1) uint32: the 64 SHA-256 round constants followed by the
-    8 initial state words. Pallas kernels may not close over array
-    constants, so these ride a pinned input (same pattern as
-    KMod.pack_consts)."""
-    return np.concatenate([np.asarray(sha256._K).reshape(64, 1),
-                           np.asarray(sha256._H0).reshape(8, 1)])
+    """(64,) uint32 SHA-256 round constants. Pallas kernels may not
+    close over array constants, so these ride an SMEM input (scalar
+    loads; the round index is the one dynamic index in the kernel)."""
+    return np.asarray(sha256._K).reshape(64)
+
+
+def _sha_h0(S: int) -> np.ndarray:
+    """(8, S, 128) uint32: the initial state words, one lane tile
+    each, as a pinned VMEM input. NOT splatted in the kernel: a
+    splat's replicated layout as the round loop's initial carry is a
+    relayout Mosaic refuses ("replicated in destination but not in
+    source")."""
+    return np.broadcast_to(
+        np.asarray(sha256._H0, dtype=np.uint32).reshape(8, 1, 1),
+        (8, S, LANE_ALIGN)).copy()
 
 
 # ---------------------------------------------------------------------------
@@ -87,52 +94,46 @@ def _rotr(x, n: int):
     return (x >> jnp.uint32(n)) | (x << jnp.uint32(32 - n))
 
 
-def _compress_rows(state, block, kc):
-    """One SHA-256 compression over a lane tile, limb-leading layout.
+def _compress_rows(state, block, k_at):
+    """One SHA-256 compression over a lane tile, word-leading layout.
 
-    state: (8, *t) uint32 rows; block: (16, *t) uint32 message words;
-    kc: (64, 1) uint32 round constants (a kernel input — see
-    _sha_consts). Mirrors sha256._compress exactly (same scan
-    structure — see the module docstring for why the rounds must NOT
-    unroll), but keeps every register as a (1, *t) row so the VPU
-    sees 2-D tiles.
+    state: 8 uint32 tiles; block: 16 uint32 message-word tiles;
+    k_at(t): round constant t (traced index). Same arithmetic as
+    sha256._compress, restructured for Mosaic, which lowers only
+    `fori_loop`-shaped scans (a carry, no scanned inputs/outputs): ONE
+    loop of 64 rounds whose carry is the 8 working registers plus a
+    rolling 16-word schedule window — round t consumes win[0] and
+    appends W[t+16]. Every index is static except k_at's. The rounds
+    must NOT unroll: XLA's fusion search (interpret mode on CPU) blows
+    up past ~24 unrolled rounds.
     """
 
-    def sched_step(win, _):
-        wm15 = win[1:2]
-        wm2 = win[14:15]
-        s0 = _rotr(wm15, 7) ^ _rotr(wm15, 18) ^ (wm15 >> jnp.uint32(3))
-        s1 = _rotr(wm2, 17) ^ _rotr(wm2, 19) ^ (wm2 >> jnp.uint32(10))
-        wt = win[0:1] + s0 + win[9:10] + s1
-        nxt = jnp.concatenate([win[1:], wt], axis=0)
-        return nxt, win[0:1]
-
-    win, w_early = lax.scan(sched_step, block, None, length=48)
-    w_all = jnp.concatenate([w_early, win[:, None]], axis=0)  # (64,1,*t)
-
-    def round_step(regs, inp):
-        a, b, c, d, e, f, g, h = regs
-        wt, kt = inp
+    def round_step(t, carry):
+        (a, b, c, d, e, f, g, h), win = carry
         s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
         ch = (e & f) ^ (~e & g)
-        t1 = h + s1 + ch + kt + wt
+        t1 = h + s1 + ch + k_at(t) + win[0]
         s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
         maj = (a & b) ^ (a & c) ^ (b & c)
-        t2 = s0 + maj
-        return (t1 + t2, a, b, c, d + t1, e, f, g), None
+        wm15, wm2 = win[1], win[14]
+        g0 = _rotr(wm15, 7) ^ _rotr(wm15, 18) ^ (wm15 >> jnp.uint32(3))
+        g1 = _rotr(wm2, 17) ^ _rotr(wm2, 19) ^ (wm2 >> jnp.uint32(10))
+        nxt = win[0] + g0 + win[9] + g1
+        return ((t1 + s0 + maj, a, b, c, d + t1, e, f, g),
+                win[1:] + (nxt,))
 
-    regs0 = tuple(state[i:i + 1] for i in range(8))
-    regs, _ = lax.scan(round_step, regs0, (w_all, kc))
-    return state + jnp.concatenate(regs, axis=0)
+    regs, _ = lax.fori_loop(0, 64, round_step,
+                            (tuple(state), tuple(block)))
+    return tuple(s + r for s, r in zip(state, regs))
 
 
 def _words_to_limbs_rows(words):
-    """(8, *t) big-endian uint32 digest rows -> (L, *t) int32 limbs.
+    """8 big-endian uint32 digest word tiles -> (L, *t) int32 limbs.
 
     The leading-axis twin of limb.words_be_to_limbs — same static
     bit-position bookkeeping, word index on axis 0.
     """
-    le = words[::-1]
+    le = [words[7 - j] for j in range(8)]
     rows = []
     for i in range(L):
         bit0 = W * i
@@ -164,29 +165,32 @@ def _windows_rows(u, wbits: int):
     return jnp.stack(rows, axis=0)
 
 
-def _sha_scalar_rows(F, shc, blk, nb_live, digests, has_digest, r, w,
-                     nb: int):
+def _sha_scalar_rows(F, k_at, h0, blk, nb_live, digests, has_digest,
+                     r, w, nb: int):
     """SHA + mod-n scalar derivation for one lane tile.
 
-    shc: the (72, 1) _sha_consts value read from a kernel input; blk:
-    (nb*16, bb) uint32 padded message blocks; nb_live: (1, bb)
-    int32 per-lane block count (0 for digest-only lanes); digests:
-    (8, bb) uint32 precomputed digest words; has_digest: (1, bb) int32;
-    r, w: (L, bb) int32 canonical limbs. Returns (words, u1, u2).
+    Tiles are (S, 128) = BLOCK_B lanes, leading axes are word / limb
+    indices (untiled, compile-time — the ops/ptree.py layout). k_at:
+    SHA round constant t; h0: (8, S, 128) initial state tiles; blk: (nb*16, S, 128) uint32 padded message blocks;
+    nb_live: (S, 128) int32 per-lane block count (0 for digest-only
+    lanes); digests: (8, S, 128) uint32 precomputed digest words;
+    has_digest: (S, 128) int32; r, w: (L, S, 128) int32 canonical
+    limbs. Returns (words (8, S, 128), u1, u2).
 
     The block loop is a STATIC Python loop with a masked state update
     (exactly sha256.sha256_blocks' fori_loop semantics) — Mosaic has
-    no dynamic leading-axis slicing, and nb is tiny (messages bucket
-    to a handful of 64-byte blocks).
+    no dynamic leading-axis slicing of values, and nb is tiny
+    (messages bucket to a handful of 64-byte blocks).
     """
-    bb = blk.shape[-1]
-    kc, h0 = shc[:64], shc[64:]
-    state = jnp.broadcast_to(h0, (8, bb))
+    state = tuple(h0[i] for i in range(8))
     for j in range(nb):
-        nxt = _compress_rows(state, blk[16 * j:16 * (j + 1)], kc)
+        nxt = _compress_rows(
+            state, [blk[16 * j + i] for i in range(16)], k_at)
         live = jnp.int32(j) < nb_live
-        state = jnp.where(live, nxt, state)
-    words = jnp.where(has_digest != 0, digests, state)
+        state = tuple(jnp.where(live, n, s)
+                      for n, s in zip(nxt, state))
+    words = jnp.stack([jnp.where(has_digest != 0, digests[i], state[i])
+                       for i in range(8)], axis=0)
     e = _words_to_limbs_rows(words)
     u1 = F.canonical(F.mulmod(e, w))
     u2 = F.canonical(F.mulmod(r, w))
@@ -197,18 +201,26 @@ def _sha_scalar_rows(F, shc, blk, nb_live, digests, has_digest, r, w,
 # Stage-A kernels: SHA-256 + scalar derivation + window extraction
 # ---------------------------------------------------------------------------
 
-def _sha_kernel(nb, wbits_g, wbits_q, consts, shc, blocks, nblocks,
-                digests, has_digest, r, w, w1_out, w2_out, d_out):
+def _sha_tile(nb, wbits_g, wbits_q, consts, shc, h0, blk, nblocks,
+              digests, has_digest, r, w, w1_out, w2_out, d_out):
+    """Shared body of the two stage-A kernels; `blk` is the
+    (nb*16, S, 128) message tile, wherever it came from. shc is the
+    (64,) SMEM ref of _sha_consts: scalar loads, the round index the
+    only dynamic one."""
     F = _fnk().bind(consts[:])
     words, u1, u2 = _sha_scalar_rows(
-        F, shc[:], blocks[0], nblocks[0], digests[0], has_digest[0],
-        r[0], w[0], nb)
+        F, lambda t: shc[t], h0[:], blk,
+        nblocks[0, 0], digests[0], has_digest[0, 0], r[0], w[0], nb)
     d_out[0] = words
     w1_out[0] = _windows_rows(u1, wbits_g)
     w2_out[0] = _windows_rows(u2, wbits_q)
 
 
-def _sha_kernel_dma(nb, wbits_g, wbits_q, consts, shc, blocks_hbm,
+def _sha_kernel(nb, wbits_g, wbits_q, consts, shc, h0, blocks, *rest):
+    _sha_tile(nb, wbits_g, wbits_q, consts, shc, h0, blocks[0], *rest)
+
+
+def _sha_kernel_dma(nb, wbits_g, wbits_q, consts, shc, h0, blocks_hbm,
                     nblocks, digests, has_digest, r, w, w1_out, w2_out,
                     d_out, blk_vmem, dma_sem):
     """The streaming variant: `blocks` stays in HBM (memory_space=ANY)
@@ -238,14 +250,9 @@ def _sha_kernel_dma(nb, wbits_g, wbits_q, consts, shc, blocks_hbm,
 
     pltpu.make_async_copy(blocks_hbm.at[i], blk_vmem.at[slot],
                           dma_sem.at[slot]).wait()
-
-    F = _fnk().bind(consts[:])
-    words, u1, u2 = _sha_scalar_rows(
-        F, shc[:], blk_vmem[slot], nblocks[0], digests[0],
-        has_digest[0], r[0], w[0], nb)
-    d_out[0] = words
-    w1_out[0] = _windows_rows(u1, wbits_g)
-    w2_out[0] = _windows_rows(u2, wbits_q)
+    _sha_tile(nb, wbits_g, wbits_q, consts, shc, h0, blk_vmem[slot],
+              nblocks, digests, has_digest, r, w, w1_out, w2_out,
+              d_out)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -253,18 +260,21 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _lead(v, g: int, bb: int):
-    """(B, rows) -> (g, rows, bb): batch-major flat order per block
-    (lane b of grid block i is batch index i*bb + b) — the scal
-    staging pattern of ptree.tree_verify_points."""
+    """(B, rows) -> (g, rows, bb//128, 128): batch-major flat order
+    per block (lane b of grid block i is batch index i*bb + b), the
+    block's lanes folded into a (sublane, lane) tile so the row axis
+    is an untiled leading axis in the kernel — limb shifts, carries
+    and pads along it are register renames (the ops/ptree.py
+    layout), never sublane data movement."""
     rows = v.shape[1]
-    return jnp.transpose(v, (1, 0)).reshape(rows, g, bb) \
-              .transpose(1, 0, 2)
+    return jnp.transpose(v, (1, 0)).reshape(
+        rows, g, bb // LANE_ALIGN, LANE_ALIGN).transpose(1, 0, 2, 3)
 
 
 def _unlead(v, Bp: int, B: int):
-    """(g, rows, bb) -> (B, rows): inverse of _lead."""
+    """(g, rows, bb//128, 128) -> (B, rows): inverse of _lead."""
     rows = v.shape[1]
-    return jnp.transpose(v, (1, 0, 2)).reshape(rows, Bp) \
+    return jnp.transpose(v, (1, 0, 2, 3)).reshape(rows, Bp) \
               .transpose(1, 0)[:B]
 
 
@@ -319,23 +329,27 @@ def sha_windows(blocks, nblocks, digests, has_digest, r_l, w_l, *,
     w_t = _lead(w_l, g, bb)
 
     consts = jnp.asarray(_fnk().pack_consts()).reshape(
-        ptree.KMod.NCONST, L, 1)
+        ptree.KMod.NCONST, L, 1, 1)
     shc = jnp.asarray(_sha_consts())
     n1, n2 = 256 // wbits_g, 256 // wbits_q
+    S = bb // LANE_ALIGN
 
     def spec(rows):
-        return pl.BlockSpec((1, rows, bb), lambda i: (i, 0, 0),
+        return pl.BlockSpec((1, rows, S, LANE_ALIGN),
+                            lambda i: (i, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    cspec = pl.BlockSpec((ptree.KMod.NCONST, L, 1),
-                         lambda i: (0, 0, 0), memory_space=pltpu.VMEM)
-    shspec = pl.BlockSpec((72, 1), lambda i: (0, 0),
+    cspec = pl.BlockSpec((ptree.KMod.NCONST, L, 1, 1),
+                         lambda i: (0, 0, 0, 0),
+                         memory_space=pltpu.VMEM)
+    shspec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    h0spec = pl.BlockSpec((8, S, LANE_ALIGN), lambda i: (0, 0, 0),
                           memory_space=pltpu.VMEM)
     if dma:
         kernel = functools.partial(_sha_kernel_dma, NB, wbits_g,
                                    wbits_q)
-        blk_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        scratch = [pltpu.VMEM((2, NB16, bb), jnp.uint32),
+        blk_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((2, NB16, S, LANE_ALIGN), jnp.uint32),
                    pltpu.SemaphoreType.DMA((2,))]
     else:
         kernel = functools.partial(_sha_kernel, NB, wbits_g, wbits_q)
@@ -345,17 +359,19 @@ def sha_windows(blocks, nblocks, digests, has_digest, r_l, w_l, *,
     w1, w2, dwords = pl.pallas_call(
         kernel,
         grid=(g,),
-        in_specs=[cspec, shspec, blk_spec, spec(1), spec(8), spec(1),
-                  spec(L), spec(L)],
+        in_specs=[cspec, shspec, h0spec, blk_spec, spec(1), spec(8),
+                  spec(1), spec(L), spec(L)],
         out_specs=[spec(n1), spec(n2), spec(8)],
-        out_shape=[jax.ShapeDtypeStruct((g, n1, bb), jnp.int32),
-                   jax.ShapeDtypeStruct((g, n2, bb), jnp.int32),
-                   jax.ShapeDtypeStruct((g, 8, bb), jnp.uint32)],
+        out_shape=[
+            jax.ShapeDtypeStruct((g, n1, S, LANE_ALIGN), jnp.int32),
+            jax.ShapeDtypeStruct((g, n2, S, LANE_ALIGN), jnp.int32),
+            jax.ShapeDtypeStruct((g, 8, S, LANE_ALIGN), jnp.uint32)],
         scratch_shapes=scratch,
-        compiler_params=ptree.compiler_params(
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=ptree.VMEM_LIMIT),
         interpret=interpret,
-    )(consts, shc, blk_t, nb_t, dig_t, hd_t, r_t, w_t)
+    )(consts, shc, jnp.asarray(_sha_h0(S)), blk_t, nb_t, dig_t, hd_t,
+      r_t, w_t)
     return (_unlead(w1, Bp, B), _unlead(w2, Bp, B),
             _unlead(dwords, Bp, B))
 
@@ -439,20 +455,22 @@ def resident_table_bytes(K: int) -> int:
     return comb.NWIN * comb.NENT * (1 + K) * 3 * L * 4
 
 
-def _resident_kernel(nb, K, consts_n, consts_p, shc, g_tab, q_tab,
+def _resident_kernel(nb, K, consts_n, consts_p, shc, h0, g_tab, q_tab,
                      blocks, nblocks, digests, has_digest, key_idx,
                      r, rpn, w, pm, out):
     Fn = _fnk().bind(consts_n[:])
     Fp = ptree._fpk().bind(consts_p[:])
     _, u1, u2 = _sha_scalar_rows(
-        Fn, shc[:], blocks[0], nblocks[0], digests[0], has_digest[0],
+        Fn, lambda t: shc[t], h0[:],
+        blocks[0], nblocks[0, 0], digests[0], has_digest[0, 0],
         r[0], w[0], nb)
-    bb = r.shape[-1]
-    w1 = _windows_rows(u1, comb.WBITS)          # (NWIN, bb)
-    w2 = _windows_rows(u2, comb.WBITS)
+    bb = r.shape[-2] * r.shape[-1]
+    # (rows, S, 128) lane tiles -> flat (rows, bb) for the gather
+    w1 = _windows_rows(u1, comb.WBITS).reshape(comb.NWIN, bb)
+    w2 = _windows_rows(u2, comb.WBITS).reshape(comb.NWIN, bb)
     win = lax.broadcasted_iota(jnp.int32, (comb.NWIN, bb), 0)
     g_pts = jnp.take(g_tab[:], win * comb.NENT + w1, axis=0)
-    q_idx = (win * K + key_idx[0]) * comb.NENT + w2
+    q_idx = (win * K + key_idx[0].reshape(1, bb)) * comb.NENT + w2
     q_pts = jnp.take(q_tab[:], q_idx, axis=0)
     pts = jnp.concatenate([g_pts, q_pts], axis=0)  # (M, bb, 3L)
     M = 2 * comb.NWIN
@@ -523,17 +541,18 @@ def fused_verify_resident(blocks, nblocks, key_idx, q_flat, r8, rpn8,
     r_t = _lead(r_l, g, bb)
     rpn_t = _lead(rpn_l, g, bb)
     w_t = _lead(w_l, g, bb)
-    pm_t = premask.astype(jnp.int32).reshape(g, 1, bb)
+    pm_t = _lead(premask.astype(jnp.int32).reshape(Bp, 1), g, bb)
 
     consts_n = jnp.asarray(_fnk().pack_consts()).reshape(
-        ptree.KMod.NCONST, L, 1)
+        ptree.KMod.NCONST, L, 1, 1)
     consts_p = jnp.asarray(ptree._fpk().pack_consts()).reshape(
         ptree.KMod.NCONST, L, 1, 1)
     M = 2 * comb.NWIN
     ts, tr = ptree._collapse_tile(M, bb)
 
     def spec(rows):
-        return pl.BlockSpec((1, rows, bb), lambda i: (i, 0, 0),
+        return pl.BlockSpec((1, rows, bb // LANE_ALIGN, LANE_ALIGN),
+                            lambda i: (i, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
     def pinned(shape):
@@ -544,9 +563,10 @@ def fused_verify_resident(blocks, nblocks, key_idx, q_flat, r8, rpn8,
     out = pl.pallas_call(
         functools.partial(_resident_kernel, NB, K),
         grid=(g,),
-        in_specs=[pinned((ptree.KMod.NCONST, L, 1)),
+        in_specs=[pinned((ptree.KMod.NCONST, L, 1, 1)),
                   pinned((ptree.KMod.NCONST, L, 1, 1)),
-                  pinned((72, 1)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pinned((8, bb // LANE_ALIGN, LANE_ALIGN)),
                   pinned(tuple(g_tab.shape)),
                   pinned(tuple(q_tab.shape)),
                   spec(NB16), spec(1), spec(8), spec(1), spec(1),
@@ -554,9 +574,10 @@ def fused_verify_resident(blocks, nblocks, key_idx, q_flat, r8, rpn8,
         out_specs=pl.BlockSpec((1, ts, tr), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((g, ts, tr), jnp.int32),
-        compiler_params=ptree.compiler_params(
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=ptree.VMEM_LIMIT),
         interpret=interpret,
-    )(consts_n, consts_p, jnp.asarray(_sha_consts()), g_tab, q_tab,
+    )(consts_n, consts_p, jnp.asarray(_sha_consts()),
+      jnp.asarray(_sha_h0(bb // LANE_ALIGN)), g_tab, q_tab,
       blk_t, nb_t, dig_t, hd_t, ki_t, r_t, rpn_t, w_t, pm_t)
     return out.reshape(Bp)[:B] != 0

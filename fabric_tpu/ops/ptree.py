@@ -40,6 +40,7 @@ from fabric_tpu.ops import limb, p256
 from fabric_tpu.ops.limb import L, MASK, W
 
 BLOCK_B = 512               # batch lanes per kernel program
+VMEM_LIMIT = 100 * 1024 * 1024      # Mosaic scoped-VMEM cap per program
 
 # Lane-count granule for callers that slice a batch into dispatch
 # chunks (the provider's overlapped verify pipeline): chunks aligned
@@ -414,8 +415,8 @@ def tree_verify_points(pts, r_l, rpn_l, premask, *, interpret=None,
         out_specs=pl.BlockSpec((1, ts, tr), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((g, ts, tr), jnp.int32),
-        compiler_params=compiler_params(
-            vmem_limit_bytes=100 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(consts, px, py, pz, r_t, rpn_t, pm_t)
     return out.reshape(Bp)[:B] != 0
@@ -423,17 +424,6 @@ def tree_verify_points(pts, r_l, rpn_l, premask, *, interpret=None,
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
-
-
-def compiler_params(**kw):
-    """Version-portable Mosaic compiler params: jax >= 0.5 renamed
-    `TPUCompilerParams` to `CompilerParams`; the 0.4.x line in the
-    wheel-free container only has the old name."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cp = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return cp(**kw)
 
 
 def _collapse_tile(M: int, B: int):

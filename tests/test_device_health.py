@@ -567,8 +567,8 @@ class TestDegradedStartupHealth:
 
     def test_factory_enumeration_failure_surfaces_on_health(
             self, monkeypatch):
-        """_resolve_mesh blowing up (mid-flight libtpu upgrade,
-        broken tunnel) still degrades to single-device — but now as a
+        """_resolve_mesh blowing up (mid-flight libtpu upgrade, a
+        chip another process holds) still degrades to single-device — but now as a
         /healthz fact, not just a log line."""
         import fabric_tpu.bccsp.factory as fmod
 

@@ -29,7 +29,6 @@ from fabric_tpu.common.devicecost import (
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FIXTURES = os.path.join(ROOT, "tests", "fixtures", "perf_rounds")
 
 pytestmark = pytest.mark.chaos
 
@@ -144,6 +143,24 @@ class TestCompileSeam:
         rec, clock = self._recorder(tmp_path)
         fn = rec.wrap("comb", _FakeJit(clock, durations=[0.05]))
         fn(np.zeros((8,), np.int32))
+        assert rec.stats["compile_cache_hits"] == 1
+        assert rec.stats["compile_cold_total"] == 0
+
+    def test_slow_load_jax_calls_a_cache_hit_is_a_hit(self, tmp_path):
+        # a GB-scale program takes ~9 s to LOAD from the persistent
+        # cache on a v5e: over the wall threshold, but JAX's own
+        # cache-hit event says no compile happened
+        import jax.monitoring
+        rec, clock = self._recorder(tmp_path)
+        fake = _FakeJit(clock, durations=[9.0])
+        run_once = fake._run_once
+
+        def load_from_cache():
+            run_once()
+            jax.monitoring.record_event(
+                "/jax/compilation_cache/cache_hits")
+        fake._run_once = load_from_cache
+        rec.wrap("comb", fake)(np.zeros((8,), np.int32))
         assert rec.stats["compile_cache_hits"] == 1
         assert rec.stats["compile_cold_total"] == 0
 
@@ -431,8 +448,55 @@ class TestHbmHealth:
 
 
 # ---------------------------------------------------------------------------
-# the perf ledger over the real round history (fixture copies)
+# the perf ledger over a synthetic round history: every shape a round
+# file can take (clean, truncated final line, crashed, timed out)
 # ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def rounds(tmp_path_factory):
+    """Five bench rounds + their multichip twins, written fresh: r01
+    and r02 parse cleanly, r03's tail is a TRUNCATED final line
+    (`parsed: null`, numbers salvaged), r04 crashed mid-bench with a
+    traceback, r05 timed out with nothing but a log line."""
+    d = tmp_path_factory.mktemp("perf_rounds")
+    log = "WARNING: some log line before the bench output\n"
+
+    def final(value, steady, **extra):
+        return json.dumps({
+            "metric": "block-validation sig-verify throughput",
+            "value": value, "unit": "sigs/s",
+            "detail": dict({"batch": 30720, "tpu_steady_s": steady},
+                           **extra)})
+
+    bench = {
+        1: (0, log + final(20337.5, 1.5105) + "\n", True),
+        2: (0, log + final(50605.0, 0.6071) + "\n", True),
+        # the driver keeps only the END of the output: this round's
+        # final line lost its head
+        3: (0, 'tch": 30720, "tpu_steady_s": 0.2206, '
+               '"provider_verify_batch_sigs_per_s": '
+               '29309.2, "pipeline": {"order_raft_s": 87.68, '
+               '"validate_s": 3.7281}, "devices": ["TPU v5 lite0"]}}\n',
+            False),
+        4: (1, log + '{"stage": "kernel_steady", "value": 41000.0}\n'
+               "Traceback (most recent call last):\n"
+               '  File "bench.py", line 1, in main\n'
+               "    q_flat = prov._qflat_cache[cache_key]\n"
+               "KeyError: (b'\\x0b', b'-')\n", False),
+        5: (124, log, False),
+    }
+    for n, (rc, tail, parse) in bench.items():
+        parsed = json.loads(tail.strip().splitlines()[-1]) \
+            if parse else None
+        (d / f"BENCH_r{n:02d}.json").write_text(json.dumps({
+            "n": n, "cmd": "python bench.py", "rc": rc, "tail": tail,
+            "parsed": parsed}))
+    for n, rc in {1: 1, 2: 0, 3: 0, 4: 0, 5: 124}.items():
+        (d / f"MULTICHIP_r{n:02d}.json").write_text(json.dumps({
+            "n_devices": 8, "rc": rc, "ok": rc == 0,
+            "skipped": False, "tail": json.dumps(log)}))
+    return str(d)
+
 
 def _ledger():
     spec = importlib.util.spec_from_file_location(
@@ -444,9 +508,9 @@ def _ledger():
 
 
 class TestPerfLedger:
-    def test_trajectory_over_real_rounds_nonempty(self):
+    def test_trajectory_over_rounds_nonempty(self, rounds):
         pl = _ledger()
-        traj = pl.trajectory(FIXTURES)
+        traj = pl.trajectory(rounds)
         statuses = {r["round"]: r["status"] for r in traj["rounds"]}
         assert statuses == {1: "ok", 2: "ok", 3: "salvaged",
                             4: "crashed", 5: "timeout"}
@@ -463,17 +527,17 @@ class TestPerfLedger:
         r4 = next(r for r in traj["rounds"] if r["round"] == 4)
         assert "KeyError" in (r4.get("error") or "")
 
-    def test_multichip_rounds_attached(self):
+    def test_multichip_rounds_attached(self, rounds):
         pl = _ledger()
-        traj = pl.trajectory(FIXTURES)
+        traj = pl.trajectory(rounds)
         mc = {r["round"]: r.get("multichip") for r in traj["rounds"]}
         assert mc[1]["ok"] is False and mc[1]["rc"] == 1
         assert mc[2]["ok"] is True
         assert mc[5]["rc"] == 124
 
-    def test_check_passes_at_history_best(self):
+    def test_check_passes_at_history_best(self, rounds):
         pl = _ledger()
-        traj = pl.trajectory(FIXTURES)
+        traj = pl.trajectory(rounds)
         cand = {"on_tpu": True,
                 "value": traj["metrics"]["value"]["best"],
                 "tpu_steady_s":
@@ -482,9 +546,9 @@ class TestPerfLedger:
         assert res["ok"] is True
         assert set(res["checked"]) == {"value", "tpu_steady_s"}
 
-    def test_seeded_regression_flagged(self):
+    def test_seeded_regression_flagged(self, rounds):
         pl = _ledger()
-        traj = pl.trajectory(FIXTURES)
+        traj = pl.trajectory(rounds)
         cand = {"on_tpu": True,
                 "value": traj["metrics"]["value"]["best"] * 0.5,
                 "tpu_steady_s": 9.9}
@@ -493,16 +557,16 @@ class TestPerfLedger:
         names = {r["metric"] for r in res["regressions"]}
         assert names == {"value", "tpu_steady_s"}
 
-    def test_verdict_strings(self, tmp_path):
+    def test_verdict_strings(self, rounds, tmp_path):
         pl = _ledger()
         assert pl.verdict({"on_tpu": True, "value": 1.0},
                           str(tmp_path)) == "no_history"
         assert pl.verdict({"on_tpu": False, "value": 1.0},
-                          FIXTURES) == "skipped:cpu-rig"
+                          rounds) == "skipped:cpu-rig"
         good = pl.verdict({"on_tpu": True, "value": 60000.0},
-                          FIXTURES)
+                          rounds)
         assert good.startswith("ok(")
-        bad = pl.verdict({"on_tpu": True, "value": 10.0}, FIXTURES)
+        bad = pl.verdict({"on_tpu": True, "value": 10.0}, rounds)
         assert bad == "regressed:value"
 
     def test_crashed_round_salvage_never_gates(self, tmp_path):
@@ -536,11 +600,11 @@ class TestPerfLedger:
         cand = pl.load_candidate(str(f))
         assert cand["value"] == 42.0 and "stage" not in cand
 
-    def test_cli_exit_codes(self, tmp_path):
+    def test_cli_exit_codes(self, rounds, tmp_path):
         env = dict(os.environ)
         tool = os.path.join(ROOT, "tools", "perf_ledger.py")
         out = subprocess.run(
-            [sys.executable, tool, "--dir", FIXTURES],
+            [sys.executable, tool, "--dir", rounds],
             capture_output=True, text=True, env=env, timeout=60)
         assert out.returncode == 0, out.stderr
         traj = json.loads(out.stdout)
@@ -549,13 +613,13 @@ class TestPerfLedger:
         bad.write_text(json.dumps({"on_tpu": True, "value": 10.0}))
         out = subprocess.run(
             [sys.executable, tool, "check", "--candidate", str(bad),
-             "--dir", FIXTURES],
+             "--dir", rounds],
             capture_output=True, text=True, env=env, timeout=60)
         assert out.returncode == 1, (out.stdout, out.stderr)
         assert "REGRESSION value" in out.stderr
         out = subprocess.run(
             [sys.executable, tool, "check", "--candidate",
-             str(tmp_path / "missing.json"), "--dir", FIXTURES],
+             str(tmp_path / "missing.json"), "--dir", rounds],
             capture_output=True, text=True, env=env, timeout=60)
         assert out.returncode == 2
 

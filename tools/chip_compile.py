@@ -1,0 +1,189 @@
+"""Compile the P-256 block-validation programs for a DESCRIBED v5e.
+
+No chip is needed: the TPU compiler installed beside JAX compiles for a
+topology that is described, not attached (`JAX_PLATFORMS=cpu` stays
+set). Nothing runs, so this says nothing about results or run time —
+it answers two questions before any chip time is spent: does the
+chip's compiler accept the program at its real shapes, and how many
+seconds does it take. Prints one JSON line per program with the
+compile seconds and `memory_analysis()`.
+
+    python tools/chip_compile.py --list
+    python tools/chip_compile.py tree_pallas_q16 sha_dma fused_q16
+    python tools/chip_compile.py --lanes 512 tree_pallas_q16
+
+Run the programs one after another (one process describes the topology
+at a time — libtpu's lock), never beside tests/test_chip_compile.py.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _provider(tree: str, fused: bool):
+    """A TPUProvider steered onto its TPU branches: on a described
+    topology `jax.devices()` still answers CPU, so the script (not the
+    program) pins what `_on_tpu()` would resolve to on the chip."""
+    from fabric_tpu.bccsp import tpu as tpumod
+    from fabric_tpu.common import jaxenv
+
+    jaxenv.pallas_interpret = lambda: False
+    prov = tpumod.TPUProvider(use_g16=True, fused_verify=fused)
+    prov._on_tpu = lambda: True
+    prov._tree_impl = lambda: tree
+    return prov
+
+
+def programs(lanes: int, K: int, nb: int, dev):
+    """name -> (jitted fn, argument shapes). Shapes mirror what
+    `TPUProvider._dispatch_{comb_digest,fused_verify}` stage for one
+    `Chunk` of `lanes` signatures over a K-slot key set."""
+    import numpy as np
+
+    from fabric_tpu.ops import comb, fused_verify as fv, limb, ptree
+
+    L = limb.L
+    i32, u8, u32 = np.int32, np.uint8, np.uint32
+    s = lambda shape, dt: _sds(shape, dt, dev)     # noqa: E731
+    ent16 = comb.NWIN_G16 * comb.NENT_G16
+    ent8 = comb.NWIN * comb.NENT
+    g16 = s((ent16, 3, L), i32)
+    g0 = s((0, 3, L), i32)
+
+    def tree(points):
+        import jax
+        return (jax.jit(lambda p, r, rpn, pm: ptree.tree_verify_points(
+                    p, r, rpn, pm, interpret=False)),
+                (s((lanes, points, 3, L), i32), s((lanes, L), i32),
+                 s((lanes, L), i32), s((lanes,), bool)))
+
+    def sha(dma):
+        import jax
+        return (jax.jit(lambda b, n, d, h, r, w: fv.sha_windows(
+                    b, n, d, h, r, w, wbits_g=16, wbits_q=16,
+                    interpret=False, dma=dma)),
+                (s((lanes, nb, 16), u32), s((lanes,), i32),
+                 s((lanes, 8), u32), s((lanes,), bool),
+                 s((lanes, L), i32), s((lanes, L), i32)))
+
+    def digest(tree_impl, q16):
+        prov = _provider(tree_impl, fused=False)
+        fn = prov._comb_pipeline_digest(K, q16)
+        ent = ent16 if q16 else ent8
+        return (fn, (s((lanes,), i32), s((ent * K, 3, L), i32),
+                     g16 if q16 else g0, s((lanes, 32), u8),
+                     s((lanes, 32), u8), s((lanes, 32), u8),
+                     s((lanes,), bool), s((lanes, 8), u32)))
+
+    def fused(tree_impl, q16):
+        prov = _provider(tree_impl, fused=True)
+        fn = prov._fused_pipeline(K, q16)
+        ent = ent16 if q16 else ent8
+        return (fn, (s((lanes, nb, 16), u32), s((lanes,), i32),
+                     s((lanes,), i32), s((ent * K, 3, L), i32),
+                     g16 if q16 else g0, s((lanes, 32), u8),
+                     s((lanes, 32), u8), s((lanes, 32), u8),
+                     s((lanes,), bool), s((lanes, 8), u32),
+                     s((lanes,), bool)))
+
+    def qtab():
+        import jax
+        return (jax.jit(comb.build_q_tables),
+                (s((K, L), i32), s((K, L), i32)))
+
+    def g16tab():
+        import jax
+        return (jax.jit(comb._combine_windows, static_argnums=2),
+                (s((ent8, 3, L), i32), s((comb.NWIN_G16,), i32),
+                 comb.NENT))
+
+    def qtab16():
+        import jax
+        return (jax.jit(comb.build_q16_tables, static_argnums=1),
+                (s((ent8 * K, 3, L), i32), K))
+
+    return {
+        "tree_pallas_q16": lambda: tree(32),
+        "tree_pallas_q8": lambda: tree(64),
+        "sha_dma": lambda: sha(True),
+        "sha_plain": lambda: sha(False),
+        "digest_q16_pallas": lambda: digest("pallas", True),
+        "digest_q16_xla": lambda: digest("xla", True),
+        "digest_q8_xla": lambda: digest("xla", False),
+        "fused_q16_pallas": lambda: fused("pallas", True),
+        "fused_q16_xla": lambda: fused("xla", True),
+        "fused_q8_xla": lambda: fused("xla", False),
+        "g16": g16tab,
+        "qtab8": qtab,
+        "qtab16": qtab16,
+    }
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("names", nargs="*")
+    ap.add_argument("--list", action="store_true")
+    ap.add_argument("--lanes", type=int, default=32768)
+    ap.add_argument("--keys", type=int, default=4,
+                    help="key-slot bucket K (3 keys -> 4)")
+    ap.add_argument("--nb", type=int, default=4,
+                    help="SHA blocks per message lane (256 B -> 8)")
+    ap.add_argument("--topology", default="v5e:2x2")
+    args = ap.parse_args()
+
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    # a described-topology compile is written to the persistent cache
+    # but cannot be read back without a chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=args.topology)
+    dev = SingleDeviceSharding(topo.devices[0])
+    table = programs(args.lanes, args.keys, args.nb, dev)
+    if args.list:
+        print("\n".join(table))
+        return 0
+    rc = 0
+    for name in args.names or list(table):
+        row = {"program": name, "lanes": args.lanes, "K": args.keys,
+               "nb": args.nb, "device_kind": topo.devices[0].device_kind}
+        try:
+            fn, shapes = table[name]()
+            t0 = time.perf_counter()
+            lowered = fn.lower(*shapes)
+            row["lower_s"] = round(time.perf_counter() - t0, 1)
+            t0 = time.perf_counter()
+            compiled = lowered.compile()
+            row["compile_s"] = round(time.perf_counter() - t0, 1)
+            ma = compiled.memory_analysis()
+            row["memory"] = {k: int(getattr(ma, k)) for k in (
+                "argument_size_in_bytes", "output_size_in_bytes",
+                "temp_size_in_bytes", "generated_code_size_in_bytes")
+                if hasattr(ma, k)}
+            row["ok"] = True
+        except Exception as e:        # the compiler's refusal IS the result
+            row["ok"] = False
+            row["error"] = f"{type(e).__name__}: {e}"[:2000]
+            rc = 1
+        print(json.dumps(row), flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
