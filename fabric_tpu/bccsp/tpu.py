@@ -23,6 +23,7 @@ outage must degrade, not halt (SURVEY §7 step 3).
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import threading
@@ -261,6 +262,11 @@ class TPUProvider(api.BCCSP):
                       "pipeline_overlap_ratio": 0.0,
                       "prepared_transfer_s": 0.0,
                       "prepared_device_s": 0.0,
+                      # cumulative, prepared-block path: signatures
+                      # handed over, the lanes their padded buckets
+                      # ran, and the operand bytes staged to the device
+                      "lanes_real": 0, "lanes_padded": 0,
+                      "h2d_bytes": 0,
                       "shard_devices": (getattr(mesh, "size", 1)
                                         if mesh is not None else 1),
                       "shard_dispatches": 0,
@@ -545,13 +551,24 @@ class TPUProvider(api.BCCSP):
         tracing spans cover every path by construction. An armed
         fault (or a broken backend) books a compile_failures count
         and an error-status span, then propagates to the caller's
-        breaker/fallback exactly as before."""
+        breaker/fallback exactly as before.
+
+        The program carries its `kind` as its name, so a device trace
+        shows `jit_comb_digest(<id>)`, `jit_qtab16(<id>)`, ... and not
+        one `jit_fused` for every program whose inner function happens
+        to be called `fused`. (The name is part of the persistent
+        compile cache's key.)"""
         t0 = self._devicecost._clock()
+
+        @functools.wraps(fn)
+        def program(*args, **kwargs):
+            return fn(*args, **kwargs)
+        program.__name__ = program.__qualname__ = kind
         try:
             with tracing.span("tpu.compile", kind=kind, build=True):
                 faults.check("tpu.compile")
                 import jax
-                jitted = jax.jit(fn, **jit_kw)
+                jitted = jax.jit(program, **jit_kw)
         except BaseException as e:
             self._devicecost.note(kind, self._devicecost._clock() - t0,
                                   cache_hit=False, error=e)
@@ -1912,73 +1929,87 @@ class TPUProvider(api.BCCSP):
 
         n = len(der_ok)
         bucket = self._bucket(n)
-        premask = np.zeros(bucket, dtype=bool)
-        premask[:n] = der_ok.astype(bool)
+        self.stats["lanes_real"] += n
+        self.stats["lanes_padded"] += bucket
+        # the host arrays the dispatch ships: one span, whatever n
+        stage = tracing.span("tpu.stage", lanes=n, bucket=bucket)
+        with stage:
+            premask = np.zeros(bucket, dtype=bool)
+            premask[:n] = der_ok.astype(bool)
 
-        # per-key gating: lanes on a non-ECDSA key reject; lanes on a
-        # non-P256 ECDSA key verify on the sw path without degrading
-        # the batch (same contract as the item path)
-        key_ok = np.array([p is not None and p.is_p256()
-                           for p in pubs], dtype=bool)
-        key_sw = np.array([p is not None and not p.is_p256()
-                           for p in pubs], dtype=bool)
-        lane_key = np.asarray(key_idx, dtype=np.int32)
-        premask[:n] &= key_ok[lane_key]
-        sw_lanes = np.nonzero(key_sw[lane_key])[0]
+            # per-key gating: lanes on a non-ECDSA key reject; lanes on
+            # a non-P256 ECDSA key verify on the sw path without
+            # degrading the batch (same contract as the item path)
+            key_ok = np.array([p is not None and p.is_p256()
+                               for p in pubs], dtype=bool)
+            key_sw = np.array([p is not None and not p.is_p256()
+                               for p in pubs], dtype=bool)
+            lane_key = np.asarray(key_idx, dtype=np.int32)
+            premask[:n] &= key_ok[lane_key]
+            sw_lanes = np.nonzero(key_sw[lane_key])[0]
 
-        key_map: dict[bytes, int] = {}
-        qx_b = np.zeros((bucket, 32), dtype=np.uint8)
-        qy_b = np.zeros((bucket, 32), dtype=np.uint8)
-        # build the key table over P-256 keys only; dead lanes keep
-        # slot 0 (masked out by premask)
-        slot_of = np.zeros(len(keys), dtype=np.int32)
-        kx = np.zeros((max(len(keys), 1), 32), dtype=np.uint8)
-        ky = np.zeros((max(len(keys), 1), 32), dtype=np.uint8)
-        for j, p in enumerate(pubs):
-            if p is None or not p.is_p256():
-                continue
-            xb = np.asarray(p.x_bytes(), dtype=np.uint8)
-            yb = np.asarray(p.y_bytes(), dtype=np.uint8)
-            kbytes = xb.tobytes() + yb.tobytes()
-            slot_of[j] = key_map.setdefault(kbytes, len(key_map))
-            kx[j] = xb
-            ky[j] = yb
-        lane_slot = np.zeros(bucket, dtype=np.int32)
-        lane_slot[:n] = slot_of[lane_key]
-        qx_b[:n] = kx[lane_key]
-        qy_b[:n] = ky[lane_key]
+            key_map: dict[bytes, int] = {}
+            # build the key table over P-256 keys only; dead lanes keep
+            # slot 0 (masked out by premask)
+            slot_of = np.zeros(len(keys), dtype=np.int32)
+            kx = np.zeros((max(len(keys), 1), 32), dtype=np.uint8)
+            ky = np.zeros((max(len(keys), 1), 32), dtype=np.uint8)
+            for j, p in enumerate(pubs):
+                if p is None or not p.is_p256():
+                    continue
+                xb = np.asarray(p.x_bytes(), dtype=np.uint8)
+                yb = np.asarray(p.y_bytes(), dtype=np.uint8)
+                kbytes = xb.tobytes() + yb.tobytes()
+                slot_of[j] = key_map.setdefault(kbytes, len(key_map))
+                kx[j] = xb
+                ky[j] = yb
+            lane_slot = np.zeros(bucket, dtype=np.int32)
+            lane_slot[:n] = slot_of[lane_key]
 
-        dg = np.zeros((bucket, 8), dtype=np.uint32)
-        dg[:n] = np.ascontiguousarray(digests).view(">u4").reshape(n, 8)
+            dg = np.zeros((bucket, 8), dtype=np.uint32)
+            dg[:n] = np.ascontiguousarray(digests).view(">u4").reshape(
+                n, 8)
 
-        def pad8(a):
-            out = np.zeros((bucket, 32), dtype=np.uint8)
-            out[:n] = a
-            return out
+            def pad8(a):
+                out = np.zeros((bucket, 32), dtype=np.uint8)
+                out[:n] = a
+                return out
 
-        if 0 < len(key_map) <= self._max_keys:
-            # transfer-minimal digest pipeline (the common case)
+            comb = 0 < len(key_map) <= self._max_keys
+            if comb:
+                # transfer-minimal digest pipeline (the common case)
+                scalars = (pad8(r), pad8(rpn), pad8(w))
+            else:
+                qx_b = np.zeros((bucket, 32), dtype=np.uint8)
+                qy_b = np.zeros((bucket, 32), dtype=np.uint8)
+                qx_b[:n] = kx[lane_key]
+                qy_b[:n] = ky[lane_key]
+                scalars = tuple(limb.be_bytes_to_limbs(pad8(a))
+                                for a in (r, rpn, w))
+            stage.set(keys=len(key_map))
+
+        if comb:
             self.stats["comb_batches"] += 1
             thunk = self._dispatch_comb_digest(
-                bucket, key_map, lane_slot, pad8(r), pad8(rpn),
-                pad8(w), premask, dg, async_out=True)
+                bucket, key_map, lane_slot, *scalars, premask, dg,
+                async_out=True)
         else:
             blocks = np.zeros((bucket, 1, 16), dtype=np.uint32)
             nblocks = np.zeros(bucket, dtype=np.int32)
             has_digest = np.ones(bucket, dtype=bool)
             thunk = self._dispatch_arrays(
                 bucket, key_map, lane_slot, blocks, nblocks,
-                limb.be_bytes_to_limbs(pad8(r)),
-                limb.be_bytes_to_limbs(pad8(rpn)),
-                limb.be_bytes_to_limbs(pad8(w)), premask, dg,
-                has_digest, qx_b, qy_b, async_out=True)
+                *scalars, premask, dg, has_digest, qx_b, qy_b,
+                async_out=True)
 
         def resolve() -> list[bool]:
-            result = thunk()[:n].tolist()
-            self._sw_scatter(
-                sw_lanes.tolist(), result,
-                lambda ls: self._verify_prepared_sw(
-                    ls, digests, key_idx, keys, pubs, get_sig))
+            out = thunk()
+            with tracing.span("tpu.readback", lanes=n):
+                result = out[:n].tolist()
+                self._sw_scatter(
+                    sw_lanes.tolist(), result,
+                    lambda ls: self._verify_prepared_sw(
+                        ls, digests, key_idx, keys, pubs, get_sig))
             return result
         return resolve
 
@@ -2540,6 +2571,13 @@ class TPUProvider(api.BCCSP):
         (key_idx remapped, K, q_flat, g16, q16?). Under a mesh the
         table arrays come back replicated (stored back, so repeat
         dispatches short-circuit the device_put)."""
+        sp = tracing.span("tpu.tables", keys=len(key_map))
+        with sp:
+            out = self._resolve_tables_traced(key_map, key_idx)
+            sp.set(q16=out[4])
+        return out
+
+    def _resolve_tables_traced(self, key_map, key_idx):
         import jax.numpy as jnp
 
         from fabric_tpu.ops import limb
@@ -2804,10 +2842,6 @@ class TPUProvider(api.BCCSP):
         prepared-block fast path."""
         lockcheck.note_blocking("tpu.dispatch")
         faults.check("tpu.dispatch")
-        import time as _time
-
-        import jax
-
         key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
                                                             key_idx)
         chunk = self._mesh_chunk(bucket)
@@ -2817,40 +2851,56 @@ class TPUProvider(api.BCCSP):
             # program serves both paths
             chunk = span
         fn = self._comb_pipeline_digest(K, q16)
+        return self._dispatch_chunks(
+            bucket, chunk,
+            (key_idx, r8, rpn8, w8, premask, digests),
+            lambda c: fn(c[0], q_flat, g16, *c[1:]), async_out)
+
+    @hot_path
+    def _dispatch_chunks(self, bucket, chunk, operands, run, async_out):
+        """The transfer-ahead double buffer of the prepared-block
+        dispatches: chunk k+1's async device_put is enqueued BEFORE
+        chunk k's dispatch, so the H2D copy rides under device
+        execution instead of serializing with it (host prep already
+        happened in native/blockprep.cpp). `run(staged)` enqueues the
+        program on one chunk's staged operands. One `tpu.h2d` and one
+        `tpu.enqueue` span a chunk; the thunk holds `tpu.wait` (the
+        host blocked on the device, nothing else) and `tpu.readback`.
+        The spans' own clock readings feed the prepared_* gauges."""
+        import jax
 
         ndev = self._mesh.size if self._mesh is not None else 1
         tdev = [0.0] * ndev
-
-        def stage(lo):
-            hi = lo + chunk
-            arrs = (key_idx[lo:hi], r8[lo:hi], rpn8[lo:hi], w8[lo:hi],
-                    premask[lo:hi], digests[lo:hi])
-            if self._mesh is not None:
-                return self._shard_put(arrs, tdev)
-            return tuple(jax.device_put(a) for a in arrs)
-
-        # transfer-ahead double buffer: chunk k+1's async device_put
-        # is enqueued BEFORE chunk k's dispatch, so the H2D copy rides
-        # under device execution instead of serializing with it (the
-        # prepared-block path's half of the overlapped pipeline — host
-        # prep already happened in native/blockprep.cpp)
-        outs = []
         transfer_s = dispatch_s = 0.0
         t_disp0 = None
-        t0 = _time.perf_counter()
+
+        def stage(lo):
+            nonlocal transfer_s
+            arrs = tuple(a[lo:lo + chunk] for a in operands)
+            nbytes = sum(a.nbytes for a in arrs)
+            self.stats["h2d_bytes"] += nbytes
+            h2d = tracing.timed("tpu.h2d", bytes=nbytes,
+                                chunk=lo // chunk)
+            with h2d:
+                if self._mesh is not None:
+                    staged = self._shard_put(arrs, tdev)
+                else:
+                    staged = tuple(jax.device_put(a) for a in arrs)
+            transfer_s += h2d.seconds
+            return staged
+
+        outs = []
         nxt = stage(0)
-        transfer_s += _time.perf_counter() - t0
         for lo in range(0, bucket, chunk):
             cur, nxt = nxt, None
             if lo + chunk < bucket:
-                t0 = _time.perf_counter()
                 nxt = stage(lo + chunk)
-                transfer_s += _time.perf_counter() - t0
-            t0 = _time.perf_counter()
+            enqueue = tracing.timed("tpu.enqueue", chunk=lo // chunk)
+            with enqueue:
+                outs.append(run(cur))
             if t_disp0 is None:
-                t_disp0 = t0
-            outs.append(fn(cur[0], q_flat, g16, *cur[1:]))
-            dispatch_s += _time.perf_counter() - t0
+                t_disp0 = enqueue.t0
+            dispatch_s += enqueue.seconds
         # prepared_* (NOT pipeline_*): these gauges must not clobber
         # the overlapped item path's coherent host/transfer/device/
         # overlap snapshot with a different batch's numbers
@@ -2859,15 +2909,23 @@ class TPUProvider(api.BCCSP):
             self.stats["shard_dispatches"] += len(outs)
 
         def thunk():
-            t0 = _time.perf_counter()
-            if self._mesh is not None:
-                self._record_shard_stats(outs[-1], tdev, chunk,
-                                         t_disp0)
-            # ftpu-lint: allow-host-sync(the thunk IS the deliberate
-            # materialization point, invoked after dispatch returns)
-            out = np.concatenate([np.asarray(o) for o in outs])
+            wait = tracing.timed("tpu.wait", chunks=len(outs))
+            with wait:
+                if self._mesh is not None:
+                    self._record_shard_stats(outs[-1], tdev, chunk,
+                                             t_disp0)
+                # ftpu-lint: allow-host-sync(the thunk IS the
+                # deliberate materialization point, invoked after
+                # dispatch returns; this is its wait)
+                jax.block_until_ready(outs)
+            readback = tracing.timed("tpu.readback", lanes=bucket)
+            with readback:
+                # ftpu-lint: allow-host-sync(the thunk IS the
+                # deliberate materialization point, invoked after
+                # dispatch returns)
+                out = np.concatenate([np.asarray(o) for o in outs])
             self.stats["prepared_device_s"] = round(
-                dispatch_s + _time.perf_counter() - t0, 6)
+                dispatch_s + readback.t1 - wait.t0, 6)
             return out
         return thunk if async_out else thunk()
 
@@ -2887,61 +2945,16 @@ class TPUProvider(api.BCCSP):
         lockcheck.note_blocking("tpu.dispatch")
         faults.check("tpu.fused_verify")
         faults.check("tpu.dispatch")
-        import time as _time
-
-        import jax
-
         key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
                                                             key_idx)
         chunk = self._mesh_chunk(bucket)
         fn = self._fused_pipeline(K, q16)
-
-        ndev = self._mesh.size if self._mesh is not None else 1
-        tdev = [0.0] * ndev
-
-        def stage(lo):
-            hi = lo + chunk
-            arrs = (blocks[lo:hi], nblocks[lo:hi], key_idx[lo:hi],
-                    r8[lo:hi], rpn8[lo:hi], w8[lo:hi], premask[lo:hi],
-                    digests[lo:hi], has_digest[lo:hi])
-            if self._mesh is not None:
-                return self._shard_put(arrs, tdev)
-            return tuple(jax.device_put(a) for a in arrs)
-
-        outs = []
-        transfer_s = dispatch_s = 0.0
-        t_disp0 = None
-        t0 = _time.perf_counter()
-        nxt = stage(0)
-        transfer_s += _time.perf_counter() - t0
-        for lo in range(0, bucket, chunk):
-            cur, nxt = nxt, None
-            if lo + chunk < bucket:
-                t0 = _time.perf_counter()
-                nxt = stage(lo + chunk)
-                transfer_s += _time.perf_counter() - t0
-            t0 = _time.perf_counter()
-            if t_disp0 is None:
-                t_disp0 = t0
-            outs.append(fn(cur[0], cur[1], cur[2], q_flat, g16,
-                           *cur[3:]))
-            dispatch_s += _time.perf_counter() - t0
-        self.stats["prepared_transfer_s"] = round(transfer_s, 6)
-        if self._mesh is not None:
-            self.stats["shard_dispatches"] += len(outs)
-
-        def thunk():
-            t0 = _time.perf_counter()
-            if self._mesh is not None:
-                self._record_shard_stats(outs[-1], tdev, chunk,
-                                         t_disp0)
-            # ftpu-lint: allow-host-sync(the thunk IS the deliberate
-            # materialization point, invoked after dispatch returns)
-            out = np.concatenate([np.asarray(o) for o in outs])
-            self.stats["prepared_device_s"] = round(
-                dispatch_s + _time.perf_counter() - t0, 6)
-            return out
-        return thunk if async_out else thunk()
+        return self._dispatch_chunks(
+            bucket, chunk,
+            (blocks, nblocks, key_idx, r8, rpn8, w8, premask, digests,
+             has_digest),
+            lambda c: fn(c[0], c[1], c[2], q_flat, g16, *c[3:]),
+            async_out)
 
     def _fused_pipeline(self, K: int, q16: bool):
         """Build (once per (K, q16)) the jitted fused-verify program.

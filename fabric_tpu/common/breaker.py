@@ -231,12 +231,17 @@ class CircuitBreaker:
         try:
             try:
                 if deadline_s > 0:
+                    from fabric_tpu.common import tracing
                     box: dict = {}
                     done = threading.Event()
+                    tctx = tracing.capture()
 
                     def work():
                         try:
-                            box["result"] = fn()
+                            # the dispatch's spans stay under the
+                            # caller's
+                            with tracing.attached(tctx):
+                                box["result"] = fn()
                         except BaseException as e:  # noqa: BLE001
                             box["error"] = e
                         finally:

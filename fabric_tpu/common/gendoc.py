@@ -36,6 +36,19 @@ EPILOGUE = """\
   (comb/ladder dispatches, q16 table cache bytes and evictions, sw
   fallbacks …), published by
   `fabric_tpu/common/profiling.py publish_provider_stats`.
+- `trace_stage_seconds{stage=<span>}` — the stage label is the span
+  name. The block-intake spans, a fixed number per block and per
+  provider call (registry and nesting: ARCHITECTURE.md, "Block-intake
+  span tree"): `peer.verify_block`, `peer.block`, `commit.validate`,
+  `validate.prep`, `validate.policy`, `validate.flags`, `tpu.verify`,
+  `tpu.stage`, `tpu.comb_digest`, `tpu.tables`, `tpu.h2d`,
+  `tpu.enqueue`, `tpu.wait`, `tpu.readback`, `intake.rwsets`,
+  `intake.txids`, `commit.commit`, `commit.pvt`, `commit.notify`,
+  `ledger.mvcc`, `ledger.blockstore`, `blockstore.append`,
+  `blockstore.index`, `ledger.history`, `ledger.state`, `runtime.gc`.
+  Once JAX is imported every span is also a
+  `jax.profiler.TraceAnnotation`, so a `/debug/jax/trace` capture
+  carries them on a host line beside the device operations.
 """
 
 

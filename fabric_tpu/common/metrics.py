@@ -207,6 +207,25 @@ BCCSP_PAIRING_FALLBACKS_OPTS = GaugeOpts(
          "verdicts stay bit-identical; small-batch POLICY routing to "
          "the host is deliberate and not counted here.")
 
+BCCSP_LANES_REAL_OPTS = GaugeOpts(
+    namespace="bccsp", name="lanes_real",
+    help="Signatures handed to the prepared-block device path since "
+         "process start (the `lanes` attr of the `tpu.stage` spans, "
+         "summed).")
+
+BCCSP_LANES_PADDED_OPTS = GaugeOpts(
+    namespace="bccsp", name="lanes_padded",
+    help="Lanes the device ran for those signatures since process "
+         "start: each batch padded to its compiled bucket. "
+         "lanes_real / lanes_padded is the share of device lanes that "
+         "verified something.")
+
+BCCSP_H2D_BYTES_OPTS = GaugeOpts(
+    namespace="bccsp", name="h2d_bytes",
+    help="Operand bytes the prepared-block dispatches staged to the "
+         "device since process start (the `bytes` attr of the "
+         "`tpu.h2d` spans, summed; resident tables not included).")
+
 BCCSP_SHARD_SKEW_SECONDS_OPTS = GaugeOpts(
     namespace="bccsp", subsystem="shard", name="skew_s",
     help="Ready-time spread (max - min) across mesh devices for the "

@@ -221,6 +221,11 @@ def publish_provider_stats(metrics_provider, csp, poll_s: float = 5.0):
         "pairing_pairs": metrics_mod.BCCSP_PAIRING_PAIRS_OPTS,
         "pairing_batches": metrics_mod.BCCSP_PAIRING_BATCHES_OPTS,
         "pairing_fallbacks": metrics_mod.BCCSP_PAIRING_FALLBACKS_OPTS,
+        # the prepared-block path's cumulative lane/byte counters (the
+        # public reading of what the spans' attrs carry per call)
+        "lanes_real": metrics_mod.BCCSP_LANES_REAL_OPTS,
+        "lanes_padded": metrics_mod.BCCSP_LANES_PADDED_OPTS,
+        "h2d_bytes": metrics_mod.BCCSP_H2D_BYTES_OPTS,
     }
     gauges = {
         name: metrics_provider.new_gauge(canonical.get(

@@ -31,14 +31,24 @@ propagating exception — on `@hot_path` code the per-span cost is two
 clock reads, one ring slot and one histogram observation. Every span
 feeds a per-stage latency reservoir (`stage_quantiles()`: the bench's
 p50/p99 stage fields) and, when a metrics provider is bound, the
-canonical `trace_stage_seconds` histogram on `/metrics`.
+canonical `trace_stage_seconds` histogram on `/metrics`. Where a
+stretch also feeds a gauge, a histogram or a log line, `timed(name)`
+is the span that hands its own clock readings back (`.t0`, `.t1`,
+`.seconds`), so one pair of reads per boundary serves them all. Once
+JAX is imported in the process (this module never imports it) every
+span is also a `jax.profiler.TraceAnnotation`: a profiler capture
+shows the span tree on a host line beside the device operations.
+A generation-2 collection is a `runtime.gc` span (a `gc.callbacks`
+hook, installed while tracing is enabled).
 
 **Flight recorder** — a preallocated, lock-light, drop-oldest ring of
 the most recent spans/events that is ALWAYS ON (`FTPU_TRACE=0` or
 `Operations.Tracing.Enabled: false` opts out; disabled mode costs one
 attribute read and allocates nothing). Exported as Chrome-trace-event
 JSON (perfetto / chrome://tracing loadable, tid = pipeline stage) via
-the `/debug/trace` operations endpoint, and dumped to a file
+the `/debug/trace` operations endpoint (`snapshot()` is the in-process
+read API, `dropped()` how many events the ring has overwritten), and
+dumped to a file
 automatically on breaker trips, device quarantines and shed bursts
 (rate-limited) — the postmortem for the rc=124 class, where the only
 prior evidence was an empty stdout tail.
@@ -54,11 +64,13 @@ observe) for hosts where even ring writes are too much.
 from __future__ import annotations
 
 import functools
+import gc
 import itertools
 import json
 import logging
 import os
 import re
+import sys
 import tempfile
 import threading
 import time
@@ -130,6 +142,8 @@ class _State:
         self.stages: dict = {}           # stage -> _StageLat
         self.stage_lock = threading.Lock()
         self.hist = None                 # bound trace_stage_seconds
+        self.annotation = None           # jax.profiler.TraceAnnotation
+        self.gc_t0: Optional[float] = None
         self.dump_dir = os.environ.get("FTPU_TRACE_DUMP_DIR") or None
         self.dump_min_interval_s = _env_float("FTPU_TRACE_DUMP_MIN_S",
                                               10.0)
@@ -190,6 +204,7 @@ def set_enabled(flag: bool) -> None:
     nodes configure once at startup). Disabled mode is the
     zero-allocation fast path: span() returns a shared no-op."""
     _state.enabled = bool(flag)
+    _sync_gc_hook()
 
 
 def configure(enabled: Optional[bool] = None,
@@ -199,7 +214,7 @@ def configure(enabled: Optional[bool] = None,
               dump_min_interval_s: Optional[float] = None,
               shed_burst: Optional[int] = None) -> None:
     if enabled is not None:
-        _state.enabled = bool(enabled)
+        set_enabled(enabled)
     if ring_size is not None and ring_size > 0:
         with _state.ring_lock:
             _state.ring = [None] * int(ring_size)
@@ -286,7 +301,7 @@ def reset(enabled: Optional[bool] = None) -> None:
     with _state.dump_lock:
         _state.last_dump_t = None
     if enabled is not None:
-        _state.enabled = bool(enabled)
+        set_enabled(enabled)
 
 
 # ---------------------------------------------------------------------------
@@ -346,18 +361,51 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` once JAX is imported in this
+    process, else None. This module never imports JAX (an orderer
+    without it pays one dict lookup a span); with no profiler session
+    active an annotation is one atomic check in C++."""
+    ta = _state.annotation
+    if ta is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            ta = _state.annotation = jax.profiler.TraceAnnotation
+        except AttributeError:      # JAX is still being imported
+            return None
+    return ta
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "_parent", "ctx", "_prior", "_t0")
+    __slots__ = ("name", "attrs", "_parent", "ctx", "_prior", "t0",
+                 "t1", "_ann")
 
     def __init__(self, name: str, parent: Optional[TraceContext],
                  attrs: Optional[dict]):
         self.name = name
         self.attrs = attrs
         self._parent = parent
+
+    def set(self, **attrs) -> None:
+        """Counts known only once the work is done (raw, like the
+        attrs given at the opening)."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
     def __enter__(self) -> TraceContext:
         parent = self._parent
@@ -371,11 +419,19 @@ class _Span:
         self.ctx = ctx
         self._prior = getattr(_tls, "ctx", None)
         _tls.ctx = ctx
-        self._t0 = time.perf_counter()
+        # the same span on the profiler's timeline, beside the device
+        # operations, whenever a capture is running
+        ta = _trace_annotation()
+        self._ann = ann = ta(self.name) if ta is not None else None
+        if ann is not None:
+            ann.__enter__()
+        self.t0 = time.perf_counter()
         return ctx
 
     def __exit__(self, et, ev, tb) -> bool:
-        t1 = time.perf_counter()
+        self.t1 = t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         _tls.ctx = self._prior
         err = None
         if et is not None:
@@ -383,7 +439,7 @@ class _Span:
             # str(ev) is the ONE formatting cost and only on failures
             err = f"{et.__name__}: {ev}" if ev is not None \
                 else et.__name__
-        dur = t1 - self._t0
+        dur = t1 - self.t0
         _observe(self.name, dur)
         # sampled ring admission — error spans always record (they are
         # exactly what a postmortem reader is looking for)
@@ -393,22 +449,63 @@ class _Span:
             _record(("X", self.name, self.ctx.trace_id,
                      self.ctx.span_id,
                      parent.span_id if parent is not None else None,
-                     self._t0, dur,
+                     self.t0, dur,
                      threading.current_thread().name,
                      self.attrs or None, err))
         return False
+
+
+class _Clock:
+    """Disabled-mode `timed()`: the two clock reads its caller's
+    gauge, histogram or log line needs, and nothing of a span."""
+
+    __slots__ = ("t0", "t1")
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 def span(name: str, parent: Optional[TraceContext] = None, **attrs):
     """Open one lifecycle span: `with span("order.propose", n=3):`.
     Inherits the ambient context (or `parent`) for correlation,
     records a perf_counter pair + the attrs (raw — formatted only at
-    export), stamps error status from a propagating exception, and
-    feeds the stage latency reservoir/histogram. Returns a shared
-    no-op when tracing is disabled."""
+    export; `.set(k=v)` adds counts known only afterwards), stamps
+    error status from a propagating exception, and feeds the stage
+    latency reservoir/histogram. Returns a shared no-op when tracing
+    is disabled."""
     if not _state.enabled:
         return _NOOP
     return _Span(name, parent, attrs or None)
+
+
+def timed(name: str, **attrs):
+    """A span that hands its own clock readings back (`.t0`, `.t1`,
+    `.seconds`, valid once it has closed): for a stretch whose
+    duration also feeds a gauge, a histogram or a log line, so that
+    one pair of reads per boundary serves them all. Those readers
+    exist with tracing disabled too, so this reads the clock either
+    way; disabled, it records nothing and builds no span.
+
+        t = tracing.timed("ledger.history")
+        with t:
+            ...
+        hist.observe(t.seconds)
+    """
+    if not _state.enabled:
+        return _Clock()
+    return _Span(name, None, attrs or None)
 
 
 def traced(name: str):
@@ -426,21 +523,39 @@ def traced(name: str):
     return deco
 
 
+def child_context(parent: Optional[TraceContext] = None
+                  ) -> Optional[TraceContext]:
+    """A context allocated ahead of its span, for a stage that spans
+    thread handoffs (the commit pipeline's block: submitted on one
+    thread, validated and committed on two others): hand it to the
+    workers as their spans' `parent`, then record the stage itself
+    with `observe_span(..., parent=parent, ctx=<this>)` once it is
+    over. None when tracing is disabled."""
+    if not _state.enabled:
+        return None
+    return _child_of(parent if parent is not None else capture())
+
+
+def _child_of(parent: Optional[TraceContext]) -> TraceContext:
+    return TraceContext(parent.trace_id if parent is not None
+                        else _next_id(), _next_id())
+
+
 def observe_span(name: str, t0: float, t1: float,
                  parent: Optional[TraceContext] = None,
+                 ctx: Optional[TraceContext] = None,
                  **attrs) -> Optional[TraceContext]:
     """Record an already-measured interval as a complete span (for
     stages whose timing is computed inline — the admission window's
     convoy wait, raft propose->commit consensus latency). `t0`/`t1`
-    are perf_counter readings. Returns the span's context."""
+    are perf_counter readings. `ctx` is the span's own context where
+    `child_context` allocated it ahead. Returns the span's context."""
     if not _state.enabled:
         return None
-    if parent is None:
-        parent = capture()
-    if parent is not None:
-        ctx = TraceContext(parent.trace_id, _next_id())
-    else:
-        ctx = TraceContext(_next_id(), _next_id())
+    if ctx is None:
+        if parent is None:
+            parent = capture()
+        ctx = _child_of(parent)
     dur = max(0.0, t1 - t0)
     _observe(name, dur)
     # same ring-admission sampling as span() exit — SampleEvery must
@@ -476,6 +591,37 @@ def observe_stage(stage: str, seconds: float) -> None:
     if not _state.enabled:
         return
     _observe(stage, seconds)
+
+
+# ---------------------------------------------------------------------------
+# the collector: a full pass stops every thread, so it is a span
+# ---------------------------------------------------------------------------
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """`gc.callbacks` entry: one `runtime.gc` span per generation-2
+    collection, under whatever span the collecting thread was in.
+    Generations 0 and 1 run thousands of times a block and are not
+    recorded."""
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _state.gc_t0 = time.perf_counter()
+    elif _state.gc_t0 is not None:
+        t0, _state.gc_t0 = _state.gc_t0, None
+        observe_span("runtime.gc", t0, time.perf_counter(),
+                     collected=info["collected"])
+
+
+def _sync_gc_hook() -> None:
+    """The hook is installed exactly while tracing is enabled."""
+    installed = _gc_hook in gc.callbacks
+    if _state.enabled and not installed:
+        gc.callbacks.append(_gc_hook)
+    elif not _state.enabled and installed:
+        gc.callbacks.remove(_gc_hook)
+
+
+_sync_gc_hook()
 
 
 # ---------------------------------------------------------------------------
@@ -653,6 +799,14 @@ def snapshot() -> list:
         cut = idx % n
         events = ring[cut:] + ring[:cut]
     return [e for e in events if e is not None]
+
+
+def dropped() -> int:
+    """How many events the ring has overwritten since it was last
+    sized or reset: a reader of `snapshot()` refuses a window that
+    starts before the oldest event still held."""
+    with _state.ring_lock:
+        return max(0, _state.ring_idx - len(_state.ring))
 
 
 def trace_stages(trace_id: str) -> list:

@@ -109,10 +109,13 @@ class _Item:
     # sequential-fallback demotion (stage-A failure)
     fallback: bool = False
     verified: bool = False       # mcs.verify_block already passed
-    # trace context captured at submit (the feeder's ambient one):
-    # the validate/commit spans keep the block's trace_id across both
-    # worker threads
+    # the block's own `peer.block` span, allocated at submit under
+    # the feeder's ambient context (`parent_tctx`) and recorded once
+    # the block is committed: the validate/commit spans on the two
+    # worker threads are its children, as at Depth 0
     tctx: object = None
+    parent_tctx: object = None
+    t_submit: float = 0.0
 
 
 class CommitPipeline:
@@ -270,9 +273,11 @@ class CommitPipeline:
                     (abort is not None and abort.is_set()):
                 raise CommitPipelineError(
                     seq, "verify", RuntimeError("pipeline stopped"))
-            self._intake.append(_Item(seq=seq, epoch=self._epoch,
-                                      raw=raw, block=block,
-                                      tctx=tracing.capture()))
+            parent = tracing.capture()
+            self._intake.append(_Item(
+                seq=seq, epoch=self._epoch, raw=raw, block=block,
+                tctx=tracing.child_context(parent) or parent,
+                parent_tctx=parent, t_submit=time.perf_counter()))
             self._inflight += 1
             self._next_seq = seq + 1
             self.stats["submitted"] += 1
@@ -518,7 +523,10 @@ class CommitPipeline:
             known = [t for txids in self._inflight_txids.values()
                      for t in txids]
 
-        tx_ids = self.channel.ledger.block_store.block_tx_ids(block)
+        n = len(block.data.data)
+        with tracing.span("intake.txids", txs=n):
+            tx_ids = self.channel.ledger.block_store.block_tx_ids(
+                block)
         result = self.channel.validator.validate_ahead(
             block, known_txids=known)
         is_config = pu.is_config_block(block)
@@ -527,7 +535,8 @@ class CommitPipeline:
         if is_config or block.header.number == 0:
             barrier_reason = "config"
         else:
-            rwsets = [extract_tx_rwset(e) for e in block.data.data]
+            with tracing.span("intake.rwsets", txs=n):
+                rwsets = [extract_tx_rwset(e) for e in block.data.data]
             if result.vp_dirty:
                 barrier_reason = "vp_update"
             elif self._touches_lifecycle(rwsets, result.codes):
@@ -604,6 +613,13 @@ class CommitPipeline:
                                  item.seq)
                 self._fail_locked(item, "commit", e)
             t1 = time.perf_counter()
+            if item.tctx is not item.parent_tctx:
+                tracing.observe_span(
+                    "peer.block", item.t_submit, t1,
+                    parent=item.parent_tctx, ctx=item.tctx,
+                    block=item.seq,
+                    txs=len(item.block.data.data)
+                    if item.block is not None else 0)
             with self._cond:
                 self._committing = None
                 self._commit_active_since = None

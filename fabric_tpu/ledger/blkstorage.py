@@ -14,6 +14,7 @@ import struct
 from typing import Iterator, Optional
 
 from fabric_tpu import protoutil as pu
+from fabric_tpu.common import tracing
 from fabric_tpu.ledger.kvdb import DBHandle
 from fabric_tpu.protos import common, transaction as txpb
 
@@ -130,10 +131,11 @@ class BlockStore:
 
     # -- writes --
 
-    def add_block(self, block: common.Block, tx_ids=None) -> None:
+    def add_block(self, block: common.Block, tx_ids=None) -> int:
         """`tx_ids` optionally reuses the intake path's single tx-id
         scan (`block_tx_ids`) so the index build does not re-scan
-        every envelope — the measured commit floor at 10k-tx blocks."""
+        every envelope — the measured commit floor at 10k-tx blocks.
+        Returns the bytes appended to the block file."""
         if block.header.number != self._height:
             raise BlockStoreError(
                 f"expected block {self._height}, got {block.header.number}")
@@ -141,20 +143,28 @@ class BlockStore:
                 block.header.previous_hash != self._last_hash:
             raise BlockStoreError(
                 f"block {block.header.number} previous_hash mismatch")
-        raw = pu.marshal(block)
-        if self._f.tell() + 4 + len(raw) > _MAX_FILE and self._f.tell() > 0:
-            self._f.close()
-            self._cur_suffix += 1
-            self._f = open(self._cur_path(), "ab")
-        offset = self._f.tell()
-        self._f.write(_LEN.pack(len(raw)))
-        self._f.write(raw)
-        self._f.flush()
-        os.fsync(self._f.fileno())
+        append = tracing.span("blockstore.append", fsyncs=1)
+        with append:
+            raw = pu.marshal(block)
+            if self._f.tell() + 4 + len(raw) > _MAX_FILE and \
+                    self._f.tell() > 0:
+                self._f.close()
+                self._cur_suffix += 1
+                self._f = open(self._cur_path(), "ab")
+            offset = self._f.tell()
+            self._f.write(_LEN.pack(len(raw)))
+            self._f.write(raw)
+            self._f.flush()
+            os.fsync(self._f.fileno())
+            append.set(bytes=4 + len(raw))
         self._height = block.header.number + 1
         self._last_hash = pu.block_header_hash(block.header)
-        self._index_block(block, self._cur_suffix, offset,
-                          self._f.tell(), tx_ids=tx_ids)
+        index = tracing.span("blockstore.index")
+        with index:
+            index.set(rows=self._index_block(
+                block, self._cur_suffix, offset, self._f.tell(),
+                tx_ids=tx_ids))
+        return 4 + len(raw)
 
     def block_tx_ids(self, block: common.Block) -> list:
         """Public tx-id scan over a NOT-yet-stored block: the commit
@@ -188,7 +198,8 @@ class BlockStore:
 
     def _index_block(self, block: common.Block, suffix: int,
                      offset: int, end_offset: int,
-                     tx_ids=None) -> None:
+                     tx_ids=None) -> int:
+        """Returns the index rows written."""
         batch = self._index.new_batch()
         loc = struct.pack(">IQ", suffix, offset)
         batch.put(b"n" + struct.pack(">Q", block.header.number), loc)
@@ -227,6 +238,7 @@ class BlockStore:
                               block.header.number + 1) +
                   pu.block_header_hash(block.header))
         self._index.write_batch(batch)
+        return len(batch.ops)
 
     # -- reads --
 

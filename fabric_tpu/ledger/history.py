@@ -30,7 +30,8 @@ class HistoryDB:
                 struct.pack(">QQ", block, tx))
 
     def commit_block(self, block: common.Block,
-                     codes: list[int]) -> None:
+                     codes: list[int]) -> int:
+        """Returns the rows written (one per key a valid tx wrote)."""
         batch = self._db.new_batch()
         for tx_num, env_bytes in enumerate(block.data.data):
             if codes[tx_num] != txpb.TxValidationCode.VALID:
@@ -48,6 +49,7 @@ class HistoryDB:
                     batch.put(self._k(nsrw.namespace, w.key,
                                       block.header.number, tx_num), b"")
         self._db.write_batch(batch)
+        return len(batch.ops)
 
     def get_history_for_key(self, block_store: BlockStore, ns: str,
                             key: str) -> Iterator[dict]:

@@ -12,11 +12,10 @@ from __future__ import annotations
 
 import hashlib
 import os
-import time
 from typing import Callable, Optional, Sequence
 
 from fabric_tpu import protoutil as pu
-from fabric_tpu.common import metrics as metrics_mod
+from fabric_tpu.common import metrics as metrics_mod, tracing
 from fabric_tpu.common.flogging import must_get_logger
 from fabric_tpu.ledger import pvtdata as pvt
 from fabric_tpu.ledger.blkstorage import BlockStore
@@ -309,64 +308,83 @@ class KVLedger:
         list and tx-id scan from the intake path (one decode pass per
         block instead of one per layer). Returns final per-tx
         validation codes."""
-        t0 = time.perf_counter()
         n = len(block.data.data)
         block_num = block.header.number
+        # one span per stretch, each boundary's clock read once: the
+        # spans' own readings feed the histograms and the log line
+        # below (with tracing disabled `timed` still reads the clock)
+        mvcc = tracing.timed("ledger.mvcc", txs=n)
+        with mvcc:
+            is_config = self._is_config_block(block)
+            if is_config or block_num == 0:
+                codes = list(flags) if flags else \
+                    [txpb.TxValidationCode.VALID] * n
+                batch = None
+            else:
+                if rwsets is None:
+                    rwsets = [extract_tx_rwset(e)
+                              for e in block.data.data]
+                reads0 = self.txmgr.reads_checked
+                codes, batch = self.txmgr.validate_and_prepare(
+                    block_num, rwsets,
+                    list(flags) if flags else None)
+                self._commit_pvt_data(block_num, rwsets, codes,
+                                      pvt_data or {}, batch)
+                mvcc.set(valid=codes.count(txpb.TxValidationCode.VALID),
+                         reads=self.txmgr.reads_checked - reads0,
+                         writes=len(batch.updates))
 
-        is_config = self._is_config_block(block)
-        if is_config or block_num == 0:
-            codes = list(flags) if flags else \
-                [txpb.TxValidationCode.VALID] * n
-            batch = None
-        else:
-            if rwsets is None:
-                rwsets = [extract_tx_rwset(e) for e in block.data.data]
-            codes, batch = self.txmgr.validate_and_prepare(
-                block_num, rwsets,
-                list(flags) if flags else None)
-            self._commit_pvt_data(block_num, rwsets, codes,
-                                  pvt_data or {}, batch)
+            # TRANSACTIONS_FILTER: one code byte per tx
+            block.metadata.metadata[
+                common.BlockMetadataIndex.TRANSACTIONS_FILTER] = \
+                bytes(codes)
+            # commit-hash chain (reference kv_ledger.go commitHash);
+            # only adopted in-memory once add_block accepts the block,
+            # so a rejected block (wrong number / previous_hash) cannot
+            # poison the chain
+            new_commit_hash = hashlib.sha256(
+                self._commit_hash + bytes(codes) +
+                block.header.data_hash).digest()
+            block.metadata.metadata[
+                common.BlockMetadataIndex.COMMIT_HASH] = new_commit_hash
 
-        # TRANSACTIONS_FILTER: one code byte per tx
-        block.metadata.metadata[
-            common.BlockMetadataIndex.TRANSACTIONS_FILTER] = bytes(codes)
-        # commit-hash chain (reference kv_ledger.go commitHash); only
-        # adopted in-memory once add_block accepts the block, so a
-        # rejected block (wrong number / previous_hash) cannot poison
-        # the chain
-        new_commit_hash = hashlib.sha256(
-            self._commit_hash + bytes(codes) +
-            block.header.data_hash).digest()
-        block.metadata.metadata[common.BlockMetadataIndex.COMMIT_HASH] = \
-            new_commit_hash
+        store = tracing.timed("ledger.blockstore")
+        with store:
+            store.set(bytes=self.block_store.add_block(
+                block, tx_ids=tx_ids))
+            self._commit_hash = new_commit_hash
 
-        t1 = time.perf_counter()
-        self.block_store.add_block(block, tx_ids=tx_ids)
-        self._commit_hash = new_commit_hash
-        t2 = time.perf_counter()
-
-        # history BEFORE the statedb savepoint: its puts are idempotent
-        # empty entries, so a crash in between is healed by replay —
-        # the reverse order would permanently lose block N's history
+        state = tracing.timed("ledger.state")
         if batch is not None:
-            self.history_db.commit_block(block, codes)
-            # listeners BEFORE the savepoint advances: a crash in
-            # between is healed by replay re-notifying (idempotent
-            # writes); the reverse order would lose block N's
-            # confighistory forever (recovery starts above the
-            # savepoint)
-            self._notify_state_listeners(block_num, batch)
-            self.state_db.apply_updates(batch,
-                                        Height(block_num, max(n - 1, 0)))
-            # bookkeeping for purged entries is dropped only AFTER the
-            # state deletes are durable: a crash in between re-purges
-            # (idempotent) on the next commit instead of leaking keys
-            self._drop_expired_bookkeeping(block_num)
+            # history BEFORE the statedb savepoint: its puts are
+            # idempotent empty entries, so a crash in between is
+            # healed by replay — the reverse order would permanently
+            # lose block N's history
+            history = tracing.span("ledger.history")
+            with history:
+                history.set(rows=self.history_db.commit_block(block,
+                                                              codes))
+            with state:
+                state.set(rows=len(batch.updates))
+                # listeners BEFORE the savepoint advances: a crash in
+                # between is healed by replay re-notifying (idempotent
+                # writes); the reverse order would lose block N's
+                # confighistory forever (recovery starts above the
+                # savepoint)
+                self._notify_state_listeners(block_num, batch)
+                self.state_db.apply_updates(
+                    batch, Height(block_num, max(n - 1, 0)))
+                # bookkeeping for purged entries is dropped only AFTER
+                # the state deletes are durable: a crash in between
+                # re-purges (idempotent) on the next commit instead of
+                # leaking keys
+                self._drop_expired_bookkeeping(block_num)
         else:
             # config/genesis blocks still advance the savepoint
-            self.state_db.apply_updates(UpdateBatch(),
-                                        Height(block_num, 0))
-        t3 = time.perf_counter()
+            with state:
+                self.state_db.apply_updates(UpdateBatch(),
+                                            Height(block_num, 0))
+        t0, t1, t2, t3 = mvcc.t0, mvcc.t1, store.t1, state.t1
 
         self._maybe_generate_snapshots()
         self._m_block_time.observe(t3 - t0)

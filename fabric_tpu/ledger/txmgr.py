@@ -324,6 +324,9 @@ class TxMgr:
 
     def __init__(self, statedb: StateDB):
         self.statedb = statedb
+        # cumulative: key reads MVCC has checked (the `ledger.mvcc`
+        # span books a block's share)
+        self.reads_checked = 0
 
     def validate_and_prepare(
         self, block_num: int,
@@ -358,6 +361,7 @@ class TxMgr:
         for nsrw in txrw.ns_rwset:
             kv = rwpb.KVRWSet()
             kv.ParseFromString(nsrw.rwset)
+            self.reads_checked += len(kv.reads)
             for read in kv.reads:
                 if not self._validate_read(nsrw.namespace, read, batch):
                     return txpb.TxValidationCode.MVCC_READ_CONFLICT

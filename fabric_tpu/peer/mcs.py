@@ -13,6 +13,7 @@ import logging
 
 from fabric_tpu.protos import common
 from fabric_tpu.protoutil import protoutil as pu
+from fabric_tpu.common import tracing
 from fabric_tpu.common.policies import policy as papi
 
 logger = logging.getLogger("peer.mcs")
@@ -35,6 +36,14 @@ class MSPMessageCryptoService:
         """Reference mcs.go:123: structural checks, header-number match,
         data-hash integrity, then the BlockValidation policy over the
         orderer signatures."""
+        # (the envelopes' bytes: `ByteSize()` of a 1.6 MB block costs
+        # 2.5 ms, this sum 10 us)
+        with tracing.span("peer.verify_block", block=seq_num,
+                          bytes=sum(map(len, block.data.data))):
+            self._verify_block(channel_id, seq_num, block)
+
+    def _verify_block(self, channel_id: str, seq_num: int,
+                      block: common.Block) -> None:
         if not block.HasField("header"):
             raise BlockVerificationError(
                 f"invalid block on [{channel_id}]: no header")

@@ -17,6 +17,7 @@ from typing import Callable, Optional
 
 from fabric_tpu.protos import common, configtx as ctxpb, transaction as txpb
 from fabric_tpu.protoutil import protoutil as pu
+from fabric_tpu.common import tracing
 from fabric_tpu.common.channelconfig import Bundle
 from fabric_tpu.common.configtx import Validator as ConfigTxValidator
 from fabric_tpu.internal.configtxgen import genesis as genesis_mod
@@ -212,14 +213,23 @@ class Channel:
         final tx codes. Reference: gossip/state deliverPayloads →
         coordinator.StoreBlock (`gossip/privdata/coordinator.go:152`,
         SURVEY §3.4)."""
-        flags = self.validator.validate(block)
-        rwsets = None
-        if not pu.is_config_block(block) and block.header.number != 0:
-            from fabric_tpu.ledger.kvledger import extract_tx_rwset
-            rwsets = [extract_tx_rwset(e) for e in block.data.data]
-        tx_ids = self.ledger.block_store.block_tx_ids(block)
-        return self.commit_validated(block, flags, rwsets=rwsets,
-                                     tx_ids=tx_ids)
+        n = len(block.data.data)
+        num = block.header.number
+        with tracing.span("peer.block", block=num, txs=n):
+            with tracing.span("commit.validate", block=num, txs=n):
+                flags = self.validator.validate(block)
+            rwsets = None
+            if not pu.is_config_block(block) and num != 0:
+                from fabric_tpu.ledger.kvledger import extract_tx_rwset
+                with tracing.span("intake.rwsets", txs=n):
+                    rwsets = [extract_tx_rwset(e)
+                              for e in block.data.data]
+            with tracing.span("intake.txids", txs=n):
+                tx_ids = self.ledger.block_store.block_tx_ids(block)
+            with tracing.span("commit.commit", block=num, txs=n):
+                return self.commit_validated(block, flags,
+                                             rwsets=rwsets,
+                                             tx_ids=tx_ids)
 
     def commit_validated(self, block: common.Block, flags: list[int],
                          rwsets=None, tx_ids=None) -> list[int]:
@@ -227,21 +237,30 @@ class Channel:
         commit → purge → notify, with the validation verdicts (and
         optionally the parsed rwsets + scanned tx-ids — each envelope
         decoded exactly once per block) already in hand. The commit
-        pipeline calls this for block N while block N+1 validates."""
-        import time as _t
-        t0 = _t.perf_counter()
-        pvt_data, committed_txids = self._gather_pvt_data(
-            block, flags, rwsets=rwsets, tx_ids=tx_ids)
-        t1 = _t.perf_counter()
+        pipeline calls this for block N while block N+1 validates.
+        Each boundary's clock is read once, by the span that starts or
+        ends there; the three privdata histograms read the spans."""
+        n = len(block.data.data)
+        pull = tracing.timed("commit.pvt", txs=n)
+        with pull:
+            pvt_data, committed_txids = self._gather_pvt_data(
+                block, flags, rwsets=rwsets, tx_ids=tx_ids)
         codes = self.committer.commit(block, flags, pvt_data=pvt_data,
                                       rwsets=rwsets, tx_ids=tx_ids)
-        t2 = _t.perf_counter()
+        purge = None
         if committed_txids:
-            self._peer.transient_store.purge_by_txids(committed_txids)
-            self._m_pvt_purge.observe(_t.perf_counter() - t2)
-        self._m_pvt_pull.observe(t1 - t0)
-        self._m_pvt_commit.observe(t2 - t1)
-        self._notify_commit(block, codes, tx_ids=tx_ids)
+            purge = tracing.timed("commit.pvt",
+                                  txs=len(committed_txids))
+            with purge:
+                self._peer.transient_store.purge_by_txids(
+                    committed_txids)
+            self._m_pvt_purge.observe(purge.seconds)
+        notify = tracing.timed("commit.notify", txs=n)
+        with notify:
+            self._notify_commit(block, codes, tx_ids=tx_ids)
+        self._m_pvt_pull.observe(pull.seconds)
+        self._m_pvt_commit.observe(
+            (purge or notify).t0 - pull.t1)
         return codes
 
     def _gather_pvt_data(self, block: common.Block, flags: list[int],

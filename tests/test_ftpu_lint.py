@@ -365,6 +365,77 @@ class TestSpanCoverage:
             lint.REQUIRED_SPANS["fabric_tpu/orderer/raft/chain.py"]
 
 
+class TestBlockIntakeSpanRegistry:
+    """PR 27: the benchmark's per-layer metrics read the block-intake
+    spans by name, so the functions that open them are registered: a
+    rename (or a dropped span) fails the lint instead of blinding a
+    metric."""
+
+    INTAKE = {
+        "fabric_tpu/peer/peer.py": ("process_block", "commit_validated"),
+        "fabric_tpu/ledger/kvledger.py": ("commit_block",),
+        "fabric_tpu/ledger/blkstorage.py": ("add_block",),
+        "fabric_tpu/core/fastvalidate.py": ("validate_fast",),
+        "fabric_tpu/bccsp/tpu.py": ("_verify_prepared_device",),
+    }
+
+    @pytest.mark.parametrize("path", sorted(INTAKE))
+    def test_registered(self, lint, path):
+        for fn in self.INTAKE[path]:
+            assert fn in lint.REQUIRED_SPANS[path], (path, fn)
+
+    def _seed(self, root, body: str):
+        ledger = os.path.join(root, "fabric_tpu", "ledger")
+        os.makedirs(ledger, exist_ok=True)
+        open(os.path.join(ledger, "__init__.py"), "w").close()
+        with open(os.path.join(ledger, "kvledger.py"), "w") as f:
+            f.write(textwrap.dedent(body))
+
+    def _findings(self, lint, root):
+        return [f for f in lint.run_lint(root, rules=("span-coverage",))
+                if f.path.endswith("kvledger.py")]
+
+    def test_a_timed_span_satisfies_the_rule(self, lint, tmp_path):
+        root = _seed_tree(str(tmp_path))
+        self._seed(root, '''\
+            from fabric_tpu.common import tracing
+
+            def commit_block(block):
+                t = tracing.timed("ledger.mvcc")
+                with t:
+                    pass
+                return t.seconds
+            ''')
+        assert self._findings(lint, root) == []
+
+    def test_a_stage_that_lost_its_span_is_a_finding(self, lint,
+                                                     tmp_path):
+        root = _seed_tree(str(tmp_path))
+        self._seed(root, '''\
+            import time
+
+            def commit_block(block):
+                t0 = time.perf_counter()
+                return time.perf_counter() - t0
+            ''')
+        findings = self._findings(lint, root)
+        assert len(findings) == 1 and "commit_block" in findings[0].message
+
+    def test_a_renamed_stage_reports_registry_drift(self, lint,
+                                                    tmp_path):
+        root = _seed_tree(str(tmp_path))
+        self._seed(root, '''\
+            from fabric_tpu.common import tracing
+
+            def commit_block_v2(block):
+                with tracing.span("ledger.mvcc"):
+                    pass
+            ''')
+        findings = self._findings(lint, root)
+        assert len(findings) == 1
+        assert "REQUIRED_SPANS" in findings[0].message
+
+
 class TestUnboundedQueueRule:
     """Round-12 rule: creating an unbounded queue.Queue anywhere in
     fabric_tpu/ is a finding — the overload-protection layer closed

@@ -384,3 +384,344 @@ class TestChaosTracing:
         for f in ("order_propose_p50_s", "order_write_p99_s",
                   "validate_p50_s", "commit_p99_s"):
             assert out[f] and out[f] > 0, (f, out[f])
+
+
+# ---------------------------------------------------------------------------
+# the block-intake span tree (PR 27): peer -> validation -> provider ->
+# ledger, the same names at every pipeline depth, a fixed number of
+# spans a block
+# ---------------------------------------------------------------------------
+
+# span -> its parent span, at Depth 0 (docs/metrics_reference.md,
+# "Block-intake spans"; the stand-in provider opens no tpu.*)
+INTAKE_TREE = {
+    "peer.block": "test.deliver",
+    "peer.verify_block": "test.deliver",
+    "commit.validate": "peer.block",
+    "validate.prep": "commit.validate",
+    "validate.policy": "commit.validate",
+    "validate.flags": "commit.validate",
+    "intake.rwsets": "peer.block",
+    "intake.txids": "peer.block",
+    "commit.commit": "peer.block",
+    "commit.pvt": "commit.commit",
+    "ledger.mvcc": "commit.commit",
+    "ledger.blockstore": "commit.commit",
+    "blockstore.append": "ledger.blockstore",
+    "blockstore.index": "ledger.blockstore",
+    "ledger.history": "commit.commit",
+    "ledger.state": "commit.commit",
+    "commit.notify": "commit.commit",
+}
+# Depth > 0: stage A verifies, scans and parses inside its own span
+PIPELINED_TREE = dict(INTAKE_TREE, **{
+    "peer.verify_block": "commit.validate",
+    "intake.rwsets": "commit.validate",
+    "intake.txids": "commit.validate"})
+
+PROVIDER_SPANS = ("tpu.verify", "tpu.stage", "tpu.comb_digest",
+                  "tpu.tables", "tpu.h2d", "tpu.enqueue", "tpu.wait",
+                  "tpu.readback")
+
+
+def _intake_run(monkeypatch, block_txs: int, depth: int = 0,
+                enabled: bool = True):
+    """A tiny chain through a real peer (the benchmark's harness with
+    its stand-in provider), every hand-over under one `test.deliver`
+    span. Returns the recorder's events."""
+    pytest.importorskip("jax")
+    from fabric_tpu import native
+    if not native.available():
+        pytest.skip("the native block-prep library cannot be built here")
+    import argparse
+
+    from benchmark import run, sut
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = manifest["workloads"][0]
+    with open(os.path.join(root, manifest["configs"][0]["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           cell["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    config["orderer"]["BatchSize"]["MaxMessageCount"] = block_txs
+    config["peer"]["CommitPipelineDepth"] = depth
+    # nine blocks on supply, twice what 0.02 s hold at either size
+    traffic["loop"] = {"kind": "closed",
+                       "supply_tx_per_s": 400 * block_txs}
+    traffic["warmup_blocks"] = 1
+    traffic["transactions"].update(keys=4 * block_txs, tampered_share=0.1)
+
+    def hand_over(self, block):
+        with tracing.span("test.deliver"):
+            if depth:
+                pipeline = self.channel.commit_pipeline
+                pipeline.submit(block.header.number, block=block)
+                pipeline.drain(timeout=60)
+            else:
+                self.mcs.verify_block(sut.CHANNEL, block.header.number,
+                                      block)
+                self.channel.process_block(block)
+    monkeypatch.setattr(sut.Intake, "hand_over", hand_over)
+    tracing.configure(enabled=enabled, ring_size=8192, sample_every=1)
+    tracing.reset()
+    try:
+        args = argparse.Namespace(
+            workload=cell["name"], seed=2 ** 31 + 27, seconds=0.02,
+            trace=0, control="", rehearse=True, workers=0)
+        rc, result = run.execute(manifest, cell, config, traffic, args)
+        assert rc == 4 and result["correct"] is True
+        return tracing.snapshot()
+    finally:
+        tracing.configure(enabled=True, ring_size=4096)
+        tracing.reset()
+
+
+def _trees(events):
+    """[{span name: [parent name, ...]}] per `test.deliver` root that
+    held an endorser block, from the recorder's own parent ids."""
+    by_id = {e[3]: e for e in events}
+    out = {}
+    for e in events:
+        if e[0] != "X":
+            continue
+        parent = by_id.get(e[4])
+        out.setdefault(e[2], {}).setdefault(e[1], []).append(
+            parent[1] if parent is not None else None)
+    return [t for t in out.values()
+            if "test.deliver" in t and "intake.rwsets" in t]
+
+
+class TestBlockIntakeTree:
+    @pytest.mark.parametrize("depth, want", [(0, INTAKE_TREE),
+                                             (1, PIPELINED_TREE)])
+    def test_one_tree_per_block_same_names_at_every_depth(
+            self, monkeypatch, depth, want):
+        trees = _trees(_intake_run(monkeypatch, 8, depth=depth))
+        assert len(trees) >= 3
+        for tree in trees:
+            # every span of the block hangs under the one trace, each
+            # below the parent the registry names
+            got = {name: set(parents) for name, parents in tree.items()
+                   if name not in ("test.deliver", "runtime.gc")}
+            assert got == {k: {v} for k, v in want.items()}
+
+    def test_spans_per_block_do_not_grow_with_its_transactions(
+            self, monkeypatch):
+        def per_block(block_txs):
+            trees = _trees(_intake_run(monkeypatch, block_txs))
+            counts = {sum(len(p) for n, p in t.items()
+                          if n != "runtime.gc") for t in trees}
+            assert len(counts) == 1, counts
+            return counts.pop()
+        small, large = per_block(8), per_block(64)
+        assert small == large == len(INTAKE_TREE) + 1
+
+    def test_disabled_intake_records_nothing(self, monkeypatch):
+        assert _intake_run(monkeypatch, 8, enabled=False) == []
+
+    def test_no_program_span_shadows_a_benchmark_annotation(self):
+        """Both land in the same profiler trace: a shared name would
+        put the program's span into the benchmark's own metrics."""
+        from benchmark import tracered
+        names = set(INTAKE_TREE) | set(PROVIDER_SPANS) | {"runtime.gc"}
+        assert not names & set(tracered.HOST_SPANS)
+
+
+class TestProviderSpans:
+    """The prepared-block path with the device math stubbed (the
+    tests/test_chaos.py idiom): real staging, transfer and readback."""
+
+    @staticmethod
+    def _provider(monkeypatch, **kw):
+        import numpy as np
+
+        from fabric_tpu.bccsp.tpu import TPUProvider
+        tpu = TPUProvider(min_batch=4, use_g16=False, **kw)
+        monkeypatch.setattr(
+            tpu, "_qtab_fn",
+            lambda K: lambda qx, qy: np.zeros((K,), dtype=np.int32))
+        monkeypatch.setattr(
+            tpu, "_comb_pipeline_digest",
+            lambda K, q16=False: lambda key_idx, q_flat, g16, r8, rpn8,
+            w8, premask, digests: premask)
+        return tpu
+
+    @staticmethod
+    def _prepared(n):
+        import numpy as np
+
+        from fabric_tpu.bccsp.sw import SWProvider
+        from fabric_tpu.bccsp import bccsp as api
+        key = SWProvider().key_gen(api.ECDSAKeyGenOpts(
+            ephemeral=True))
+        z = np.zeros((n, 32), dtype=np.uint8)
+        der_ok = np.ones(n, dtype=bool)
+        der_ok[1] = False
+        return (z, z, z, z, der_ok, np.zeros(n, dtype=np.int32),
+                [key], lambda i: b"")
+
+    def test_one_provider_call_opens_the_registered_spans(
+            self, trace_env, monkeypatch):
+        pytest.importorskip("jax")
+        tpu = self._provider(monkeypatch)
+        for n in (8, 24):
+            tracing.reset()
+            before = dict(tpu.stats)
+            with tracing.span("commit.validate") as root:
+                resolve = tpu.verify_prepared_start(*self._prepared(n))
+                with tracing.span("validate.flags"):
+                    out = resolve()
+            assert out == [i != 1 for i in range(n)]
+            evs = [e for e in tracing.snapshot() if e[2] == root.trace_id]
+            by_id = {e[3]: e[1] for e in evs}
+            parents = {}
+            for e in evs:
+                parents.setdefault(e[1], set()).add(by_id.get(e[4]))
+            # the same spans whatever the batch holds
+            assert sorted(e[1] for e in evs) == sorted(
+                ["commit.validate", "validate.flags", "tpu.verify",
+                 "tpu.verify", "tpu.stage", "tpu.comb_digest",
+                 "tpu.tables", "tpu.h2d", "tpu.enqueue", "tpu.wait",
+                 "tpu.readback", "tpu.readback"])
+            assert parents["tpu.stage"] == {"tpu.verify"}
+            assert parents["tpu.comb_digest"] == {"tpu.verify"}
+            for name in ("tpu.tables", "tpu.h2d", "tpu.enqueue"):
+                assert parents[name] == {"tpu.comb_digest"}
+            assert parents["tpu.wait"] == {"tpu.verify"}
+            assert parents["tpu.verify"] == {"commit.validate",
+                                             "validate.flags"}
+            stage = _events("tpu.stage")[0][8]
+            bucket = stage["bucket"]
+            assert stage == {"lanes": n, "bucket": bucket, "keys": 1}
+            h2d = _events("tpu.h2d")[0][8]
+            assert h2d["chunk"] == 0 and h2d["bytes"] > 0
+            # the counters the spans' attrs are booked beside
+            assert tpu.stats["lanes_real"] - before["lanes_real"] == n
+            assert tpu.stats["lanes_padded"] - before["lanes_padded"] \
+                == bucket
+            assert tpu.stats["h2d_bytes"] - before["h2d_bytes"] \
+                == h2d["bytes"]
+            # one set of clock reads feeds the span and the gauge
+            assert tpu.stats["prepared_transfer_s"] == round(
+                _events("tpu.h2d")[0][6], 6)
+
+    def test_the_gauges_read_the_same_with_tracing_off(
+            self, trace_env, monkeypatch):
+        pytest.importorskip("jax")
+        tpu = self._provider(monkeypatch)
+        tracing.set_enabled(False)
+        try:
+            assert tpu.verify_prepared(*self._prepared(8)) == \
+                [i != 1 for i in range(8)]
+        finally:
+            tracing.set_enabled(True)
+        assert tracing.snapshot() == []
+        assert tpu.stats["prepared_transfer_s"] > 0
+        assert tpu.stats["prepared_device_s"] > 0
+        assert tpu.stats["lanes_real"] == 8
+
+    def test_jit_names_the_program_after_its_kind(self, monkeypatch):
+        pytest.importorskip("jax")
+        import jax.numpy as jnp
+
+        from fabric_tpu.bccsp.tpu import TPUProvider
+        tpu = TPUProvider(min_batch=4, use_g16=False)
+
+        def fused(x, n):
+            return x * n
+        fn = tpu._jit("comb_digest", fused, static_argnums=1)
+        assert int(fn(jnp.int32(3), 2)) == 6
+        assert "jit_comb_digest" in fn._fn.lower(
+            jnp.int32(3), 2).as_text()[:200]
+        assert fused.__name__ == "fused"     # the caller's is untouched
+
+
+class TestSpanReadings:
+    def test_timed_hands_back_its_own_readings(self, trace_env):
+        t = tracing.timed("ledger.history", rows=3)
+        with t:
+            time.sleep(0.002)
+        ev = _events("ledger.history")[0]
+        assert (ev[5], ev[6]) == (t.t0, t.seconds)
+        assert t.seconds == t.t1 - t.t0 >= 0.002
+
+    def test_timed_reads_the_clock_with_tracing_off(self, trace_env):
+        tracing.set_enabled(False)
+        try:
+            t = tracing.timed("ledger.history")
+            with t:
+                time.sleep(0.002)
+            t.set(rows=1)
+            assert t.seconds >= 0.002
+            assert not isinstance(t, type(tracing.span("x"))) and \
+                not hasattr(t, "ctx")       # no span was built
+            assert tracing.snapshot() == []
+        finally:
+            tracing.set_enabled(True)
+
+    def test_set_adds_counts_known_afterwards(self, trace_env):
+        sp = tracing.span("validate.prep", txs=5)
+        with sp:
+            sp.set(lanes=15)
+        assert _events("validate.prep")[0][8] == {"txs": 5, "lanes": 15}
+
+    def test_dropped_counts_what_the_ring_overwrote(self, trace_env):
+        assert tracing.dropped() == 0
+        for i in range(300):
+            tracing.instant("tick", i=i)
+        assert tracing.dropped() == 300 - 256
+        tracing.reset()
+        assert tracing.dropped() == 0
+
+    def test_a_context_allocated_ahead_parents_its_children(
+            self, trace_env):
+        with tracing.span("deliver") as outer:
+            ahead = tracing.child_context()
+        with tracing.span("commit.validate", parent=ahead):
+            pass
+        tracing.observe_span("peer.block", 1.0, 2.0, parent=outer,
+                             ctx=ahead, block=7)
+        block = _events("peer.block")[0]
+        assert (block[2], block[3], block[4]) == (
+            outer.trace_id, ahead.span_id, outer.span_id)
+        assert _events("commit.validate")[0][4] == ahead.span_id
+
+    def test_full_collections_are_spans_young_ones_are_not(
+            self, trace_env):
+        import gc
+        with tracing.span("peer.block") as ctx:
+            gc.collect(0)
+            gc.collect(1)
+            gc.collect()
+        evs = _events("runtime.gc")
+        assert len(evs) == 1
+        assert evs[0][4] == ctx.span_id and "collected" in evs[0][8]
+        tracing.set_enabled(False)
+        try:
+            assert tracing._gc_hook not in gc.callbacks
+        finally:
+            tracing.set_enabled(True)
+        assert gc.callbacks.count(tracing._gc_hook) == 1
+
+    def test_spans_reach_the_profiler_once_jax_is_imported(
+            self, trace_env, tmp_path):
+        """A capture (`/debug/jax/trace`, or the benchmark's) shows the
+        program's spans on a host line, by name."""
+        jax = pytest.importorskip("jax")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracing.span("peer.block", block=1):
+                with tracing.span("ledger.state"):
+                    time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        import glob
+        path = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+        data = jax.profiler.ProfileData.from_file(path)
+        names = {ev.name for plane in data.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert {"peer.block", "ledger.state"} <= names

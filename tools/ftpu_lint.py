@@ -52,7 +52,7 @@ on the flagged line or the line above; the reason is mandatory):
                  REQUIRED_HOT_PATHS dispatch spans plus the pipeline
                  stage workers) must open a lifecycle tracing span —
                  a `@traced("...")` decorator or a
-                 span/observe_span/observe_stage/instant call
+                 span/timed/observe_span/observe_stage/instant call
                  (common/tracing.py). Dropping it silently blinds the
                  flight recorder and the per-stage histograms on
                  exactly the code they were written for (no waiver:
@@ -141,6 +141,16 @@ for _path, _funcs in {
     # transitively: it is itself a recognized span-opening call, so a
     # seam that drops it trips the entries above)
     "fabric_tpu/common/clustertrace.py": ("note_commit",),
+    # the block-intake span tree (docs/metrics_reference.md, "Block-
+    # intake spans"): the benchmark's per-layer metrics read these
+    # spans by name from the flight recorder, so a rename that drops
+    # one must fail here and not silently blind a metric
+    "fabric_tpu/peer/peer.py": ("process_block", "commit_validated"),
+    "fabric_tpu/ledger/kvledger.py": ("commit_block",),
+    "fabric_tpu/ledger/blkstorage.py": ("add_block",),
+    "fabric_tpu/core/fastvalidate.py": ("validate_fast",),
+    "fabric_tpu/bccsp/tpu.py": ("_verify_prepared_device",
+                                "_dispatch_chunks"),
 }.items():
     REQUIRED_SPANS[_path] = REQUIRED_SPANS.get(_path, ()) + _funcs
 
@@ -411,7 +421,8 @@ def _hot_coverage_findings(rel, tree):
 
 # -- rule: span-coverage --
 
-_SPAN_CALLS = {"span", "observe_span", "observe_stage", "instant",
+_SPAN_CALLS = {"span", "timed", "observe_span", "observe_stage",
+               "instant",
                # round 18: the carrier-resume primitive opens the
                # hop.recv span — extraction seams satisfy span
                # coverage through it
@@ -430,7 +441,7 @@ def _is_traced_decorator(dec) -> bool:
 def _opens_span(fn) -> bool:
     """True when `fn` carries a @traced decorator or (anywhere in its
     body, nested closures included — broadcast_stream's span lives in
-    its flush_run closure) calls span()/observe_span()/
+    its flush_run closure) calls span()/timed()/observe_span()/
     observe_stage()/instant() — plain or as tracing.<name>."""
     if any(_is_traced_decorator(d) for d in fn.decorator_list):
         return True
