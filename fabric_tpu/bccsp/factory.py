@@ -49,11 +49,15 @@ class TpuOpts:
     use_g16: Optional[bool] = None
     chunk: int = 32768
     # dispatch-pipeline chunk (BCCSP.TPU.PipelineChunk): a device batch
-    # is split into spans of this many lanes so stage N's device
-    # execution overlaps stage N+1's host prep (native DER parse, limb
-    # packing) and host->device transfer. 0 disables the overlapped
-    # pipeline (whole-batch staging, the pre-round-6 behavior).
-    pipeline_chunk: int = 8192
+    # is padded to and split into spans of this many lanes (all devices
+    # together) so stage N's device execution overlaps stage N+1's host
+    # prep (native DER parse, limb packing) and host->device transfer.
+    # None (unset) = 2,048 lanes for each device of the verify mesh
+    # (tpu.SPAN_LANES_PER_DEVICE): one default-cut block's 1,500-2,000
+    # signatures fill one span, and a four-chip mesh keeps 2,048 lanes a
+    # chip. 0 disables the overlapped pipeline (whole-batch staging, the
+    # pre-round-6 behavior).
+    pipeline_chunk: Optional[int] = None
     max_keys: int = 16
     table_cache_bytes: int = 6 << 30
     # True (default): hash message lanes on host, ship 32-byte digests
@@ -125,7 +129,9 @@ class FactoryOpts:
                 use_g16=(bool(tpu_cfg["UseG16"])
                          if tpu_cfg.get("UseG16") is not None else None),
                 chunk=int(tpu_cfg.get("Chunk", 32768)),
-                pipeline_chunk=int(tpu_cfg.get("PipelineChunk", 8192)),
+                pipeline_chunk=(int(tpu_cfg["PipelineChunk"])
+                                if tpu_cfg.get("PipelineChunk") is not None
+                                else None),
                 max_keys=int(tpu_cfg.get("MaxKeys", 16)),
                 table_cache_bytes=(
                     int(tpu_cfg.get("TableCacheMB", 6144)) << 20),

@@ -74,11 +74,20 @@ def host_prep_scalars(pub, signature):
 
 _DEVICE_INFO: dict = {}     # TPUProvider.device_info() memo
 
+# lanes of one pipeline span on each device, where BCCSP.TPU.PipelineChunk
+# is unset. A block Fabric really cuts (MaxMessageCount 500 x 3..4
+# signatures) fits one such span three-quarters full; device time grows
+# with the lanes (24 ms at 2,048, 39 at 4,096, 81 at 8,192 on a v5e:
+# PERF.md, Findings PR 28), so a larger span only adds premasked lanes
+# the host then waits for.
+SPAN_LANES_PER_DEVICE = 2048
+
 
 class TPUProvider(api.BCCSP):
     def __init__(self, keystore=None, min_batch: int = 16,
                  max_blocks: int = 64, mesh=None, max_keys: int = 16,
-                 chunk: int = 32768, pipeline_chunk: int = 8192,
+                 chunk: int = 32768,
+                 pipeline_chunk: Optional[int] = None,
                  use_g16: Optional[bool] = None,
                  table_cache_bytes: int = 6 << 30,
                  hash_on_host: bool = True,
@@ -175,7 +184,9 @@ class TPUProvider(api.BCCSP):
         # DER parse + limb packing on a worker thread) and its async
         # host->device transfer, so host cost hides behind device time
         # instead of adding to it (the FPGA-verify-engine shape,
-        # arXiv:2112.02229). 0 disables (whole-batch staging).
+        # arXiv:2112.02229). None (unset) = SPAN_LANES_PER_DEVICE on
+        # each device of the serving mesh; a number is the total lanes
+        # of a span, as given; 0 disables (whole-batch staging).
         self._pipeline_chunk = pipeline_chunk
         self._prep_pool = None      # lazy 1-worker host-prep executor
         # 16-bit windows on BOTH bases: the per-signature tree drops
@@ -1233,16 +1244,22 @@ class TPUProvider(api.BCCSP):
             qy_l = limb.be_bytes_to_limbs(qy_b)
             args = (blocks, nblocks, qx_l, qy_l, r_l, rpn_l, w_l,
                     premask, digests, has_digest)
-            if self._mesh is None:
-                args = tuple(jnp.asarray(a) for a in args)
             # under a mesh the host arrays stay UNCOMMITTED so the
             # jit's NamedSharding in_shardings place each lane slice
             # on its device directly (a jnp.asarray here would commit
             # to device 0 and force a gather-then-scatter reshard)
-            out = self._pipeline()(*args)
-            # ftpu-lint: allow-host-sync(the thunk IS the deliberate
-            # materialization point, invoked after dispatch returns)
-            thunk = lambda: np.asarray(out)  # noqa: E731
+            stage = ((lambda a: a) if self._mesh is not None
+                     else jnp.asarray)
+            # lanes are independent: above the span the bucket goes
+            # span by span like the comb tiers, one lane shape
+            chunk = self._mesh_chunk(bucket)
+            fn = self._pipeline()
+            outs = [fn(*(stage(a[lo:lo + chunk]) for a in args))
+                    for lo in range(0, bucket, chunk)]
+            thunk = lambda: np.concatenate(  # noqa: E731
+                # ftpu-lint: allow-host-sync(the thunk IS the deliberate
+                # materialization point, invoked after dispatch returns)
+                [np.asarray(o) for o in outs])
         return thunk if async_out else thunk()
 
     def _finish_dispatch(self, bucket, key_map, key_idx, blocks,
@@ -1572,19 +1589,24 @@ class TPUProvider(api.BCCSP):
     # -- the overlapped dispatch pipeline (BCCSP.TPU.PipelineChunk) --
 
     def _pipeline_span(self) -> Optional[int]:
-        """Effective pipeline-chunk lane count: the configured
-        PipelineChunk, floored to the Pallas-tile/mesh granule
-        (ops/ptree.py aligned_span) and capped at Chunk. None when
-        the overlapped pipeline is disabled — including when the mesh
-        granule itself exceeds Chunk (the span must never break the
-        per-dispatch staging cap)."""
+        """Lanes of one dispatch of the span pipeline, over all
+        devices: the configured PipelineChunk as given or, unset,
+        SPAN_LANES_PER_DEVICE for each device of the serving mesh
+        (2,048 on one chip; 8,192 on a four-chip mesh, whose shard_map
+        program so keeps 2,048 lanes a chip) — floored to the
+        Pallas-tile/mesh granule (ops/ptree.py aligned_span) and
+        capped at Chunk. None when the overlapped pipeline is
+        disabled — including when the mesh granule itself exceeds
+        Chunk (the span must never break the per-dispatch staging
+        cap)."""
+        ndev = self._mesh.size if self._mesh is not None else 1
         pc = self._pipeline_chunk
-        if not pc or pc <= 0:
+        if pc is None:
+            pc = SPAN_LANES_PER_DEVICE * ndev
+        if pc <= 0:
             return None
         from fabric_tpu.ops import ptree
-        span = ptree.aligned_span(
-            min(pc, self._chunk),
-            self._mesh.size if self._mesh is not None else 1)
+        span = ptree.aligned_span(min(pc, self._chunk), ndev)
         return span if span <= self._chunk else None
 
     def _prep_executor(self):
@@ -2824,12 +2846,18 @@ class TPUProvider(api.BCCSP):
             self.stats.update(self._devhealth.totals())
 
     def _mesh_chunk(self, bucket: int) -> int:
-        """Chunk size; under a mesh, slices stay divisible by the mesh
-        size for shard_map."""
+        """Lanes of one dispatch of a `bucket`-lane batch: the bucket
+        itself up to the pipeline span, the span above it (`_bucket`
+        pads to whole spans there, so every chunked tier runs ONE
+        lane shape whatever the batch size), Chunk at most. Under a
+        mesh, slices stay divisible by the mesh size for shard_map."""
         chunk = min(bucket, self._chunk)
         if self._mesh is not None:
             m = self._mesh.size
             chunk = max(m, (chunk // m) * m)
+        span = self._pipeline_span()
+        if span is not None and chunk > span and bucket % span == 0:
+            chunk = span
         return chunk
 
     @hot_path
@@ -2844,12 +2872,9 @@ class TPUProvider(api.BCCSP):
         faults.check("tpu.dispatch")
         key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
                                                             key_idx)
+        # above the span this is the overlapped item path's span
+        # shape: one compiled program serves both paths
         chunk = self._mesh_chunk(bucket)
-        span = self._pipeline_span()
-        if span is not None and chunk > span and bucket % span == 0:
-            # the overlapped item path's span shape: one compiled
-            # program serves both paths
-            chunk = span
         fn = self._comb_pipeline_digest(K, q16)
         return self._dispatch_chunks(
             bucket, chunk,
@@ -3408,21 +3433,36 @@ class TPUProvider(api.BCCSP):
 
     def _floor(self) -> int:
         """BCCSP.TPU.BucketFloor; unset (0) resolves on a TPU backend
-        to the pipeline span: every device batch up to the span pads
-        to ONE lane shape, and larger ones go span by span, so a
-        (K, q16) pair costs one pipeline compile whatever the block
-        size. The TPU compiler takes ~2 min per shape
-        (tools/chip_compile.py) — a cliff per new power-of-two bucket
-        that padded, premasked lanes are cheap against. CPU backends
-        keep the tight power-of-two buckets."""
+        to the pipeline span (2,048 lanes a device unless
+        PipelineChunk says otherwise): every device batch up to the
+        span pads to ONE lane shape, and larger ones go span by span
+        (`_bucket`), so a (K, q16) pair costs one pipeline compile
+        whatever the block size. The TPU compiler takes ~2 min per
+        shape (tools/chip_compile.py) — a cliff per new bucket that
+        padded, premasked lanes are cheap against. Not free: device
+        time grows with the lanes (PERF.md, Findings PR 28), which is
+        why the floor is no larger than a block Fabric really cuts.
+        CPU backends keep the tight power-of-two buckets."""
         if self._bucket_floor:
             return self._bucket_floor
         return (self._pipeline_span() or 0) if self._on_tpu() else 0
 
     def _bucket(self, n: int) -> int:
-        b = max(self._min_batch, self._floor())
+        """Lanes a batch of `n` signatures is padded to: the next
+        power of two from max(MinBatch, `_floor`) while that stays
+        within the pipeline span; above the span the next whole
+        number of spans (5,000 lanes over a 2,048-lane span are 3
+        spans, not the 4 of 8,192), which `_mesh_chunk` then cuts
+        into span-sized dispatches. With the floor at the span (a
+        TPU) that is ceil(n / span) dispatches of one shape for
+        every n."""
+        floor = max(self._min_batch, self._floor())
+        b = floor
         while b < n:
             b *= 2
+        span = self._pipeline_span()
+        if span is not None and b > span:
+            b = max(floor, -(-n // span) * span)
         if self._mesh is not None:
             m = self._mesh.size
             b = ((b + m - 1) // m) * m

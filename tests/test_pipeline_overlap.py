@@ -147,7 +147,10 @@ class TestPipelineParity:
         assert tpu.verify_batch(items) == expected
         assert not any(expected)
         assert tpu.stats["pipeline_batches"] == 0
-        assert calls["ladder"] == 1
+        # 140 lanes over 128-lane spans: the ladder goes span by
+        # span too, one batch in two dispatches of one shape
+        assert calls["ladder"] == 2
+        assert tpu.stats["ladder_batches"] == 1
 
     def test_single_span_takes_whole_batch_path(self):
         faults.clear()
@@ -192,7 +195,8 @@ class TestPipelineParity:
         items, expected = _corpus(200)      # 2 distinct keys > max
         assert tpu.verify_batch(items) == expected
         assert tpu.stats["pipeline_batches"] == 0
-        assert calls["ladder"] == 1
+        assert calls["ladder"] == 2         # 200 lanes, 128-lane spans
+        assert tpu.stats["ladder_batches"] == 1
 
 
 class TestPipelineObservability:

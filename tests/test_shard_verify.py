@@ -145,7 +145,10 @@ class TestShardedParity:
         assert sharded.verify_batch(items) == expected
         assert not any(expected)
         assert sharded.stats["pipeline_batches"] == 0
-        assert calls["ladder"] == 1
+        # 1,100 lanes over 1,024-lane spans: the ladder goes span by
+        # span like the comb tiers, two dispatches of the one shape
+        assert calls["ladder"] == 2
+        assert sharded.stats["ladder_batches"] == 1
 
     def test_whole_batch_digest_path_sharded(self, mesh8):
         """pipeline_chunk=0 (overlap off): the whole-batch digest comb
