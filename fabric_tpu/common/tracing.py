@@ -63,6 +63,7 @@ observe) for hosts where even ring writes are too much.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import gc
 import itertools
@@ -506,6 +507,38 @@ def timed(name: str, **attrs):
     if not _state.enabled:
         return _Clock()
     return _Span(name, None, attrs or None)
+
+
+def _thread_io() -> Optional[tuple[int, int]]:
+    """(syscr, syscw) of the calling thread: the read and write system
+    calls it has made. None where the kernel keeps no such account."""
+    try:
+        with open("/proc/thread-self/io", "rb") as f:
+            fields = dict(line.split(b": ") for line in f)
+        return int(fields[b"syscr"]), int(fields[b"syscw"])
+    except (OSError, KeyError, ValueError):
+        return None
+
+
+@contextlib.contextmanager
+def thread_io(sp):
+    """Book on span `sp`, as `syscr` / `syscw`, the read and write
+    system calls the calling thread makes inside the block:
+
+        commit = tracing.span("commit.commit", block=num)
+        with commit, tracing.thread_io(commit):
+            ...
+
+    What a stretch asks of the file system, where the time it takes
+    there depends on the host (a network file system under sqlite).
+    Nothing is read with tracing disabled."""
+    io0 = _thread_io() if _state.enabled else None
+    try:
+        yield
+    finally:
+        io1 = _thread_io() if io0 is not None else None
+        if io1 is not None:
+            sp.set(syscr=io1[0] - io0[0], syscw=io1[1] - io0[1])
 
 
 def traced(name: str):

@@ -589,9 +589,10 @@ class CommitPipeline:
             codes = None
             t0 = time.perf_counter()
             try:
-                with tracing.span("commit.commit", parent=item.tctx,
-                                  seq=item.seq,
-                                  fallback=item.fallback):
+                commit = tracing.span("commit.commit", parent=item.tctx,
+                                      seq=item.seq,
+                                      fallback=item.fallback)
+                with commit, tracing.thread_io(commit):
                     if item.fallback:
                         codes = self._commit_fallback(item)
                     else:

@@ -9,11 +9,16 @@ values out of the block store (the history DB stores no values).
 from __future__ import annotations
 
 import struct
-from typing import Iterator
+from typing import Iterator, Optional
 
 from fabric_tpu import protoutil as pu
 from fabric_tpu.ledger.blkstorage import BlockStore
 from fabric_tpu.ledger.kvdb import DBHandle
+from fabric_tpu.ledger.txmgr import (
+    BlockRWSets,
+    extract_tx_rwset,
+    parse_block_rwsets,
+)
 from fabric_tpu.protos import common, proposal as proppb
 from fabric_tpu.protos import rwset as rwpb, transaction as txpb
 
@@ -29,25 +34,23 @@ class HistoryDB:
         return (ns.encode() + _SEP + key.encode() + _SEP +
                 struct.pack(">QQ", block, tx))
 
-    def commit_block(self, block: common.Block,
-                     codes: list[int]) -> int:
-        """Returns the rows written (one per key a valid tx wrote)."""
+    def commit_block(self, block: common.Block, codes: list[int],
+                     parsed: Optional[BlockRWSets] = None) -> int:
+        """One row per key a valid tx wrote; returns the rows written.
+        `parsed` is the commit path's decoded rwsets; a caller that
+        holds only the block has the envelopes decoded here."""
+        if parsed is None:
+            parsed = parse_block_rwsets(
+                [extract_tx_rwset(e) for e in block.data.data], codes)
         batch = self._db.new_batch()
-        for tx_num, env_bytes in enumerate(block.data.data):
-            if codes[tx_num] != txpb.TxValidationCode.VALID:
+        for tx_num, tx in enumerate(parsed.txs):
+            if tx is None or \
+                    codes[tx_num] != txpb.TxValidationCode.VALID:
                 continue
-            try:
-                action = pu.get_action_from_envelope(env_bytes)
-            except Exception:
-                continue
-            txrw = rwpb.TxReadWriteSet()
-            txrw.ParseFromString(action.results)
-            for nsrw in txrw.ns_rwset:
-                kv = rwpb.KVRWSet()
-                kv.ParseFromString(nsrw.rwset)
+            for ns, kv, _colls in tx:
                 for w in kv.writes:
-                    batch.put(self._k(nsrw.namespace, w.key,
-                                      block.header.number, tx_num), b"")
+                    batch.put(self._k(ns, w.key, block.header.number,
+                                      tx_num), b"")
         self._db.write_batch(batch)
         return len(batch.ops)
 

@@ -6,6 +6,19 @@ bookkeeping). The interface is leveldb-shaped — get/put/delete,
 write-batch, ordered range iteration, named sub-DBs via key prefixes —
 backed here by SQLite (stdlib, crash-safe WAL); the interface leaves
 room for a C++ LSM engine drop-in if profiling demands it.
+
+When and how bytes reach the disk — `journal_mode=WAL`,
+`synchronous=NORMAL`, sqlite's default `wal_autocheckpoint` and
+`page_size` — is the deployment's durability setting (the benchmark
+configuration's `ledger` group states it), and a change of speed is no
+reason to touch it. The page cache is not of that kind — it is only how
+much of the file a store keeps in memory — and it is left at sqlite's
+default (2 MB) for now: against a ledger's 17-20 MB file after 180
+default blocks that makes every block re-read ~3,400 leaves and spill
+~1,350 dirty pages to the WAL before its commit, and 64 MiB a store
+was measured to take 50 ms off a 280 ms block on the chip host's 9p
+root (PERF.md, Findings PR 31, which also says why it waits for the
+benchmark's block supply to be raised first).
 """
 
 from __future__ import annotations
@@ -32,6 +45,9 @@ class KVStore:
     def __init__(self, path: str):
         self._conn = sqlite3.connect(path, check_same_thread=False)
         self._lock = threading.Lock()
+        # cumulative SELECT statements (the `ledger.mvcc` span books a
+        # block's share)
+        self.selects = 0
         cur = self._conn.cursor()
         cur.execute("PRAGMA journal_mode=WAL")
         cur.execute("PRAGMA synchronous=NORMAL")
@@ -41,6 +57,7 @@ class KVStore:
 
     def get(self, key: bytes) -> Optional[bytes]:
         with self._lock:
+            self.selects += 1
             row = self._conn.execute(
                 "SELECT v FROM kv WHERE k = ?", (key,)).fetchone()
         return row[0] if row else None
@@ -53,6 +70,7 @@ class KVStore:
         with self._lock:
             for lo in range(0, len(keys), 500):
                 chunk = keys[lo:lo + 500]
+                self.selects += 1
                 q = ("SELECT k, v FROM kv WHERE k IN (%s)"
                      % ",".join("?" * len(chunk)))
                 for k, v in self._conn.execute(q, chunk):
@@ -103,6 +121,7 @@ class KVStore:
                 ) -> Iterator[tuple[bytes, bytes]]:
         """Ordered [start, end) scan; end=None = to the end of keyspace."""
         with self._lock:
+            self.selects += 1
             if end is None:
                 rows = self._conn.execute(
                     "SELECT k, v FROM kv WHERE k >= ? ORDER BY k",

@@ -22,7 +22,13 @@ from fabric_tpu.ledger.blkstorage import BlockStore
 from fabric_tpu.ledger.history import HistoryDB
 from fabric_tpu.ledger.kvdb import DBHandle, KVStore
 from fabric_tpu.ledger.statedb import Height, StateDB, UpdateBatch
-from fabric_tpu.ledger.txmgr import TxMgr, TxSimulator
+from fabric_tpu.ledger.txmgr import (
+    BlockRWSets,
+    TxMgr,
+    TxSimulator,
+    extract_tx_rwset,
+    parse_block_rwsets,
+)
 from fabric_tpu.protos import common, rwset as rwpb, transaction as txpb
 
 logger = must_get_logger("kvledger")
@@ -56,18 +62,6 @@ TRANSACTION_COUNT = metrics_mod.CounterOpts(
 
 class LedgerError(Exception):
     pass
-
-
-def extract_tx_rwset(env_bytes: bytes) -> Optional[rwpb.TxReadWriteSet]:
-    """Pull the simulation results out of a tx envelope; None if the
-    envelope isn't a well-formed endorser tx."""
-    try:
-        action = pu.get_action_from_envelope(env_bytes)
-        txrw = rwpb.TxReadWriteSet()
-        txrw.ParseFromString(action.results)
-        return txrw
-    except Exception:
-        return None
 
 
 class KVLedger:
@@ -324,15 +318,18 @@ class KVLedger:
                 if rwsets is None:
                     rwsets = [extract_tx_rwset(e)
                               for e in block.data.data]
-                reads0 = self.txmgr.reads_checked
-                codes, batch = self.txmgr.validate_and_prepare(
-                    block_num, rwsets,
-                    list(flags) if flags else None)
-                self._commit_pvt_data(block_num, rwsets, codes,
-                                      pvt_data or {}, batch)
+                txmgr = self.txmgr
+                reads0, prefetched0, fallthrough0, selects0 = (
+                    txmgr.reads_checked, txmgr.prefetched,
+                    txmgr.fallthrough, self._kv.selects)
+                codes, batch, parsed = self._validate_and_prepare(
+                    block_num, rwsets, flags, pvt_data or {})
                 mvcc.set(valid=codes.count(txpb.TxValidationCode.VALID),
-                         reads=self.txmgr.reads_checked - reads0,
-                         writes=len(batch.updates))
+                         reads=txmgr.reads_checked - reads0,
+                         writes=len(batch.updates),
+                         prefetched=txmgr.prefetched - prefetched0,
+                         selects=self._kv.selects - selects0,
+                         fallthrough=txmgr.fallthrough - fallthrough0)
 
             # TRANSACTIONS_FILTER: one code byte per tx
             block.metadata.metadata[
@@ -362,8 +359,8 @@ class KVLedger:
             # lose block N's history
             history = tracing.span("ledger.history")
             with history:
-                history.set(rows=self.history_db.commit_block(block,
-                                                              codes))
+                history.set(rows=self.history_db.commit_block(
+                    block, codes, parsed))
             with state:
                 state.set(rows=len(batch.updates))
                 # listeners BEFORE the savepoint advances: a crash in
@@ -426,21 +423,34 @@ class KVLedger:
             filt[i] if i < len(filt) else txpb.TxValidationCode.VALID
             for i in range(len(rwsets))
         ]
-        codes, batch = self.txmgr.validate_and_prepare(
-            block_num, rwsets, flags)
         pvt_data = {}
         for tx_num in range(len(rwsets)):
             stored = self.pvt_store.get_pvt_data(block_num, tx_num)
             if stored is not None:
                 pvt_data[tx_num] = stored
-        self._commit_pvt_data(block_num, rwsets, codes, pvt_data, batch)
+        codes, batch, parsed = self._validate_and_prepare(
+            block_num, rwsets, flags, pvt_data)
         # same history/listener-before-savepoint ordering as
         # commit_block
-        self.history_db.commit_block(block, codes)
+        self.history_db.commit_block(block, codes, parsed)
         self._notify_state_listeners(block_num, batch)
         self.state_db.apply_updates(
             batch, Height(block_num, max(len(rwsets) - 1, 0)))
         self._drop_expired_bookkeeping(block_num)
+
+    def _validate_and_prepare(
+            self, block_num: int, rwsets, flags, pvt_data: dict
+    ) -> tuple[list[int], UpdateBatch, BlockRWSets]:
+        """What commit and crash recovery share before anything is
+        written: the one decoding pass over the block's rwsets, MVCC
+        over one bulk read of committed state, the private-data
+        commit. History takes the decoded rwsets afterwards."""
+        flags = list(flags) if flags else None
+        parsed = parse_block_rwsets(rwsets, flags)
+        codes, batch = self.txmgr.validate_and_prepare(
+            block_num, rwsets, flags, parsed=parsed)
+        self._commit_pvt_data(block_num, parsed, codes, pvt_data, batch)
+        return codes, batch, parsed
 
     def _notify_state_listeners(self, block_num: int,
                                 batch: UpdateBatch) -> None:
@@ -460,8 +470,9 @@ class KVLedger:
     # -- private data commit (reference: commitToPvtAndBlockStore +
     #    pvtdatastorage Commit + expiry keeper) --
 
-    def _commit_pvt_data(self, block_num: int, rwsets, codes: list[int],
-                         pvt_data: dict, batch: UpdateBatch) -> None:
+    def _commit_pvt_data(self, block_num: int, parsed: BlockRWSets,
+                         codes: list[int], pvt_data: dict,
+                         batch: UpdateBatch) -> None:
         """Verify supplied cleartext against the on-chain hashes, apply
         it to the private namespaces, persist it to the pvt store,
         record missing collections + BTL expiry, and fold purges of
@@ -471,43 +482,37 @@ class KVLedger:
         missing: list[pvt.MissingPvtData] = []
         expiry: dict[int, list] = {}   # expiry_block -> entries
 
-        for tx_num, txrw in enumerate(rwsets):
-            if txrw is None or \
+        for tx_num, tx in enumerate(parsed.txs):
+            if tx is None or \
                     codes[tx_num] != txpb.TxValidationCode.VALID:
                 continue
             supplied = self._index_supplied_pvt(pvt_data.get(tx_num))
             kept = rwpb.TxPvtReadWriteSet(
                 data_model=rwpb.TxReadWriteSet.KV)
-            for nsrw in txrw.ns_rwset:
+            for ns, _kv, colls in tx:
                 ns_kept = None
-                for chrw in nsrw.collection_hashed_rwset:
-                    hset = rwpb.HashedRWSet()
-                    hset.ParseFromString(chrw.rwset)
+                for coll, _hns, hset, pvt_rwset_hash in colls:
                     if not hset.hashed_writes:
                         continue   # read-only: no cleartext to commit
-                    coll = chrw.collection_name
-                    raw = supplied.get((nsrw.namespace, coll))
-                    if raw is None or pvt.pvt_rwset_hash(raw) != \
-                            chrw.pvt_rwset_hash:
+                    raw = supplied.get((ns, coll))
+                    if raw is None or \
+                            pvt.pvt_rwset_hash(raw) != pvt_rwset_hash:
                         if raw is not None:
                             logger.warning(
                                 "[%s] pvt data for tx %d [%s/%s] does "
                                 "not match its on-chain hash; treating "
                                 "as missing", self.ledger_id, tx_num,
-                                nsrw.namespace, coll)
+                                ns, coll)
                         missing.append(pvt.MissingPvtData(
-                            block_num, tx_num, nsrw.namespace, coll))
+                            block_num, tx_num, ns, coll))
                         self._record_expiry_hashes(
-                            expiry, block_num, nsrw.namespace, coll,
-                            hset)
+                            expiry, block_num, ns, coll, hset)
                         continue
                     self._apply_pvt_writes(
                         batch, expiry, block_num,
-                        Height(block_num, tx_num),
-                        nsrw.namespace, coll, raw, hset)
+                        Height(block_num, tx_num), ns, coll, raw, hset)
                     if ns_kept is None:
-                        ns_kept = kept.ns_pvt_rwset.add(
-                            namespace=nsrw.namespace)
+                        ns_kept = kept.ns_pvt_rwset.add(namespace=ns)
                     ns_kept.collection_pvt_rwset.add(
                         collection_name=coll, rwset=raw)
             if kept.ns_pvt_rwset:

@@ -119,6 +119,12 @@ class VersionedDB:
     def get_state_metadata_many(self, wanted):
         return {nk: self.get_state_metadata(*nk) for nk in wanted}
 
+    def get_states_many(self, pairs):
+        """{(ns, key): VersionedValue | None} for every pair asked:
+        absent is an answer. A block's MVCC reads committed state
+        through this once; a backend with a bulk read overrides it."""
+        return {nk: self.get_state(*nk) for nk in pairs}
+
     def get_version(self, ns: str, key: str):
         vv = self.get_state(ns, key)
         return vv.version if vv is not None else None
@@ -295,23 +301,24 @@ class StateDB(VersionedDB):
         vv = self.get_state(ns, key)
         return vv.metadata if vv and vv.metadata else None
 
+    def get_states_many(
+            self, pairs: list[tuple[str, str]]
+    ) -> dict[tuple[str, str], Optional[VersionedValue]]:
+        """One bulk read (a statement per 500 keys, in key order) in
+        place of a point read per pair."""
+        by_raw = {self._k(ns, k): (ns, k) for ns, k in pairs}
+        raw = self._db.get_many(sorted(by_raw))
+        return {nk: _decode(raw[rk]) if rk in raw else None
+                for rk, nk in by_raw.items()}
+
     def get_state_metadata_many(
             self, pairs: list[tuple[str, str]]
     ) -> dict[tuple[str, str], Optional[bytes]]:
         """Batched get_state_metadata over (ns, key) pairs — one probe
         per block for the key-level validation-parameter lookups instead
         of one per written key."""
-        uniq = list(dict.fromkeys(pairs))
-        raw = self._db.get_many([self._k(ns, k) for ns, k in uniq])
-        out: dict[tuple[str, str], Optional[bytes]] = {}
-        for ns, k in uniq:
-            r = raw.get(self._k(ns, k))
-            if r is None:
-                out[(ns, k)] = None
-            else:
-                vv = _decode(r)
-                out[(ns, k)] = vv.metadata if vv.metadata else None
-        return out
+        return {nk: vv.metadata if vv and vv.metadata else None
+                for nk, vv in self.get_states_many(pairs).items()}
 
     def get_version(self, ns: str, key: str) -> Optional[Height]:
         vv = self.get_state(ns, key)

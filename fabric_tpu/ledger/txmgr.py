@@ -13,13 +13,17 @@ MVCC conflicts; surviving writes land in one UpdateBatch stamped with
 from __future__ import annotations
 
 import logging
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
+from google.protobuf.message import DecodeError
+
+from fabric_tpu import protoutil as pu
 from fabric_tpu.ledger import pvtdata as pvt
 from fabric_tpu.ledger.statedb import (
     Height,
     StateDB,
     UpdateBatch,
+    VersionedDB,
     VersionedValue,
 )
 from fabric_tpu.protos import rwset as rwpb, transaction as txpb
@@ -318,84 +322,204 @@ class TxSimulator:
         return txpvt
 
 
+def extract_tx_rwset(env_bytes: bytes) -> Optional[rwpb.TxReadWriteSet]:
+    """Pull the simulation results out of a tx envelope; None if the
+    envelope isn't a well-formed endorser tx."""
+    try:
+        action = pu.get_action_from_envelope(env_bytes)
+        txrw = rwpb.TxReadWriteSet()
+        txrw.ParseFromString(action.results)
+        return txrw
+    except Exception:
+        return None
+
+
+class CollRWSet(NamedTuple):
+    """One collection's hashed rwset of one transaction, decoded."""
+    name: str
+    hashed_ns: str
+    rwset: rwpb.HashedRWSet
+    pvt_rwset_hash: bytes
+
+
+class NsRWSet(NamedTuple):
+    """One namespace of one transaction's rwset, decoded."""
+    namespace: str
+    kv: rwpb.KVRWSet
+    colls: list[CollRWSet]
+
+
+class BlockRWSets:
+    """A block's rwsets decoded once (`parse_block_rwsets`), for MVCC,
+    the private-data commit and history alike.
+
+    `txs[i]` is the transaction's namespaces, or None where there is
+    nothing to validate: flagged invalid upstream, no rwset, or one
+    that does not parse (MVCC marks the last two BAD_RWSET). `keys`
+    holds every (ns, key) a point read or a write of the block names
+    — hashed ones under their hashed namespace — which is what MVCC
+    reads from committed state, in one bulk read."""
+
+    __slots__ = ("txs", "keys")
+
+    def __init__(self, txs: list[Optional[list[NsRWSet]]],
+                 keys: dict[tuple[str, str], None]):
+        self.txs = txs
+        self.keys = keys
+
+
+def _parse_tx(txrw: rwpb.TxReadWriteSet, keys: dict) -> list[NsRWSet]:
+    out = []
+    for nsrw in txrw.ns_rwset:
+        ns = nsrw.namespace
+        kv = rwpb.KVRWSet()
+        kv.ParseFromString(nsrw.rwset)
+        for group in (kv.reads, kv.writes, kv.metadata_writes):
+            for item in group:
+                keys[(ns, item.key)] = None
+        colls = []
+        for chrw in nsrw.collection_hashed_rwset:
+            hset = rwpb.HashedRWSet()
+            hset.ParseFromString(chrw.rwset)
+            hns = pvt.hash_ns(ns, chrw.collection_name)
+            for group in (hset.hashed_reads, hset.hashed_writes,
+                          hset.metadata_writes):
+                for item in group:
+                    keys[(hns, pvt.hashed_key_str(item.key_hash))] = None
+            colls.append(CollRWSet(chrw.collection_name, hns, hset,
+                                   chrw.pvt_rwset_hash))
+        out.append(NsRWSet(ns, kv, colls))
+    return out
+
+
+def parse_block_rwsets(
+        tx_rwsets: Sequence[Optional[rwpb.TxReadWriteSet]],
+        flags: Optional[Sequence[int]] = None) -> BlockRWSets:
+    """The one decoding pass over a block's rwsets (commit and crash
+    recovery both start here). Transactions `flags` already marks
+    invalid are not decoded: nothing reads them."""
+    txs: list[Optional[list[NsRWSet]]] = []
+    keys: dict[tuple[str, str], None] = {}
+    for tx_num, txrw in enumerate(tx_rwsets):
+        tx = None
+        if txrw is not None and not (
+                flags and flags[tx_num] != txpb.TxValidationCode.VALID):
+            try:
+                tx = _parse_tx(txrw, keys)
+            except DecodeError:
+                logger.warning("tx %d: rwset does not parse", tx_num)
+        txs.append(tx)
+    return BlockRWSets(txs, keys)
+
+
+class _Committed:
+    """Committed state as one block's MVCC sees it: the bulk read of
+    the keys the block names, and a counted point read for a key it
+    did not name (absent is an answer of the bulk read, not a miss)."""
+
+    __slots__ = ("_db", "_got", "fallthrough")
+
+    def __init__(self, statedb: VersionedDB, keys):
+        self._db = statedb
+        self._got = statedb.get_states_many(list(keys)) if keys else {}
+        self.fallthrough = 0
+
+    def __len__(self) -> int:
+        return len(self._got)
+
+    def get(self, ns: str, key: str) -> Optional[VersionedValue]:
+        try:
+            return self._got[(ns, key)]
+        except KeyError:
+            self.fallthrough += 1
+            return self._db.get_state(ns, key)
+
+
 class TxMgr:
     """Block-level validate-and-prepare (reference:
     `validation/validator.go` validateAndPrepareBatch)."""
 
-    def __init__(self, statedb: StateDB):
+    def __init__(self, statedb: VersionedDB):
         self.statedb = statedb
-        # cumulative: key reads MVCC has checked (the `ledger.mvcc`
-        # span books a block's share)
+        # cumulative (the `ledger.mvcc` span books a block's share):
+        # key reads MVCC has checked, keys it read from committed
+        # state in bulk, point reads the bulk read could not answer
         self.reads_checked = 0
+        self.prefetched = 0
+        self.fallthrough = 0
 
     def validate_and_prepare(
         self, block_num: int,
         tx_rwsets: Sequence[Optional[rwpb.TxReadWriteSet]],
         flags: Optional[list[int]] = None,
+        parsed: Optional[BlockRWSets] = None,
     ) -> tuple[list[int], UpdateBatch]:
         """For each tx (None = already invalid upstream): MVCC-check its
         reads against committed state + earlier in-block updates; valid
-        txs contribute writes. Returns (validation codes, batch)."""
+        txs contribute writes. `parsed` is `parse_block_rwsets` of the
+        same rwsets and flags where the caller needs it afterwards too.
+        Returns (validation codes, batch)."""
         n = len(tx_rwsets)
         codes = list(flags) if flags else \
             [txpb.TxValidationCode.VALID] * n
+        if parsed is None:
+            parsed = parse_block_rwsets(tx_rwsets, codes)
+        committed = _Committed(self.statedb, parsed.keys)
         batch = UpdateBatch()
 
-        for tx_num, txrw in enumerate(tx_rwsets):
+        for tx_num, tx in enumerate(parsed.txs):
             if codes[tx_num] != txpb.TxValidationCode.VALID:
                 continue
-            if txrw is None:
+            if tx is None:
                 codes[tx_num] = txpb.TxValidationCode.BAD_RWSET
                 continue
-            code = self._validate_tx(txrw, batch)
+            code = self._validate_tx(tx, batch, committed)
             codes[tx_num] = code
             if code == txpb.TxValidationCode.VALID:
-                self._apply_writes(txrw, batch,
+                self._apply_writes(tx, batch, committed,
                                    Height(block_num, tx_num))
+        self.prefetched += len(committed)
+        self.fallthrough += committed.fallthrough
         return codes, batch
 
     # -- per-tx checks --
 
-    def _validate_tx(self, txrw: rwpb.TxReadWriteSet,
-                     batch: UpdateBatch) -> int:
-        for nsrw in txrw.ns_rwset:
-            kv = rwpb.KVRWSet()
-            kv.ParseFromString(nsrw.rwset)
+    def _validate_tx(self, tx: list[NsRWSet], batch: UpdateBatch,
+                     committed: _Committed) -> int:
+        for ns, kv, colls in tx:
             self.reads_checked += len(kv.reads)
             for read in kv.reads:
-                if not self._validate_read(nsrw.namespace, read, batch):
+                if not self._validate_read(ns, read.key, read, batch,
+                                           committed):
                     return txpb.TxValidationCode.MVCC_READ_CONFLICT
             for rqi in kv.range_queries_info:
-                if not self._validate_range_query(nsrw.namespace, rqi,
-                                                  batch):
+                if not self._validate_range_query(ns, rqi, batch):
                     return txpb.TxValidationCode.PHANTOM_READ_CONFLICT
             # hashed collection reads: same MVCC rule over the hashed
             # namespace (deterministic on every peer)
-            for chrw in nsrw.collection_hashed_rwset:
-                hset = rwpb.HashedRWSet()
-                hset.ParseFromString(chrw.rwset)
-                hns = pvt.hash_ns(nsrw.namespace, chrw.collection_name)
-                for hread in hset.hashed_reads:
-                    read = rwpb.KVRead(
-                        key=pvt.hashed_key_str(hread.key_hash))
-                    if hread.HasField("version"):
-                        read.version.CopyFrom(hread.version)
-                    if not self._validate_read(hns, read, batch):
+            for coll in colls:
+                for hread in coll.rwset.hashed_reads:
+                    if not self._validate_read(
+                            coll.hashed_ns,
+                            pvt.hashed_key_str(hread.key_hash), hread,
+                            batch, committed):
                         return txpb.TxValidationCode.MVCC_READ_CONFLICT
         return txpb.TxValidationCode.VALID
 
-    def _validate_read(self, ns: str, read: rwpb.KVRead,
-                       batch: UpdateBatch) -> bool:
+    @staticmethod
+    def _validate_read(ns: str, key: str, read, batch: UpdateBatch,
+                       committed: _Committed) -> bool:
         """Reference: validator.go:174 validateKVRead — a read conflicts
         if the key was updated in this block by an earlier valid tx, or
-        its committed version differs from the read version."""
-        in_batch, _ = batch.get(ns, read.key)
+        its committed version differs from the read version. `read` is
+        a KVRead or a KVReadHash: the version is all that is read."""
+        in_batch, _ = batch.get(ns, key)
         if in_batch:
             return False
-        committed = self.statedb.get_version(ns, read.key)
+        vv = committed.get(ns, key)
         read_ver = _height_of(read.version) if read.HasField("version") \
             else None
-        return committed == read_ver
+        return (vv.version if vv is not None else None) == read_ver
 
     def _validate_range_query(self, ns: str, rqi: rwpb.RangeQueryInfo,
                               batch: UpdateBatch) -> bool:
@@ -431,16 +555,19 @@ class TxMgr:
             current = current[:len(expected)]
         return current == expected
 
-    def _existing(self, ns: str, key: str, batch: UpdateBatch):
+    @staticmethod
+    def _existing(ns: str, key: str, batch: UpdateBatch,
+                  committed: _Committed):
         """Current VersionedValue: this block's batch first, then
         committed state. None when absent/deleted."""
         in_batch, vv = batch.get(ns, key)
         if in_batch:
             return vv
-        return self.statedb.get_state(ns, key)
+        return committed.get(ns, key)
 
     def _apply_ns_writes(self, ns: str, writes, metadata_writes,
-                        batch: UpdateBatch, height: Height) -> None:
+                        batch: UpdateBatch, committed: _Committed,
+                        height: Height) -> None:
         """Value + metadata writes of one tx within one namespace.
 
         Reference semantics (validator batch preparation + statedb):
@@ -460,32 +587,28 @@ class TxMgr:
             if w.key in md_map:
                 md = md_map.pop(w.key)
             else:
-                cur = self._existing(ns, w.key, batch)
+                cur = self._existing(ns, w.key, batch, committed)
                 md = cur.metadata if cur else b""
             batch.put(ns, w.key, w.value, height, metadata=md)
         for key, md in md_map.items():          # metadata-only updates
-            cur = self._existing(ns, key, batch)
+            cur = self._existing(ns, key, batch, committed)
             if cur is None:
                 continue
             batch.put(ns, key, cur.value, height, metadata=md)
 
-    def _apply_writes(self, txrw, batch: UpdateBatch,
-                      height: Height) -> None:
-        for nsrw in txrw.ns_rwset:
-            kv = rwpb.KVRWSet()
-            kv.ParseFromString(nsrw.rwset)
-            self._apply_ns_writes(nsrw.namespace, kv.writes,
-                                  kv.metadata_writes, batch, height)
-            for chrw in nsrw.collection_hashed_rwset:
-                hset = rwpb.HashedRWSet()
-                hset.ParseFromString(chrw.rwset)
-                hns = pvt.hash_ns(nsrw.namespace, chrw.collection_name)
+    def _apply_writes(self, tx: list[NsRWSet], batch: UpdateBatch,
+                      committed: _Committed, height: Height) -> None:
+        for ns, kv, colls in tx:
+            self._apply_ns_writes(ns, kv.writes, kv.metadata_writes,
+                                  batch, committed, height)
+            for coll in colls:
                 writes = [rwpb.KVWrite(
                     key=pvt.hashed_key_str(hw.key_hash),
                     is_delete=hw.is_delete, value=hw.value_hash)
-                    for hw in hset.hashed_writes]
+                    for hw in coll.rwset.hashed_writes]
                 mwrites = [rwpb.KVMetadataWrite(
                     key=pvt.hashed_key_str(mw.key_hash),
                     entries=mw.entries)
-                    for mw in hset.metadata_writes]
-                self._apply_ns_writes(hns, writes, mwrites, batch, height)
+                    for mw in coll.rwset.metadata_writes]
+                self._apply_ns_writes(coll.hashed_ns, writes, mwrites,
+                                      batch, committed, height)

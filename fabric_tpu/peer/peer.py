@@ -226,7 +226,8 @@ class Channel:
                               for e in block.data.data]
             with tracing.span("intake.txids", txs=n):
                 tx_ids = self.ledger.block_store.block_tx_ids(block)
-            with tracing.span("commit.commit", block=num, txs=n):
+            commit = tracing.span("commit.commit", block=num, txs=n)
+            with commit, tracing.thread_io(commit):
                 return self.commit_validated(block, flags,
                                              rwsets=rwsets,
                                              tx_ids=tx_ids)
