@@ -10,8 +10,7 @@ peer and a sw-provider peer over the SAME ordered block.
 
 Reference analog: `integration/e2e/e2e_test.go`; the timings mirror
 "Validated block [n] in Tms" (`validator.go:262`) and the commit
-breakdown (`kv_ledger.go:673-681`). Used by bench.py (BENCH_E2E=1) to
-emit the `pipeline` section of the headline JSON.
+breakdown (`kv_ledger.go:673-681`).
 """
 
 from __future__ import annotations

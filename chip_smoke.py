@@ -50,12 +50,10 @@ from concurrent.futures import ThreadPoolExecutor
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-FORBIDDEN_ENV = ("FTPU_PALLAS", "FTPU_PALLAS_INTERPRET", "FTPU_FUSED",
-                 "FTPU_BLS_DEVICE")
-FALLBACK_COUNTERS = ("sw_fallbacks", "fused_fallbacks",
-                     "host_hash_fallbacks", "degraded_batches",
-                     "ladder_batches")
-DISPATCH_COUNTERS = ("fused_batches", "comb_batches", "pipeline_batches")
+FORBIDDEN_ENV = ("FTPU_BLS_DEVICE",)
+FALLBACK_COUNTERS = ("sw_fallbacks", "host_hash_fallbacks",
+                     "degraded_batches", "ladder_batches")
+DISPATCH_COUNTERS = ("comb_batches", "pipeline_batches")
 BLOCK_TXS = 500             # orderer/blockcutter.py MaxMessageCount
 BATCH_TIMEOUT = "2s"        # orderer/blockcutter.py BatchTimeout
 CHILD_TIMEOUT_S = 1100      # one JAX-owning child, cold compiles included
@@ -191,18 +189,14 @@ def seam_child(args) -> dict:
         warm_call_s=warm_s, served_by=served_by,
         warm_compiles=st["compile_total"] - cold["compile_total"],
         health=prov.health(),
-        path={"tree": prov._tree_impl(),
-              "pallas_interpret": jaxenv.pallas_interpret(),
-              "fused": prov._fused_enabled(),
-              "q16": prov._g16_enabled(), "chunk": prov._chunk,
+        path={"q16": prov._g16_enabled(), "chunk": prov._chunk,
               "pipeline_span": prov._pipeline_span(),
               "bucket": prov._bucket(len(items))},
         stats={k: st[k] for k in FALLBACK_COUNTERS + DISPATCH_COUNTERS
                + ("q16_resident_sets", "q16_builds", "compile_total",
                   "compile_cold_total", "compile_cache_hits",
                   "compile_seconds", "shard_devices",
-                  "shard_dispatches", "host_hashed_lanes",
-                  "fused_lanes")},
+                  "shard_dispatches", "host_hashed_lanes")},
         shard_lanes=list(prov.shard_stats.get("lanes") or []),
         verdict_sha256=hashlib.sha256(bytes(got)).hexdigest())
     ms = getattr(dev, "memory_stats", lambda: None)() or {}
@@ -218,8 +212,6 @@ def seam_child(args) -> dict:
               "the warm calls rode the 8-bit Q tables (q16 not resident)")
     if not args.rehearse:
         check(out["path"]["q16"], "q16 tables resolved off on a TPU")
-        check(not out["path"]["pallas_interpret"],
-              "Pallas interpret mode resolved on, on a TPU")
     if ndev > 1:
         check(st["shard_dispatches"] > 0, "no sharded dispatch")
         check(len(out["shard_lanes"]) == ndev

@@ -64,13 +64,6 @@ class TpuOpts:
     # (reference-matching CPU hash; minimal device transfer). False:
     # fuse SHA-256 into the device pipeline (PCIe-attached hosts).
     hash_on_host: bool = True
-    # BCCSP.TPU.FusedVerify: the round-20 fused Pallas tier — device
-    # SHA-256 + scalar recovery + comb in ONE program, host never
-    # hashes message lanes. None = auto (on for real TPU backends,
-    # off on CPU rigs); verdicts are bit-identical either way, an
-    # armed fault or missing lowering demotes to the host-hash
-    # comb-digest path
-    fused_verify: Optional[bool] = None
     # directory where the provider persists the org key sets it has
     # built Q tables for, so `prewarm()` rebuilds them BEFORE the first
     # block after a restart (node assembly defaults this under
@@ -136,9 +129,6 @@ class FactoryOpts:
                 table_cache_bytes=(
                     int(tpu_cfg.get("TableCacheMB", 6144)) << 20),
                 hash_on_host=bool(tpu_cfg.get("HashOnHost", True)),
-                fused_verify=(bool(tpu_cfg.get("FusedVerify"))
-                              if tpu_cfg.get("FusedVerify") is not None
-                              else None),
                 warm_keys_dir=tpu_cfg.get("WarmKeysDir") or None,
                 bucket_floor=int(tpu_cfg.get("BucketFloor", 0)),
                 ed25519=bool(tpu_cfg.get("Ed25519", True)),
@@ -240,7 +230,6 @@ def new_bccsp(opts: FactoryOpts) -> BCCSP:
                            use_g16=opts.tpu.use_g16,
                            table_cache_bytes=opts.tpu.table_cache_bytes,
                            hash_on_host=opts.tpu.hash_on_host,
-                           fused_verify=opts.tpu.fused_verify,
                            warm_keys_dir=opts.tpu.warm_keys_dir,
                            bucket_floor=opts.tpu.bucket_floor,
                            fallback=opts.tpu.fallback,

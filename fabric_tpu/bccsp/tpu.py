@@ -55,9 +55,9 @@ def host_prep_scalars(pub, signature):
     for native/batchprep.cpp (differential-tested): strict DER +
     low-S + scalar-range gates, then the device operand scalars.
     Returns (r, rpn, w) as 32-byte big-endian rows, or None when the
-    lane is host-rejected. ONE implementation — the whole-batch path,
-    the pipelined prep worker, and bench.py all call this; a policy
-    change here cannot desynchronize them."""
+    lane is host-rejected. ONE implementation — the whole-batch path
+    and the pipelined prep worker both call this; a policy change here
+    cannot desynchronize them."""
     rs = swmod.check_signature(pub, signature)
     if rs is None:
         return None
@@ -82,6 +82,19 @@ _DEVICE_INFO: dict = {}     # TPUProvider.device_info() memo
 # the host then waits for.
 SPAN_LANES_PER_DEVICE = 2048
 
+# granule of a span's lanes on each device: the TPU's vector registers
+# are 128 lanes wide, and under a mesh every device takes an equal slice
+LANE_ALIGN = 128
+
+
+def aligned_span(lanes: int, mesh_size: int = 1) -> int:
+    """Round a requested pipeline-chunk lane count to a multiple of
+    LANE_ALIGN * mesh_size (floor, min one granule), so every span of
+    every batch reuses one compiled shape that splits evenly over the
+    mesh."""
+    granule = LANE_ALIGN * max(1, mesh_size)
+    return max(granule, (lanes // granule) * granule)
+
 
 class TPUProvider(api.BCCSP):
     def __init__(self, keystore=None, min_batch: int = 16,
@@ -91,7 +104,6 @@ class TPUProvider(api.BCCSP):
                  use_g16: Optional[bool] = None,
                  table_cache_bytes: int = 6 << 30,
                  hash_on_host: bool = True,
-                 fused_verify: Optional[bool] = None,
                  warm_keys_dir: Optional[str] = None,
                  bucket_floor: int = 0,
                  fallback: Optional[breaker_mod.BreakerConfig] = None,
@@ -135,15 +147,6 @@ class TPUProvider(api.BCCSP):
         # trade when the accelerator link is PCIe-fast and host cores
         # are the scarce resource.
         self._hash_on_host = hash_on_host
-        # round-20 fused device path (BCCSP.TPU.FusedVerify): hash
-        # message lanes ON DEVICE inside one Pallas program fused with
-        # the comb (ops/fused_verify.py) — host ships padded SHA
-        # blocks instead of hashing, the device returns verdict
-        # bitmaps. None = auto: on for real TPU backends (where the
-        # host SHA stage is the serialized slice of host_prep_s), off
-        # on CPU rigs (interpret-mode Pallas would be slower than the
-        # OpenSSL-class host hash). FTPU_FUSED=0/1 overrides.
-        self._fused_verify = fused_verify
         # elastic device mesh: `_mesh` is the SERVING mesh (swapped
         # for a smaller one over the survivors when a chip is
         # quarantined, grown back on probe re-admission); `_mesh_full`
@@ -251,12 +254,6 @@ class TPUProvider(api.BCCSP):
                       "q16_disk_loads": 0, "q8_disk_loads": 0,
                       "q16_loading_skips": 0,
                       "nonp256_sw_lanes": 0,
-                      # round-20 fused-kernel counters: batches served
-                      # by the fused Pallas path, message lanes hashed
-                      # on device, and demotions to the host-hash
-                      # comb-digest fallback
-                      "fused_batches": 0, "fused_lanes": 0,
-                      "fused_fallbacks": 0,
                       "ed25519_batches": 0,
                       "bls_aggregate_checks": 0,
                       # round-21 pairing-engine counters: device
@@ -401,45 +398,6 @@ class TPUProvider(api.BCCSP):
                         self._use_g16)
         return self._use_g16
 
-    def _tree_impl(self) -> str:
-        """Pick the tree-reduction implementation for the comb path.
-
-        "xla" (comb._tree_reduce) on every backend. "pallas"
-        (ops/ptree.py — the whole complete-add tree in VMEM) is
-        opt-in with FTPU_PALLAS=1: the v5e compiler accepts it, but
-        takes ~16 min for the one program (tools/chip_compile.py,
-        PR 23: 954 s at 32,768 lanes against 128 s for the XLA tree) —
-        a cold peer would validate nothing for that long, so it is
-        not a default until the kernel compiles in the time a node
-        start can spend. Under a device mesh the comb pipeline runs
-        inside `shard_map` (per-shard programs), so either tree is
-        legal there.
-        """
-        import os
-        return ("pallas" if os.environ.get("FTPU_PALLAS") == "1"
-                else "xla")
-
-    def _fused_enabled(self) -> bool:
-        """Resolve the fused-verify knob (BCCSP.TPU.FusedVerify).
-
-        FTPU_FUSED=0/1 overrides for experiments and the fused CI
-        subset; explicit knob next; otherwise OFF on every backend.
-        It was auto-on for TPU backends until the first compile for a
-        real v5e (PR 23): Mosaic refused the SHA kernel as it then
-        was (`lax.scan` with scanned inputs inside a kernel), so the
-        default threw and demoted on every batch. The kernel is
-        repaired and compiles (tests/test_chip_compile.py), but the
-        tier has not RUN on a chip, a peer's block validation never
-        used it anyway — native block prep hashes on the host — and a
-        second hash tier is a second whole pipeline to compile (~2
-        min) on a cold node. Opt in with BCCSP.TPU.FusedVerify.
-        """
-        import os
-        env = os.environ.get("FTPU_FUSED")
-        if env is not None:
-            return env != "0"
-        return bool(self._fused_verify)
-
     def _bls_pairing_enabled(self) -> bool:
         """Resolve the BLS pairing-kernel knob (BCCSP.TPU.BLSPairing).
 
@@ -455,16 +413,6 @@ class TPUProvider(api.BCCSP):
         if self._bls_pairing is not None:
             return self._bls_pairing
         return self._on_tpu()
-
-    def _fused_resident_enabled(self) -> bool:
-        """Gate the single-program resident fused kernel (tables
-        pinned in VMEM across grid steps). Default OFF: it is the
-        experimental tier — the tiered fused path (SHA kernel + XLA
-        gather/tree) is the serving configuration; flip on with
-        FTPU_FUSED_RESIDENT=1 when the key-set table fits the VMEM
-        budget (ops/fused_verify.resident_table_bytes)."""
-        import os
-        return os.environ.get("FTPU_FUSED_RESIDENT") == "1"
 
     # -- everything non-batch delegates (pkcs11-style containment) --
 
@@ -1048,8 +996,7 @@ class TPUProvider(api.BCCSP):
         # helpers (_dispatch_arrays/_dispatch_comb_digest, and the
         # overlapped pipeline's own check) — exactly one fire per
         # logical batch, whichever path staging takes
-        fused_ok = self._fused_enabled()
-        if self._hash_on_host and not fused_ok:
+        if self._hash_on_host:
             out = self._verify_batch_pipelined(items)
             if out is not None:
                 return out
@@ -1130,7 +1077,7 @@ class TPUProvider(api.BCCSP):
                 max_len = max(max_len, len(it.message))
 
         msgs += [b""] * (bucket - n)
-        if self._hash_on_host and not fused_ok:
+        if self._hash_on_host:
             # default path: host SHA-256 → 32-byte digest lanes (runs
             # for EVERY pending lane, including empty messages — an
             # empty message still hashes to SHA-256(b""), never to a
@@ -1186,28 +1133,10 @@ class TPUProvider(api.BCCSP):
                     has_digest[i] = True
                 msgs[i] = b""
             nb = 1
-            fused_ok = False    # every lane is a digest lane now
         blocks, nblocks = sha256.pack_messages(msgs, nb)
         # digest-carrying lanes skip on-device hashing: zero their block
         # count and inject the digest after the hash stage via select
         nblocks = np.where(has_digest, 0, nblocks).astype(np.int32)
-
-        if fused_ok and 0 < len(key_map) <= self._max_keys:
-            # round-20 fused tier: SHA-256 + scalar recovery + comb
-            # windows run ON DEVICE in one Pallas program — the host
-            # ships padded blocks, never hashes. A fused failure
-            # (armed tpu.fused_verify fault, missing Mosaic lowering)
-            # demotes to the host-hash comb-digest path with
-            # bit-identical verdicts, inside _try_fused
-            out = self._try_fused(
-                bucket, key_map, key_idx, blocks, nblocks, r_b, rpn_b,
-                w_b, premask, digests, has_digest, msgs, n)
-            result = out[:n].tolist()
-            self._sw_scatter(
-                sw_lanes, result,
-                lambda ls: self._sw.verify_batch(
-                    [items[i] for i in ls]))
-            return result
 
         r_l = limb.be_bytes_to_limbs(r_b)
         rpn_l = limb.be_bytes_to_limbs(rpn_b)
@@ -1273,46 +1202,6 @@ class TPUProvider(api.BCCSP):
             sw_lanes, result,
             lambda ls: self._sw.verify_batch([items[i] for i in ls]))
         return result
-
-    def _try_fused(self, bucket, key_map, key_idx, blocks, nblocks,
-                   r8, rpn8, w8, premask, digests, has_digest, msgs,
-                   n) -> np.ndarray:
-        """Serve the batch on the fused device path, demoting to the
-        host-hash comb-digest path on ANY fused failure (armed
-        tpu.fused_verify fault, unimplemented Mosaic lowering, OOM on
-        the block tensors). The demotion is bit-identical: the same
-        lanes verify against the same tables, the only difference is
-        WHERE the SHA-256 runs. DeviceLostError propagates — a dead
-        chip is device-attributed (quarantine + mesh rebuild), not a
-        fused-tier defect, and retrying it here on the digest path
-        would just fail again while masking the attribution."""
-        fused_lanes = int(np.sum(premask[:n] & ~has_digest[:n]))
-        try:
-            out = self._dispatch_fused_verify(
-                bucket, key_map, key_idx, blocks, nblocks, r8, rpn8,
-                w8, premask, digests, has_digest)
-        except DeviceLostError:
-            raise
-        except Exception:
-            self.stats["fused_fallbacks"] += 1
-            logger.exception(
-                "fused verify dispatch failed; demoting %d lanes to "
-                "the host-hash comb-digest path", n)
-            hashed = 0
-            for i in range(n):
-                if premask[i] and not has_digest[i]:
-                    digests[i] = np.frombuffer(
-                        self._sw.hash(msgs[i]), dtype=">u4")
-                    has_digest[i] = True
-                    hashed += 1
-            self.stats["host_hashed_lanes"] += hashed
-            self.stats["comb_batches"] += 1
-            return self._dispatch_comb_digest(
-                bucket, key_map, key_idx, r8, rpn8, w8, premask,
-                digests)
-        self.stats["fused_batches"] += 1
-        self.stats["fused_lanes"] += fused_lanes
-        return out
 
     # -- the Ed25519 batch path (scheme router "ed25519" lanes) --
 
@@ -1594,19 +1483,17 @@ class TPUProvider(api.BCCSP):
         SPAN_LANES_PER_DEVICE for each device of the serving mesh
         (2,048 on one chip; 8,192 on a four-chip mesh, whose shard_map
         program so keeps 2,048 lanes a chip) — floored to the
-        Pallas-tile/mesh granule (ops/ptree.py aligned_span) and
-        capped at Chunk. None when the overlapped pipeline is
-        disabled — including when the mesh granule itself exceeds
-        Chunk (the span must never break the per-dispatch staging
-        cap)."""
+        lane/mesh granule (aligned_span) and capped at Chunk. None
+        when the overlapped pipeline is disabled — including when the
+        mesh granule itself exceeds Chunk (the span must never break
+        the per-dispatch staging cap)."""
         ndev = self._mesh.size if self._mesh is not None else 1
         pc = self._pipeline_chunk
         if pc is None:
             pc = SPAN_LANES_PER_DEVICE * ndev
         if pc <= 0:
             return None
-        from fabric_tpu.ops import ptree
-        span = ptree.aligned_span(min(pc, self._chunk), ndev)
+        span = aligned_span(min(pc, self._chunk), ndev)
         return span if span <= self._chunk else None
 
     def _prep_executor(self):
@@ -2689,27 +2576,6 @@ class TPUProvider(api.BCCSP):
                 g16 = jax.device_put(g16, rep)
         return key_idx, K, q_flat, g16, q16
 
-    def prepared_digest_pipeline(self, key_map, key_idx):
-        """Supported measurement/diagnostic surface (bench.py, ops
-        tooling): canonical key order, resident tables and the
-        provider's own compiled digest-lane pipeline — WITHOUT
-        private-cache peeking. BENCH_r04 postmortem: the bench read
-        `_qflat_cache` directly and crashed with KeyError when the
-        cache policy changed under it; measurements now go through
-        this method, which degrades to the 8-bit path exactly as
-        `verify_batch` would instead of crashing.
-
-        key_map: {pubkey_bytes(64B x||y): slot}; key_idx: int array of
-        per-lane slots. Returns (fn, key_idx, tables) where tables is
-        a dict {"q_flat", "g16", "q16": bool, "K"}; invoke as
-        fn(key_idx_chunk, q_flat, g16, r, rpn, w, premask, digests)."""
-        key_idx = np.asarray(key_idx, dtype=np.int32)
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(
-            dict(key_map), key_idx)
-        fn = self._comb_pipeline_digest(K, q16)
-        return fn, key_idx, {"q_flat": q_flat, "g16": g16,
-                             "q16": q16, "K": K}
-
     @hot_path
     @tracing.traced("tpu.shard_put")
     def _shard_put(self, arrs, timings=None):
@@ -2955,92 +2821,6 @@ class TPUProvider(api.BCCSP):
         return thunk if async_out else thunk()
 
     @hot_path
-    @tracing.traced("tpu.fused_verify")
-    def _dispatch_fused_verify(self, bucket, key_map, key_idx, blocks,
-                               nblocks, r8, rpn8, w8, premask, digests,
-                               has_digest, async_out=False):
-        """Round-20 fused dispatch: padded SHA blocks + compact u8
-        scalars ship to the device, ONE Pallas program hashes, recovers
-        the (u1, u2) scalars and combs (ops/fused_verify.py) — only
-        verdict bitmaps come back. Same transfer-ahead double buffer
-        as the digest path: chunk k+1's H2D rides under chunk k's
-        execution. The `tpu.fused_verify` fault point arms the
-        fused-tier chaos demotion (see _try_fused); `tpu.dispatch`
-        stays the once-per-batch device seam."""
-        lockcheck.note_blocking("tpu.dispatch")
-        faults.check("tpu.fused_verify")
-        faults.check("tpu.dispatch")
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
-                                                            key_idx)
-        chunk = self._mesh_chunk(bucket)
-        fn = self._fused_pipeline(K, q16)
-        return self._dispatch_chunks(
-            bucket, chunk,
-            (blocks, nblocks, key_idx, r8, rpn8, w8, premask, digests,
-             has_digest),
-            lambda c: fn(c[0], c[1], c[2], q_flat, g16, *c[3:]),
-            async_out)
-
-    def _fused_pipeline(self, K: int, q16: bool):
-        """Build (once per (K, q16)) the jitted fused-verify program.
-        Same seams as the comb pipelines: `_jit` (compile telemetry +
-        tpu.compile fault point), shard_map per-shard programs under a
-        mesh, 8-bit two-table fallback when q16 denied. The resident
-        single-program variant (tables pinned in VMEM across grid
-        steps) is gated by FTPU_FUSED_RESIDENT and the VMEM budget."""
-        key = ("fused", K, q16)
-        with self._jit_lock:
-            if key not in self._comb_fns:
-                from fabric_tpu.ops import fused_verify as fv
-
-                use_g16 = self._g16_enabled() and q16
-                tree = self._tree_impl() if q16 else "xla"
-                resident = (self._fused_resident_enabled() and not q16
-                            and fv.resident_table_bytes(K)
-                            <= fv.RESIDENT_TABLE_BUDGET)
-
-                def fused(blocks, nblocks, key_idx, q_flat, g16, r8,
-                          rpn8, w8, premask, digests, has_digest):
-                    if resident:
-                        return fv.fused_verify_resident(
-                            blocks, nblocks, key_idx, q_flat, r8,
-                            rpn8, w8, premask, digests, has_digest)
-                    return fv.fused_verify_with_tables(
-                        blocks, nblocks, key_idx, q_flat, r8, rpn8,
-                        w8, premask, digests, has_digest,
-                        g16=g16 if use_g16 else None, q16=q16,
-                        tree=tree)
-
-                if self._mesh is not None:
-                    from jax.sharding import PartitionSpec as P
-                    s = P("batch")
-                    rep = P()
-                    self._comb_fns[key] = self._jit(
-                        "fused_verify", jaxenv.shard_map(
-                            fused, mesh=self._mesh,
-                            in_specs=(s, s, s, rep, rep, s, s, s, s,
-                                      s, s),
-                            out_specs=s))
-                else:
-                    self._comb_fns[key] = self._jit("fused_verify",
-                                                    fused)
-            return self._comb_fns[key]
-
-    def prepared_fused_pipeline(self, key_map, key_idx):
-        """Measurement surface for the fused path (bench.py), the twin
-        of prepared_digest_pipeline: canonical key order, resident
-        tables, and the provider's compiled fused program — no private
-        cache peeking. Returns (fn, key_idx, tables); invoke as
-        fn(blocks, nblocks, key_idx_chunk, q_flat, g16, r8, rpn8, w8,
-        premask, digests, has_digest)."""
-        key_idx = np.asarray(key_idx, dtype=np.int32)
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(
-            dict(key_map), key_idx)
-        fn = self._fused_pipeline(K, q16)
-        return fn, key_idx, {"q_flat": q_flat, "g16": g16,
-                             "q16": q16, "K": K}
-
-    @hot_path
     @tracing.traced("tpu.comb")
     def _dispatch_comb(self, bucket, key_map, key_idx, blocks, nblocks,
                        r_l, rpn_l, w_l, premask, digests, has_digest,
@@ -3102,10 +2882,6 @@ class TPUProvider(api.BCCSP):
             # serve the adaptive-overflow and restore-pending windows,
             # and must not block on (or embed) the ~252 MB g16 build
             use_g16 = self._g16_enabled() and q16
-            # the Pallas VMEM tree is tuned for the 32-point (16-bit
-            # window) tree; the 64-point 8-bit tree hits unimplemented
-            # Mosaic lowerings — q8 dispatches keep the XLA tree
-            tree = self._tree_impl() if q16 else "xla"
 
             def fused(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
                       premask, digests, has_digest):
@@ -3114,13 +2890,11 @@ class TPUProvider(api.BCCSP):
                 words = jnp.where(has_digest[:, None], digests, hashed)
                 return comb.comb_verify_with_tables(
                     words, key_idx, q_flat, r, rpn, w, premask,
-                    g16=g16 if use_g16 else None, q16=q16, tree=tree)
+                    g16=g16 if use_g16 else None, q16=q16)
 
             if self._mesh is not None:
-                # shard_map, not GSPMD: the flagship q16 + pallas-tree
-                # configuration contains a pallas_call XLA cannot
-                # auto-partition, but as a per-shard program each chip
-                # simply combs its own batch slice against replicated
+                # shard_map, not GSPMD: as a per-shard program each
+                # chip combs its own batch slice against replicated
                 # tables — no collectives in the main path at all
                 from jax.sharding import PartitionSpec as P
                 s = P("batch")
@@ -3153,7 +2927,6 @@ class TPUProvider(api.BCCSP):
                 # windows, and must not block on (or embed) the
                 # ~252 MB g16 build
                 use_g16 = self._g16_enabled() and q16
-                tree = self._tree_impl() if q16 else "xla"
 
                 def fused(key_idx, q_flat, g16, r8, rpn8, w8, premask,
                           digests):
@@ -3162,8 +2935,7 @@ class TPUProvider(api.BCCSP):
                     w = limb.be_bytes_to_limbs_jnp(w8)
                     return comb.comb_verify_with_tables(
                         digests, key_idx, q_flat, r, rpn, w, premask,
-                        g16=g16 if use_g16 else None, q16=q16,
-                        tree=tree)
+                        g16=g16 if use_g16 else None, q16=q16)
 
                 if self._mesh is not None:
                     from jax.sharding import PartitionSpec as P

@@ -8,11 +8,6 @@ The well-known points:
     tpu.dispatch       every device batch dispatch (bccsp/tpu.py)
     tpu.compile        jit pipeline builds / AOT compiles
     tpu.table_persist  warm-table byte writers
-    tpu.fused_verify   the round-20 fused Pallas dispatch (device
-                       SHA-256 + comb in one program) — a fault
-                       demotes the batch to the host-hash
-                       comb-digest path, bit-identical verdicts
-                       (bccsp/tpu.py _dispatch_fused_verify)
     tpu.ed25519        the scheme router's Ed25519 device dispatch —
                        a fault serves the sub-batch on the host
                        reference path, bit-identical (bccsp/tpu.py)
@@ -141,7 +136,6 @@ class FaultInjected(RuntimeError):
 KNOWN_POINTS = frozenset({
     "tpu.dispatch",
     "tpu.compile",
-    "tpu.fused_verify",
     "tpu.table_persist",
     "tpu.ed25519",
     "tpu.bls_aggregate",

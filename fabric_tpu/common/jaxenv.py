@@ -76,31 +76,11 @@ def enable_compilation_cache() -> str | None:
 
 def shard_map(fn, mesh, in_specs, out_specs):
     """`jax.shard_map` for the sharded verify pipeline, with
-    replication checking off: the comb pipeline may contain a
-    pallas_call custom call the checker cannot see through, and the
-    tables really are replicated by construction
-    (`TPUProvider._resolve_tables` places them with an empty
-    PartitionSpec)."""
+    replication checking off: every output is batch-sharded, so there
+    is no replicated result for it to vouch for, and the tables really
+    are replicated by construction (`TPUProvider._resolve_tables`
+    places them with an empty PartitionSpec)."""
     import jax
 
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-def pallas_interpret() -> bool:
-    """Whether Pallas programs should run under ``interpret=True``.
-
-    On a CPU backend (tier-1 tests, rehearsals) every Pallas kernel
-    (ops/ptree.py, ops/fused_verify.py) runs interpreted — same
-    program, traced through XLA on CPU — and compiles through Mosaic
-    only when a TPU backend is attached. FTPU_PALLAS_INTERPRET=0/1
-    overrides the autodetect for A/B runs on real chips.
-    """
-    # ftpu-check: allow-retrace(compile-time config by design: the
-    # interpret flag is pinned for the process, read once per trace)
-    env = os.environ.get("FTPU_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    import jax
-
-    return jax.default_backend() != "tpu"

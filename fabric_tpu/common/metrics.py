@@ -167,26 +167,6 @@ BCCSP_SCHEME_DISPATCHES_OPTS = GaugeOpts(
          "per scheme (one per routed sub-batch; for bls, one per "
          "aggregate pairing check).", label_names=("scheme",))
 
-BCCSP_FUSED_BATCHES_OPTS = GaugeOpts(
-    namespace="bccsp", subsystem="fused", name="batches",
-    help="Verify batches served end to end by the round-20 fused "
-         "Pallas tier (device SHA-256 + scalar recovery + comb in one "
-         "program — the host never hashes message lanes).")
-
-BCCSP_FUSED_LANES_OPTS = GaugeOpts(
-    namespace="bccsp", subsystem="fused", name="lanes",
-    help="Message lanes whose SHA-256 ran on device inside the fused "
-         "verify program since process start (digest-bearing lanes "
-         "skip the hash stage and are not counted).")
-
-BCCSP_FUSED_FALLBACKS_OPTS = GaugeOpts(
-    namespace="bccsp", subsystem="fused", name="fallbacks",
-    help="Fused-tier dispatches demoted to the host-hash comb-digest "
-         "path (missing Pallas lowering, armed tpu.fused_verify "
-         "fault, or a fused-program error) — verdicts stay "
-         "bit-identical; a nonzero steady rate means the flagship "
-         "tier is not actually serving.")
-
 BCCSP_PAIRING_PAIRS_OPTS = GaugeOpts(
     namespace="bccsp", subsystem="pairing", name="pairs",
     help="Miller pairs served by the device pairing engines since "

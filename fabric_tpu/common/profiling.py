@@ -210,11 +210,6 @@ def publish_provider_stats(metrics_provider, csp, poll_s: float = 5.0):
         "compile_cache_hits":
             metrics_mod.BCCSP_COMPILE_CACHE_HITS_OPTS,
         "compile_seconds": metrics_mod.BCCSP_COMPILE_SECONDS_OPTS,
-        # round-20 fused tier: the serving/demotion counters operators
-        # watch to confirm the flagship fused path is the one serving
-        "fused_batches": metrics_mod.BCCSP_FUSED_BATCHES_OPTS,
-        "fused_lanes": metrics_mod.BCCSP_FUSED_LANES_OPTS,
-        "fused_fallbacks": metrics_mod.BCCSP_FUSED_FALLBACKS_OPTS,
         # round-21 pairing engine: serving/demotion counters spanning
         # both device pairing paths (BLS12-381 aggregates, BN254
         # idemix products)

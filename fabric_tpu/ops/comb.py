@@ -329,8 +329,7 @@ def comb_gather_points(u1, u2, key_idx, g_flat, q_flat, K: int,
     """Gather the per-signature comb points: (B, M, 3, L).
 
     M = (16 or 32 G-side) + (16 or 32 Q-side) depending on window
-    widths. The subsequent tree sum is done either by `_tree_reduce`
-    (XLA) or by the Pallas VMEM kernel (fabric_tpu/ops/ptree.py).
+    widths. `_tree_reduce` sums them.
     """
     if g16 is not None:
         w1 = _windows(u1, 16)               # (B, 16)
@@ -368,16 +367,14 @@ def comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat, K: int,
 
 
 def comb_verify_with_tables(digest_words, key_idx, q_flat, r, rpn, w,
-                            premask, g16=None, q16: bool = False,
-                            tree: str = "xla"):
+                            premask, g16=None, q16: bool = False):
     """Batched ECDSA accept/reject against a prebuilt Q-table.
 
     q_flat: from build_q_tables (8-bit windows; q16=False) or
     build_q16_tables (16-bit; q16=True) — built once per key set and
     reused across blocks/chunks. g16: optional 16-bit G-window table
     (g16_tables()); with both 16-bit sides the per-signature tree has
-    32 points. tree: "xla" (fusion-island graph) or "pallas" (the
-    VMEM tree kernel, ops/ptree.py — the fast path on real TPUs).
+    32 points.
     """
     ent = NWIN_G16 * NENT_G16 if q16 else NWIN * NENT
     K = q_flat.shape[0] // ent
@@ -385,11 +382,6 @@ def comb_verify_with_tables(digest_words, key_idx, q_flat, r, rpn, w,
     e = limb.words_be_to_limbs(digest_words)
     u1 = FN.canonical(FN.mulmod(e, w))
     u2 = FN.canonical(FN.mulmod(r, w))
-    if tree == "pallas":
-        from fabric_tpu.ops import ptree
-        pts = comb_gather_points(u1, u2, key_idx, g_flat, q_flat, K,
-                                 g16=g16, q16=q16)
-        return ptree.tree_verify_points(pts, r, rpn, premask)
     X, _, Z = comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat, K,
                                      g16=g16, q16=q16)
     nonzero = jnp.any(FP.canonical(Z) != 0, axis=-1)

@@ -118,10 +118,8 @@ def pack_messages(msgs: list[bytes], nb: int) -> tuple[np.ndarray, np.ndarray]:
     counts. Every message must satisfy len(msg) <= max_message_len(nb).
 
     Vectorized: one flat-byte scatter plus numpy word assembly instead
-    of a per-message Python loop — at 30k lanes the loop was itself a
-    measurable slice of host_prep_s (round-20 fused-kernel bench).
-    Byte-identical to the per-message reference; pinned by
-    tests/test_fused_verify.py::TestPackMessages.
+    of a per-message Python loop. Byte-identical to the per-message
+    reference; pinned by tests/test_sha256.py::TestPackMessages.
     """
     B = len(msgs)
     out = np.zeros((B, nb, 16), dtype=np.uint32)

@@ -55,16 +55,15 @@ def sharded_verify_fn(mesh: Mesh):
     )
 
 
-def shardmap_comb_verify(mesh: Mesh, q16: bool, tree: str = "xla"):
+def shardmap_comb_verify(mesh: Mesh, q16: bool):
     """The flagship comb pipeline as a per-shard program (shard_map).
 
     This is the SAME layout the TPU provider compiles under a mesh
     (bccsp/tpu.py _comb_pipeline_locked): batch-sharded operand lanes,
     replicated tables, no collectives — shard_map rather than GSPMD so
-    the pallas VMEM tree (a custom call the partitioner cannot split)
-    is legal per shard. With q16=True the 16-bit window configuration
-    (the measured single-chip headline) is exercised; tree="xla" keeps
-    the gate runnable on CPU meshes where pallas cannot lower.
+    each chip runs the whole per-shard program on its own lanes and
+    the partitioner has nothing to decide. With q16=True the 16-bit
+    window configuration (the one the chip serves) is exercised.
     """
     from fabric_tpu.common import jaxenv
     from fabric_tpu.ops import comb
@@ -72,7 +71,7 @@ def shardmap_comb_verify(mesh: Mesh, q16: bool, tree: str = "xla"):
     def local(words, key_idx, q_flat, g16, r, rpn, w, premask):
         return comb.comb_verify_with_tables(
             words, key_idx, q_flat, r, rpn, w, premask,
-            g16=g16 if q16 else None, q16=q16, tree=tree)
+            g16=g16 if q16 else None, q16=q16)
 
     s = P(BATCH_AXIS)
     rep = P()

@@ -63,6 +63,11 @@ class Channel:
         self._definitions: dict[str, ChaincodeDefinition] = {}
         self._commit_listeners: list[Callable] = []
         self._commit_cond = threading.Condition()
+        # blocks whose WHOLE ledger commit (block store, history,
+        # state) is done: an open ledger has recovered, so that is its
+        # height; from then on `_notify_commit` advances it. The block
+        # store's own height runs ahead of it inside a commit.
+        self._applied_height = ledger.height
 
         cfg_block = self._find_last_config_block()
         self._apply_config(cfg_block)
@@ -320,7 +325,7 @@ class Channel:
                 except Exception:
                     continue
         with self._commit_cond:
-            self._last_committed = block.header.number
+            self._applied_height = block.header.number + 1
             self._commit_cond.notify_all()
         for cb in list(self._commit_listeners):
             try:
@@ -333,9 +338,11 @@ class Channel:
 
     def wait_for_height(self, height: int,
                         timeout: Optional[float] = None) -> bool:
+        """True once `height` blocks are committed AND applied: a
+        caller that then reads state or the commit hash sees them."""
         with self._commit_cond:
             return self._commit_cond.wait_for(
-                lambda: self.ledger.height >= height, timeout)
+                lambda: self._applied_height >= height, timeout)
 
     def tx_validation_code(self, tx_id: str) -> Optional[int]:
         ptx = self.ledger.get_transaction_by_id(tx_id)

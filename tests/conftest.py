@@ -1,8 +1,8 @@
 """Test bootstrap: force an 8-device virtual CPU mesh before JAX imports.
 
 Multi-chip sharding paths (fabric_tpu/parallel) are exercised on a virtual
-8-device CPU backend so the suite runs anywhere; real-TPU benchmarking lives
-in bench.py, which does NOT import this.
+8-device CPU backend so the suite runs anywhere; the benchmark on the chip is
+benchmark/run.py, which does NOT import this.
 """
 
 import os

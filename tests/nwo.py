@@ -234,7 +234,11 @@ class Network:
                     crypto, "ordererOrganizations", "example.com",
                     "orderers", f"orderer{i}.example.com", "msp"),
                 "LocalMSPID": "OrdererMSP",
-                "BootstrapFiles": [self.genesis_path],
+                # a spare orderer starts with no channel: the test
+                # joins it through channel participation (osnadmin),
+                # which refuses a channel the node already bootstrapped
+                "BootstrapFiles": ([self.genesis_path]
+                                   if i < self.n_orderers else []),
             },
             "FileLedger": {"Location": os.path.join(
                 self.root, f"orderer{i}", "ledger")},

@@ -6,6 +6,7 @@ adversarial corpus (bad DER, high-S, out-of-range scalars, tampered
 digests, wrong keys) — the reference's semantics at `bccsp/sw/ecdsa.go:41-57`.
 """
 
+import functools
 import hashlib
 import os
 
@@ -27,7 +28,7 @@ from fabric_tpu.bccsp import (
 from fabric_tpu.bccsp import factory, utils
 from fabric_tpu.bccsp.keystore import FileKeyStore
 from fabric_tpu.bccsp.sw import SWProvider
-from fabric_tpu.bccsp.tpu import TPUProvider
+from fabric_tpu.bccsp.tpu import TPUProvider, host_prep_scalars
 
 
 class TestDERUtils:
@@ -348,6 +349,154 @@ def _corpus():
     return items
 
 
+# ---------------------------------------------------------------------------
+# one lane kind at a time, on the program that serves it
+# ---------------------------------------------------------------------------
+
+LANES = 16          # TPUProvider(min_batch=4)'s bucket for these batches
+LANE_KINDS = ("message", "digest", "empty_message", "long_message",
+              "tampered", "wrong_key", "high_s", "malformed_der",
+              "trailing_der_bytes", "r_out_of_range", "short_digest",
+              "non_p256")
+# a prepared batch arrives hashed, 32 bytes a lane: its lanes are
+# digest lanes, and the message kinds are native block prep's business
+# upstream of the seam
+PREPARED_KINDS = ("digest", "tampered", "wrong_key", "high_s",
+                  "malformed_der", "trailing_der_bytes",
+                  "r_out_of_range", "non_p256")
+SW_LANE_KINDS = ("non_p256", "short_digest")    # verified lane by lane
+FALLBACK_COUNTERS = ("sw_fallbacks", "host_hash_fallbacks",
+                     "degraded_batches", "pairing_fallbacks",
+                     "breaker_trips", "compile_failures")
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_keys():
+    """17 P-256 keys (one more than MaxKeys) and one P-384 key."""
+    from fabric_tpu.bccsp.bccsp import ECDSAPrivateKeyImportOpts
+    sw = SWProvider()
+    p256 = [sw.key_gen(ECDSAKeyGenOpts(ephemeral=True))
+            for _ in range(17)]
+    p384 = sw.key_import(ec.generate_private_key(ec.SECP384R1()),
+                         ECDSAPrivateKeyImportOpts())
+    return p256, p384
+
+
+def _kind_lane(kind, i, key_no, nkeys, second=False):
+    """Lane `i` of a batch over `nkeys` P-256 keys, signed by key
+    `key_no`, of one kind (of a kind's two lanes the second is the
+    tampered P-384 one)."""
+    sw = SWProvider()
+    p256, p384 = _lane_keys()
+    k = p256[key_no]
+    pub = k.public_key()
+    m = f"lane {i} of kind {kind}".encode()
+    if kind == "empty_message":
+        m = b""
+    elif kind == "long_message":        # beyond MaxBlocks = 64 blocks
+        m = bytes(range(256)) * 20 + m
+    if kind == "non_p256":
+        pub = p384.public_key()
+        sig = sw.sign(p384, hashlib.sha256(m).digest())
+        if second:
+            m += b"!"
+        return VerifyItem(key=pub, signature=sig, message=m)
+    sig = sw.sign(k, hashlib.sha256(m).digest())
+    if kind == "digest":
+        return VerifyItem(key=pub, signature=sig,
+                          digest=hashlib.sha256(m).digest())
+    if kind == "short_digest":          # not a SHA-256 digest
+        return VerifyItem(key=pub, signature=sig, digest=b"\x00" * 20)
+    if kind == "tampered":
+        m += b"!"
+    elif kind == "wrong_key":
+        pub = p256[(key_no + 1) % nkeys].public_key()
+    elif kind == "high_s":
+        r, s_ = utils.unmarshal_signature(sig)
+        sig = utils.marshal_signature(r, utils.P256_N - s_)
+    elif kind == "malformed_der":
+        sig = sig[:-2]
+    elif kind == "trailing_der_bytes":  # Go's asn1 ignores the rest
+        sig += b"\x00\x01"
+    elif kind == "r_out_of_range":
+        sig = utils.marshal_signature(utils.P256_N, 5)
+    return VerifyItem(key=pub, signature=sig, message=m)
+
+
+def _kind_batch(kinds, lanes=LANES, nkeys=3):
+    """`lanes` valid message lanes that between them use all `nkeys`
+    keys, with two lanes of each of `kinds` among them; the sw
+    provider's verdicts."""
+    spots = {}
+    for j, kind in enumerate(kinds):
+        spots[1 + 2 * j] = (kind, False)
+        spots[lanes - 2 - 2 * j] = (kind, True)
+    assert len(spots) == 2 * len(kinds) and max(spots) < lanes
+    assert lanes - len(spots) >= nkeys
+    items, plain = [], 0
+    for i in range(lanes):
+        if i in spots:
+            kind, second = spots[i]
+            items.append(_kind_lane(kind, i, i % nkeys, nkeys, second))
+        else:           # the plain lanes go round all the keys
+            items.append(_kind_lane("message", i, plain % nkeys, nkeys))
+            plain += 1
+    want = SWProvider().verify_batch(items)
+    assert any(want)
+    return items, want
+
+
+def _message_lanes(items):
+    """Lanes the provider must hash itself: live P-256 message lanes."""
+    return sum(1 for it in items
+               if it.digest is None and it.key.is_p256()
+               and host_prep_scalars(it.key, it.signature) is not None)
+
+
+def _prepared_args(items):
+    """`items` as native block prep hands them to `verify_prepared`:
+    digests, the scalars of every signature that parses, lanes grouped
+    by key."""
+    n = len(items)
+    der_ok = np.zeros(n, dtype=bool)
+    r, rpn, w = (np.zeros((n, 32), dtype=np.uint8) for _ in range(3))
+    keys, slot, key_idx = [], {}, np.zeros(n, dtype=np.int32)
+    digests = np.zeros((n, 32), dtype=np.uint8)
+    for i, it in enumerate(items):
+        prep = (host_prep_scalars(it.key, it.signature)
+                if it.key.is_p256() else None)
+        if prep is not None:
+            der_ok[i] = True
+            r[i], rpn[i], w[i] = (np.frombuffer(b, np.uint8)
+                                  for b in prep)
+        if (it.key.x, it.key.y) not in slot:
+            slot[it.key.x, it.key.y] = len(keys)
+            keys.append(it.key)
+        key_idx[i] = slot[it.key.x, it.key.y]
+        digests[i] = np.frombuffer(
+            it.digest or hashlib.sha256(it.message).digest(), np.uint8)
+    return (digests, r, rpn, w, der_ok, key_idx, keys,
+            lambda i: items[i].signature)
+
+
+def _verify(prov, entry, items):
+    if entry == "verify_prepared":
+        return prov.verify_prepared(*_prepared_args(items))
+    return prov.verify_batch(items)
+
+
+def _moved(prov, before):
+    return {k: prov.stats[k] - v for k, v in before.items()
+            if isinstance(v, (int, float)) and prov.stats[k] != v}
+
+
+@pytest.fixture(scope="module")
+def lane_provider():
+    """ONE provider for every lane-kind case: each (K, lanes) program
+    is traced and loaded once for the module."""
+    return TPUProvider(min_batch=4)
+
+
 class TestDifferential:
     def test_tpu_matches_sw_bit_identical(self):
         expected_and_items = _corpus()
@@ -378,8 +527,8 @@ class TestDifferential:
         assert tpu.verify_batch(items) == [e for e, _ in expected_and_items]
 
     def test_hash_on_host_and_device_hash_agree(self):
-        """The default (host SHA-256 → digest lanes) and the fused
-        device-SHA pipeline (HashOnHost: false) must be bit-identical
+        """The default (host SHA-256 → digest lanes) and the
+        device-SHA `comb` program (HashOnHost: false) must be bit-identical
         on a mixed valid/tampered/digest-lane batch — and both must run
         the device path, not the sw fallback."""
         expected_and_items = _corpus()
@@ -425,3 +574,75 @@ class TestDifferential:
             raise AssertionError("sw fallback ran; device path failed")
         tpu._sw.verify_batch = boom
         assert tpu.verify_batch(items) == expected
+
+    @pytest.mark.parametrize("entry, kind", [
+        ("verify_batch", k) for k in LANE_KINDS] + [
+        ("verify_prepared", k) for k in PREPARED_KINDS])
+    def test_lane_kind_served_by_comb_digest(self, lane_provider, entry,
+                                             kind):
+        """Every kind of lane a batch can carry, through the entry that
+        can carry it: the sw provider's verdict, from `comb_digest`
+        after a host hash — no other program, no fallback rung."""
+        prov = lane_provider
+        items, want = _kind_batch((kind,))
+        before = dict(prov.stats)
+        events = len(prov.device_cost.events)
+        assert _verify(prov, entry, items) == want
+        moved = _moved(prov, before)
+        assert moved.pop("comb_batches") == 1
+        hashed = _message_lanes(items) if entry == "verify_batch" else 0
+        assert moved.pop("host_hashed_lanes", 0) == hashed
+        assert moved.pop("nonp256_sw_lanes", 0) == \
+            (2 if kind in SW_LANE_KINDS else 0)
+        assert not set(moved) & set(FALLBACK_COUNTERS), moved
+        assert "ladder_batches" not in moved
+        assert "pipeline_batches" not in moved  # one span: no overlap
+        assert {e["kind"] for e in prov.device_cost.events[events:]} \
+            <= {"qtab", "comb_digest"}
+
+    @pytest.mark.parametrize("entry, kinds", [
+        ("verify_batch", ("message", "digest", "empty_message",
+                          "long_message")),
+        ("verify_batch", ("tampered", "wrong_key", "high_s",
+                          "malformed_der", "r_out_of_range",
+                          "short_digest", "non_p256")),
+        ("verify_prepared", ("digest", "tampered", "wrong_key", "high_s",
+                             "malformed_der", "non_p256")),
+    ], ids=["accept_kinds", "reject_kinds", "prepared"])
+    def test_more_than_max_keys_served_by_ladder(self, lane_provider,
+                                                 entry, kinds):
+        """The other side of the one choice: 17 distinct keys are one
+        more than MaxKeys, and the batch goes to the ladder."""
+        prov = lane_provider
+        items, want = _kind_batch(kinds, lanes=32, nkeys=17)
+        assert len({(it.key.x, it.key.y) for it in items
+                    if it.key.is_p256()}) > prov._max_keys
+        before = dict(prov.stats)
+        assert _verify(prov, entry, items) == want
+        moved = _moved(prov, before)
+        assert moved.pop("ladder_batches") == 1
+        assert "comb_batches" not in moved
+        assert not set(moved) & set(FALLBACK_COUNTERS), moved
+
+    def test_program_inventory(self, lane_provider):
+        """What stops a sixth tier arriving unannounced: after
+        prewarm() and one batch on each side of MaxKeys, every program
+        the compile seam has named — here and in every case above that
+        ran on this provider — is one of the five a P-256 batch can
+        need."""
+        prov = lane_provider
+        prov.prewarm(buckets=(LANES,), bounded=True)
+        assert prov.stats["prewarm_done"] == 1
+        for lanes, nkeys, counter in ((LANES, 3, "comb_batches"),
+                                      (32, 17, "ladder_batches")):
+            items, want = _kind_batch(("digest", "tampered"), lanes,
+                                      nkeys)
+            before = dict(prov.stats)
+            assert prov.verify_batch(items) == want
+            assert _moved(prov, before).get(counter) == 1
+        kinds = {e["kind"] for e in prov.device_cost.events}
+        assert {"qtab", "comb_digest", "ladder"} <= kinds
+        assert kinds <= {"qtab", "qtab16", "comb_digest", "comb",
+                         "ladder"}
+        assert not [e for e in prov.device_cost.events if e["error"]]
+        assert not [k for k in prov.stats if "fused" in k]

@@ -1,36 +1,31 @@
-"""The Pallas kernels, compiled by the REAL TPU compiler for a described
-v5e — no chip attached, nothing runs (tools/chip_compile.py is the
-by-hand twin at full size).
+"""The program the chip runs, compiled by the REAL TPU compiler for a
+described v5e — no chip attached, nothing runs (tools/chip_compile.py
+is the by-hand twin and supplies the program and its shapes).
 
-Interpret mode (every other test of these kernels) cannot see what
-Mosaic refuses: an unsupported op, a misaligned block, a bad memory
-space. PR 23 found all three kinds only here — the fused SHA kernel
-had passed every interpret-mode test since it was written and could
-not lower at all. These compiles guard that at no chip time.
-
-Only what compiles in seconds may live here (the suite runs out its
-clock): the kernels at their real `BLOCK_B` lane width and real block
-shapes, but the tree at 4 points (two levels, one of them re-packed to
-full sublanes) instead of 32 — the 32-point tree alone takes ~16 min,
-superlinear in its unrolled size. Fast-memory FIT at full size and the
-whole jitted pipelines are checked by tools/chip_compile.py, not here.
+A CPU run cannot see what the TPU compiler refuses (PR 23 found three
+such refusals only this way). This compile guards the one P-256 program
+every ledger line has measured — `comb_digest`, K = 4, 16-bit windows
+on both bases, one 2,048-lane span — at no chip time: ~70 s here, one
+core.
 
 The topology is described inside a module-scoped fixture (one process
 at a time may load libtpu; every xdist worker imports this file), with
-the persistent compilation cache off around the compiles (a
+the persistent compilation cache off around the compile (a
 described-topology entry is written but can never be read back), in
 the test's own process. Keep every such test in THIS file.
 """
 
-import numpy as np
+import importlib.util
+import os
+
 import pytest
 
 import jax
 
-from fabric_tpu.ops import fused_verify as fv, limb, ptree
+from fabric_tpu.bccsp import tpu as tpumod
+from fabric_tpu.ops import comb, limb
 
-L = limb.L
-LANES = 2 * ptree.BLOCK_B       # a grid of 2: the DMA form prefetches
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -57,35 +52,27 @@ def no_cache():
     cc.reset_cache()
 
 
-def _compile(fn, one_chip, *shapes):
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()   # Mosaic, not XLA
-    return compiled
+def _chip_compile():
+    spec = importlib.util.spec_from_file_location(
+        "chip_compile_under_test",
+        os.path.join(ROOT, "tools", "chip_compile.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-@pytest.mark.parametrize("dma", [False, True], ids=["plain", "dma"])
-def test_fused_sha_kernel_compiles(one_chip, no_cache, dma):
-    """ops/fused_verify.sha_windows, both forms, 16-bit windows, 4 SHA
-    blocks per lane (the ~250-byte messages of a block's signatures)."""
-    nb = 4
-    _compile(
-        lambda b, n, d, h, r, w: fv.sha_windows(
-            b, n, d, h, r, w, wbits_g=16, wbits_q=16, interpret=False,
-            dma=dma),
-        one_chip,
-        ((LANES, nb, 16), np.uint32), ((LANES,), np.int32),
-        ((LANES, 8), np.uint32), ((LANES,), bool),
-        ((LANES, L), np.int32), ((LANES, L), np.int32))
-
-
-def test_tree_kernel_compiles(one_chip, no_cache):
-    """ops/ptree.tree_verify_points at BLOCK_B lanes per program."""
-    points = 4
-    _compile(
-        lambda p, r, rpn, pm: ptree.tree_verify_points(
-            p, r, rpn, pm, interpret=False),
-        one_chip,
-        ((LANES, points, 3, L), np.int32), ((LANES, L), np.int32),
-        ((LANES, L), np.int32), ((LANES,), bool))
+def test_comb_digest_span_compiles(one_chip, no_cache):
+    """`jit_comb_digest` at the shape a one-chip provider dispatches
+    for every block: K = 4 key slots, q16, SPAN_LANES_PER_DEVICE."""
+    table = _chip_compile().programs(
+        tpumod.SPAN_LANES_PER_DEVICE, 4, one_chip)
+    fn, shapes = table["digest_q16"]()
+    lowered = fn.lower(*shapes)
+    assert "jit_comb_digest" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    # the resident 16-bit tables are the arguments' bulk: one for G
+    # and one a key slot for Q (~252 MB each)
+    table_bytes = comb.NWIN_G16 * comb.NENT_G16 * 3 * limb.L * 4
+    assert ma.argument_size_in_bytes >= 5 * table_bytes
+    assert ma.temp_size_in_bytes < 1 << 30

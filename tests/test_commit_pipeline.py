@@ -239,6 +239,7 @@ def make_seam_channel(root: str, name: str = CHANNEL):
     ch._lock = threading.Lock()
     ch._commit_listeners = []
     ch._commit_cond = threading.Condition()
+    ch._applied_height = ledger.height
     ch.commit_pipeline = None
     validator = SeamValidator(ledger)
     ch.validator = validator
@@ -304,6 +305,38 @@ def _run_sequential(tmp_path, stream, sub="seq"):
     ch.ledger.initialize_from_genesis(_parse(stream[0]))
     codes = [ch.process_block(_parse(raw)) for raw in stream[1:]]
     return ch, codes
+
+
+class TestAppliedHeight:
+    def test_wait_for_height_waits_for_state_not_the_block_store(
+            self, tmp_path, stream):
+        """Inside a commit the block store already holds the block
+        while history and state do not: a reader woken then would read
+        the state of the block before (the join-by-snapshot race)."""
+        ch = make_seam_channel(str(tmp_path / "applied"))
+        ch.ledger.initialize_from_genesis(_parse(stream[0]))
+        history_commit = ch.ledger.history_db.commit_block
+        seen = []
+
+        def inside_commit(block, codes, parsed):
+            seen.append((ch.ledger.height,
+                         ch.ledger.get_state(CC, "k1"),
+                         ch.wait_for_height(block.header.number + 1,
+                                            0.01)))
+            return history_commit(block, codes, parsed)
+        ch.ledger.history_db.commit_block = inside_commit
+        ch.process_block(_parse(stream[1]))
+        assert seen == [(2, None, False)]
+        assert ch.wait_for_height(2, 0)
+        assert ch.ledger.get_state(CC, "k1") == b"v"
+
+    def test_wait_for_height_past_and_future(self, tmp_path, stream):
+        ch, _ = _run_sequential(tmp_path, stream)
+        assert ch.height == len(stream)
+        assert all(ch.wait_for_height(h, 0)
+                   for h in range(len(stream) + 1))
+        assert not ch.wait_for_height(len(stream) + 1, 0.01)
+        assert ch.wait_for_block(len(stream) - 1, 0)
 
 
 class TestParity:

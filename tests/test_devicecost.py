@@ -4,19 +4,14 @@ Compile-seam counting (cold vs persistent-cache-hit, wheel-free via
 recorder stubs with injected clocks/cache dirs), the provider `_jit`
 seam + armed `tpu.compile` faults (error-status compile spans,
 compile_failures), busy-ratio math, memory-gauge rendering, the
-/healthz HBM-headroom sub-state, the perf ledger's parse/compare over
-checked-in copies of the real r01–r05 driver captures (including the
-crashed r04 and rc=124 r05 shapes) with a seeded regression that must
-be flagged, and the /debug/jax/trace busy/bounded hardening.
+/healthz HBM-headroom sub-state, and the /debug/jax/trace busy/bounded
+hardening.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -27,8 +22,6 @@ from fabric_tpu.common import metrics as metrics_mod
 from fabric_tpu.common.devicecost import (
     CompileRecorder, DeviceBusy,
 )
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.chaos
 
@@ -445,191 +438,6 @@ class TestHbmHealth:
         monkeypatch.setattr(devicecost, "device_memory",
                             lambda: _fake_rows(used=990, limit=1000))
         assert prov.health() == "device;hbm_low:d0:1%free"
-
-
-# ---------------------------------------------------------------------------
-# the perf ledger over a synthetic round history: every shape a round
-# file can take (clean, truncated final line, crashed, timed out)
-# ---------------------------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def rounds(tmp_path_factory):
-    """Five bench rounds + their multichip twins, written fresh: r01
-    and r02 parse cleanly, r03's tail is a TRUNCATED final line
-    (`parsed: null`, numbers salvaged), r04 crashed mid-bench with a
-    traceback, r05 timed out with nothing but a log line."""
-    d = tmp_path_factory.mktemp("perf_rounds")
-    log = "WARNING: some log line before the bench output\n"
-
-    def final(value, steady, **extra):
-        return json.dumps({
-            "metric": "block-validation sig-verify throughput",
-            "value": value, "unit": "sigs/s",
-            "detail": dict({"batch": 30720, "tpu_steady_s": steady},
-                           **extra)})
-
-    bench = {
-        1: (0, log + final(20337.5, 1.5105) + "\n", True),
-        2: (0, log + final(50605.0, 0.6071) + "\n", True),
-        # the driver keeps only the END of the output: this round's
-        # final line lost its head
-        3: (0, 'tch": 30720, "tpu_steady_s": 0.2206, '
-               '"provider_verify_batch_sigs_per_s": '
-               '29309.2, "pipeline": {"order_raft_s": 87.68, '
-               '"validate_s": 3.7281}, "devices": ["TPU v5 lite0"]}}\n',
-            False),
-        4: (1, log + '{"stage": "kernel_steady", "value": 41000.0}\n'
-               "Traceback (most recent call last):\n"
-               '  File "bench.py", line 1, in main\n'
-               "    q_flat = prov._qflat_cache[cache_key]\n"
-               "KeyError: (b'\\x0b', b'-')\n", False),
-        5: (124, log, False),
-    }
-    for n, (rc, tail, parse) in bench.items():
-        parsed = json.loads(tail.strip().splitlines()[-1]) \
-            if parse else None
-        (d / f"BENCH_r{n:02d}.json").write_text(json.dumps({
-            "n": n, "cmd": "python bench.py", "rc": rc, "tail": tail,
-            "parsed": parsed}))
-    for n, rc in {1: 1, 2: 0, 3: 0, 4: 0, 5: 124}.items():
-        (d / f"MULTICHIP_r{n:02d}.json").write_text(json.dumps({
-            "n_devices": 8, "rc": rc, "ok": rc == 0,
-            "skipped": False, "tail": json.dumps(log)}))
-    return str(d)
-
-
-def _ledger():
-    spec = importlib.util.spec_from_file_location(
-        "perf_ledger_under_test",
-        os.path.join(ROOT, "tools", "perf_ledger.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestPerfLedger:
-    def test_trajectory_over_rounds_nonempty(self, rounds):
-        pl = _ledger()
-        traj = pl.trajectory(rounds)
-        statuses = {r["round"]: r["status"] for r in traj["rounds"]}
-        assert statuses == {1: "ok", 2: "ok", 3: "salvaged",
-                            4: "crashed", 5: "timeout"}
-        assert {b["round"] for b in traj["broken_rounds"]} == {4, 5}
-        # the truncated r03 tail still yields its numbers
-        r3 = next(r for r in traj["rounds"] if r["round"] == 3)
-        assert r3["metrics"]["tpu_steady_s"] == 0.2206
-        assert r3["metrics"]["order_raft_s"] == 87.68
-        # pre-staged-bench alias lands on the canonical series
-        assert r3["metrics"]["provider_sigs_per_s"] == 29309.2
-        assert traj["metrics"]["value"]["best"] == 50605.0
-        assert traj["metrics"]["tpu_steady_s"]["best"] == 0.2206
-        # the crashed round carries its error, not silence
-        r4 = next(r for r in traj["rounds"] if r["round"] == 4)
-        assert "KeyError" in (r4.get("error") or "")
-
-    def test_multichip_rounds_attached(self, rounds):
-        pl = _ledger()
-        traj = pl.trajectory(rounds)
-        mc = {r["round"]: r.get("multichip") for r in traj["rounds"]}
-        assert mc[1]["ok"] is False and mc[1]["rc"] == 1
-        assert mc[2]["ok"] is True
-        assert mc[5]["rc"] == 124
-
-    def test_check_passes_at_history_best(self, rounds):
-        pl = _ledger()
-        traj = pl.trajectory(rounds)
-        cand = {"on_tpu": True,
-                "value": traj["metrics"]["value"]["best"],
-                "tpu_steady_s":
-                    traj["metrics"]["tpu_steady_s"]["best"]}
-        res = pl.compare(cand, traj)
-        assert res["ok"] is True
-        assert set(res["checked"]) == {"value", "tpu_steady_s"}
-
-    def test_seeded_regression_flagged(self, rounds):
-        pl = _ledger()
-        traj = pl.trajectory(rounds)
-        cand = {"on_tpu": True,
-                "value": traj["metrics"]["value"]["best"] * 0.5,
-                "tpu_steady_s": 9.9}
-        res = pl.compare(cand, traj)
-        assert res["ok"] is False
-        names = {r["metric"] for r in res["regressions"]}
-        assert names == {"value", "tpu_steady_s"}
-
-    def test_verdict_strings(self, rounds, tmp_path):
-        pl = _ledger()
-        assert pl.verdict({"on_tpu": True, "value": 1.0},
-                          str(tmp_path)) == "no_history"
-        assert pl.verdict({"on_tpu": False, "value": 1.0},
-                          rounds) == "skipped:cpu-rig"
-        good = pl.verdict({"on_tpu": True, "value": 60000.0},
-                          rounds)
-        assert good.startswith("ok(")
-        bad = pl.verdict({"on_tpu": True, "value": 10.0}, rounds)
-        assert bad == "regressed:value"
-
-    def test_crashed_round_salvage_never_gates(self, tmp_path):
-        """A crashed round's tail can carry MID-RUN stage-line
-        numbers (half the final aggregate); they must appear on the
-        round row but never become the series' best/last gating
-        reference."""
-        pl = _ledger()
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "n": 1, "rc": 0, "tail": "",
-            "parsed": {"value": 50000.0, "unit": "sigs/s"}}))
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-            "n": 2, "rc": 1, "parsed": None,
-            "tail": '{"stage": "kernel_steady", "value": 12000.0}\n'
-                    "Traceback (most recent call last):\n  boom\n"}))
-        traj = pl.trajectory(str(tmp_path))
-        r2 = next(r for r in traj["rounds"] if r["round"] == 2)
-        assert r2["status"] == "crashed"
-        assert r2["metrics"]["value"] == 12000.0   # represented...
-        s = traj["metrics"]["value"]
-        assert s["last"] == 50000.0                # ...never gating
-        assert s["best"] == 50000.0
-
-    def test_candidate_from_raw_stdout(self, tmp_path):
-        pl = _ledger()
-        f = tmp_path / "bench.out"
-        f.write_text(
-            "WARNING: some log line\n"
-            '{"stage": "core", "value": 1.0}\n'
-            '{"value": 42.0, "unit": "sigs/s", "on_tpu": true}\n')
-        cand = pl.load_candidate(str(f))
-        assert cand["value"] == 42.0 and "stage" not in cand
-
-    def test_cli_exit_codes(self, rounds, tmp_path):
-        env = dict(os.environ)
-        tool = os.path.join(ROOT, "tools", "perf_ledger.py")
-        out = subprocess.run(
-            [sys.executable, tool, "--dir", rounds],
-            capture_output=True, text=True, env=env, timeout=60)
-        assert out.returncode == 0, out.stderr
-        traj = json.loads(out.stdout)
-        assert len(traj["rounds"]) == 5 and traj["metrics"]
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps({"on_tpu": True, "value": 10.0}))
-        out = subprocess.run(
-            [sys.executable, tool, "check", "--candidate", str(bad),
-             "--dir", rounds],
-            capture_output=True, text=True, env=env, timeout=60)
-        assert out.returncode == 1, (out.stdout, out.stderr)
-        assert "REGRESSION value" in out.stderr
-        out = subprocess.run(
-            [sys.executable, tool, "check", "--candidate",
-             str(tmp_path / "missing.json"), "--dir", rounds],
-            capture_output=True, text=True, env=env, timeout=60)
-        assert out.returncode == 2
-
-    def test_empty_history_dir_is_usage_error(self, tmp_path):
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(ROOT, "tools", "perf_ledger.py"),
-             "--dir", str(tmp_path)],
-            capture_output=True, text=True, timeout=60)
-        assert out.returncode == 2
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,7 @@ blocks, tampered blocks, adversarial mutations, custom plugins,
 key-level validation parameters. Crypto is routed through the
 provider's sw path (MinBatch above the block size) so these tests pin
 the HOST pipeline; the device kernel equivalence is pinned by
-tests/test_tpu_seam.py and the comb/ptree differential suites.
+tests/test_tpu_seam.py and the comb differential suites.
 """
 
 import copy

@@ -152,7 +152,7 @@ class TestShardedComb:
         # the provider's mesh layout (shard_map, per-shard comb
         # programs) must agree bit for bit too
         from fabric_tpu.parallel import shardmap_comb_verify
-        smap = shardmap_comb_verify(mesh, q16=False, tree="xla")
+        smap = shardmap_comb_verify(mesh, q16=False)
         out = smap(jax.device_put(args[0], s_),
                    jax.device_put(args[1], s_), q_flat,
                    jax.device_put(
@@ -181,7 +181,7 @@ class TestShardedComb:
         g16 = jax.device_put(
             jnp.zeros((comb.NWIN_G16 * comb.NENT_G16, 3, limb.L),
                       jnp.int32), rep)
-        fn = shardmap_comb_verify(mesh8, q16=True, tree="xla")
+        fn = shardmap_comb_verify(mesh8, q16=True)
         out = fn(jax.device_put(np.zeros((B, 8), np.uint32), s_),
                  jax.device_put(np.zeros(B, np.int32), s_), q16, g16,
                  *(jax.device_put(np.zeros((B, limb.L), np.int32), s_)
@@ -235,7 +235,7 @@ class TestShardedComb:
         g16 = comb.g16_tables()
         rep = NamedSharding(mesh8, P())
         s_ = NamedSharding(mesh8, P(BATCH_AXIS))
-        fn = shardmap_comb_verify(mesh8, q16=True, tree="xla")
+        fn = shardmap_comb_verify(mesh8, q16=True)
         out = fn(jax.device_put(words, s_),
                  jax.device_put(np.zeros(B, np.int32), s_),
                  jax.device_put(q_flat, rep),

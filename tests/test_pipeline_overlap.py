@@ -20,9 +20,8 @@ import pytest
 
 from fabric_tpu.bccsp import ECDSAKeyGenOpts, VerifyItem, utils
 from fabric_tpu.bccsp.sw import SWProvider
-from fabric_tpu.bccsp.tpu import TPUProvider
+from fabric_tpu.bccsp.tpu import TPUProvider, aligned_span
 from fabric_tpu.common import faults
-from fabric_tpu.ops import ptree
 
 _SW = SWProvider()
 _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
@@ -78,10 +77,10 @@ def _corpus(n, all_invalid=False):
 
 class TestSpanMath:
     def test_aligned_span_granule(self):
-        assert ptree.aligned_span(8192) == 8192
-        assert ptree.aligned_span(100) == 128      # min one granule
-        assert ptree.aligned_span(300) == 256      # floored
-        assert ptree.aligned_span(1000, mesh_size=4) == 512
+        assert aligned_span(8192) == 8192
+        assert aligned_span(100) == 128      # min one granule
+        assert aligned_span(300) == 256      # floored
+        assert aligned_span(1000, mesh_size=4) == 512
 
     def test_provider_span_caps_at_chunk(self):
         tpu = TPUProvider(pipeline_chunk=8192, chunk=512)

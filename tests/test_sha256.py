@@ -4,6 +4,7 @@ import hashlib
 import random
 
 import numpy as np
+import pytest
 
 from fabric_tpu.ops import sha256
 
@@ -45,7 +46,63 @@ class TestSha256:
         assert (got[0] == _ref(m)).all()
 
     def test_too_long_raises(self):
-        import pytest
-
         with pytest.raises(ValueError):
             sha256.pack_messages([b"x" * 200], nb=2)
+
+
+def _pack_reference(msgs, nb):
+    """The pre-round-20 per-message pack, pinned verbatim: the
+    vectorized `pack_messages` must stay byte-identical to THIS."""
+    B = len(msgs)
+    out = np.zeros((B, nb, 16), dtype=np.uint32)
+    counts = np.zeros((B,), dtype=np.int32)
+    for i, m in enumerate(msgs):
+        if len(m) > sha256.max_message_len(nb):
+            raise ValueError(f"message {i} too long for {nb} blocks")
+        k = (len(m) + 9 + 63) // 64
+        counts[i] = k
+        padded = m + b"\x80" + b"\x00" * (k * 64 - len(m) - 9) \
+            + (8 * len(m)).to_bytes(8, "big")
+        words = np.frombuffer(padded, dtype=">u4").astype(np.uint32)
+        out[i, :k, :] = words.reshape(k, 16)
+    return out, counts
+
+
+class TestPackMessages:
+    def test_byte_identical_to_reference(self):
+        rng = np.random.default_rng(7)
+        for trial in range(9):
+            nb = [1, 2, 4][trial % 3]
+            B = int(rng.integers(1, 70))
+            msgs = [rng.integers(0, 256, size=int(n),
+                                 dtype=np.uint8).tobytes()
+                    for n in rng.integers(
+                        0, sha256.max_message_len(nb) + 1, size=B)]
+            if B > 2:
+                msgs[0] = b""                             # SHA("")
+                msgs[1] = bytes(sha256.max_message_len(nb))  # max fit
+            got = sha256.pack_messages(msgs, nb)
+            want = _pack_reference(msgs, nb)
+            assert (got[0] == want[0]).all()
+            assert (got[1] == want[1]).all()
+            assert got[0].dtype == np.uint32
+            assert got[0].flags["C_CONTIGUOUS"]
+
+    def test_empty_batch(self):
+        blocks, counts = sha256.pack_messages([], 2)
+        assert blocks.shape == (0, 2, 16) and counts.shape == (0,)
+
+    def test_too_long_error_parity(self):
+        msgs = [b"a", b"x" * 100]
+        with pytest.raises(ValueError) as got:
+            sha256.pack_messages(msgs, 1)
+        with pytest.raises(ValueError) as want:
+            _pack_reference(msgs, 1)
+        assert str(got.value) == str(want.value)
+
+    def test_digests_unchanged(self):
+        msgs = [b"", b"abc", b"m" * 200, b"x" * sha256.max_message_len(2)]
+        got = sha256.sha256_host(msgs, nb=4)
+        for i, m in enumerate(msgs):
+            want = np.frombuffer(hashlib.sha256(m).digest(), dtype=">u4")
+            assert (got[i] == want).all()

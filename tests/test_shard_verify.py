@@ -39,7 +39,7 @@ from fabric_tpu.parallel import batch_mesh
 _SW = SWProvider()
 _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
 
-# aligned_span granule for an 8-way mesh (ops/ptree.py LANE_ALIGN=128)
+# aligned_span granule for an 8-way mesh (bccsp/tpu.py LANE_ALIGN=128)
 SPAN8 = 1024
 
 
@@ -72,10 +72,10 @@ def _stubbed_provider(mesh=None, **kw):
     return tpu, calls
 
 
-def _corpus(n, all_invalid=False):
+def _corpus(n, all_invalid=False, keys=_KEYS):
     items, expected = [], []
     for i in range(n):
-        k = _KEYS[i % 2]
+        k = keys[i % len(keys)]
         m = f"shard {i}".encode()
         sig = _SW.sign(k, hashlib.sha256(m).digest())
         if all_invalid or i % 3 == 2:
@@ -184,6 +184,62 @@ class TestShardedParity:
         expected[5] = False
         assert sharded.verify_batch(items) == expected
         assert sharded.stats["nonp256_sw_lanes"] == 1
+
+
+class TestProgramInventory:
+    def test_program_inventory_on_the_mesh(self, mesh8, monkeypatch):
+        """The sharded twin of tests/test_bccsp.py's inventory: the
+        provider's OWN sharded builders (shard_map specs, the ladder's
+        NamedShardings, the compile seam's names) around stand-in
+        math that accepts a lane where the premask does AND the digest
+        in that lane is one the sw provider accepted — so lanes staged
+        to the wrong device read wrong. After prewarm() and one batch
+        on each side of MaxKeys the seam has named nothing but the
+        programs a P-256 batch can need."""
+        import jax.numpy as jnp
+
+        from fabric_tpu.ops import comb, p256, sha256
+        faults.clear()
+        keys17 = _KEYS + [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True))
+                          for _ in range(15)]
+        few, want_few = _corpus(64)
+        many, want_many = _corpus(40, keys=keys17)
+        accepted = jnp.asarray(sorted(
+            int.from_bytes(hashlib.sha256(it.message).digest()[:4], "big")
+            for it, ok in zip(few + many, want_few + want_many) if ok),
+            dtype=jnp.uint32)
+
+        def verdict(words, premask):
+            return premask & jnp.isin(words[:, 0], accepted)
+        monkeypatch.setattr(
+            comb, "comb_verify_with_tables",
+            lambda words, key_idx, q_flat, r, rpn, w, premask, g16=None,
+            q16=False: verdict(words, premask))
+        monkeypatch.setattr(
+            p256, "verify_core",
+            lambda words, qx, qy, r, rpn, w, premask:
+            verdict(words, premask))
+        monkeypatch.setattr(
+            sha256, "sha256_blocks",
+            lambda blocks, nblocks: jnp.zeros((blocks.shape[0], 8),
+                                              jnp.uint32))
+
+        prov = TPUProvider(min_batch=16, use_g16=False, mesh=mesh8)
+        prov.prewarm(bounded=True)
+        assert prov.verify_batch(few) == want_few == \
+            _SW.verify_batch(few)
+        assert prov.verify_batch(many) == want_many == \
+            _SW.verify_batch(many)
+        assert any(want_many) and not all(want_many)
+        st = prov.stats
+        assert (st["comb_batches"], st["ladder_batches"]) == (1, 1)
+        assert st["shard_dispatches"] >= 1      # the comb side books them
+        assert st["sw_fallbacks"] == st["degraded_batches"] == 0
+        assert st["host_hashed_lanes"] == sum(want_few) + sum(want_many)
+        kinds = {e["kind"] for e in prov.device_cost.events}
+        assert {"qtab", "comb_digest", "ladder"} <= kinds
+        assert kinds <= {"qtab", "qtab16", "comb_digest", "comb",
+                         "ladder"}
 
 
 class TestDevicesKnob:

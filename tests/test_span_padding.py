@@ -107,15 +107,9 @@ def _prepared_args(items):
             lambda i: sigs[i])
 
 
-def _provider(**kw):
-    """A provider as a TPU backend resolves it — the floor at the
-    span — whose digest program is a cheap jitted stand-in built
-    through the provider's own compile seam."""
-    faults.clear()
-    kw.setdefault("min_batch", MIN_BATCH)
-    kw.setdefault("use_g16", False)
-    kw.setdefault("bucket_floor", SPAN)
-    prov = TPUProvider(**kw)
+def _stand_in(prov):
+    """Swap `prov`'s digest program for a cheap jitted stand-in built
+    through the provider's own compile seam; the lane counts it ran."""
     shapes = []
     accepted = _unique()[2]
     programs = {}
@@ -138,7 +132,18 @@ def _provider(**kw):
 
     prov._qtab_fn = lambda K: lambda qx, qy: np.zeros((K,), np.int32)
     prov._comb_pipeline_digest = fake_pipeline_digest
-    return prov, shapes
+    return shapes
+
+
+def _provider(**kw):
+    """A provider as a TPU backend resolves it — the floor at the
+    span — with the stand-in digest program."""
+    faults.clear()
+    kw.setdefault("min_batch", MIN_BATCH)
+    kw.setdefault("use_g16", False)
+    kw.setdefault("bucket_floor", SPAN)
+    prov = TPUProvider(**kw)
+    return prov, _stand_in(prov)
 
 
 def _run(prov, path, items):
@@ -263,3 +268,34 @@ def test_factory_leaves_the_span_unset_unless_configured():
     prov = factory.new_bccsp(factory.FactoryOpts.from_config(
         {"Default": "TPU", "TPU": {"Devices": 1, "PipelineChunk": 8192}}))
     assert prov._pipeline_span() == 8192
+
+
+@pytest.mark.parametrize("switch", [
+    "FTPU_FUSED", "FTPU_FUSED_RESIDENT", "FTPU_PALLAS",
+    "FTPU_PALLAS_INTERPRET", "FusedVerify"])
+def test_removed_switch_is_inert(switch, monkeypatch):
+    """Nothing a user sets selects a kernel (ISSUE 32): with a retired
+    switch set, a message-lane batch is still hashed on the host and
+    served by `comb_digest`, and by nothing else."""
+    faults.clear()
+    cfg = {"MinBatch": MIN_BATCH, "UseG16": False, "BucketFloor": SPAN,
+           "Devices": 1}
+    if switch.startswith("FTPU_"):
+        monkeypatch.setenv(switch, "1")
+    else:
+        cfg[switch] = True
+    opts = factory.FactoryOpts.from_config({"Default": "TPU", "TPU": cfg})
+    assert not hasattr(opts.tpu, "fused_verify")
+    prov = factory.new_bccsp(opts)
+    shapes = _stand_in(prov)
+    items, want = _batch(1500)
+    assert all(it.digest is None for it in items)
+    assert prov.verify_batch(items) == want
+    assert shapes == [SPAN]
+    assert prov.stats["comb_batches"] == 1
+    assert prov.stats["host_hashed_lanes"] == sum(
+        host_prep_scalars(it.key, it.signature) is not None
+        for it in items)
+    assert prov.stats["sw_fallbacks"] == prov.stats["ladder_batches"] == 0
+    assert not [k for k in prov.stats if "fused" in k]
+    assert {e["kind"] for e in prov.device_cost.events} == {"comb_digest"}

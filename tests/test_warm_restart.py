@@ -5,8 +5,7 @@ Round-3 verdict: the warm-keys machinery existed but was unreachable
 `_prewarm_tables()`). These tests pin the WIRING end to end — config →
 factory → provider, build → persist, fresh provider → prewarm →
 cache hit — with the table builders stubbed (the real 16-bit comb
-build is a multi-minute device job measured by bench.py, not a unit
-concern).
+build is a multi-minute device job, not a unit concern).
 """
 
 import json

@@ -12,7 +12,7 @@
 # Spec grammar: point=mode[:count][:delay_s][:arg], mode in
 # {error, delay}; the 4th field targets a check() argument (the
 # per-device points pass the full-mesh chip index).
-# Usage: chaos_check.sh [all|bccsp|raft|deliver|onboarding|commit|shard|order|schemes|overload|adaptive|mesh-health|tracing|net|devicecost|e2e-trace|fused|pairing|static]
+# Usage: chaos_check.sh [all|bccsp|raft|deliver|onboarding|commit|shard|order|schemes|overload|adaptive|mesh-health|tracing|net|devicecost|e2e-trace|pairing|static]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -228,22 +228,6 @@ e2e_trace() {
         -k "Carrier or Chaos or Cluster or Resume"
 }
 
-fused() {
-    # the round-20 fused Pallas tier under fire: an armed
-    # tpu.fused_verify fault must demote the batch to the host-hash
-    # comb-digest path with BIT-IDENTICAL verdicts (a fused-tier
-    # defect is a tier downgrade, never a device outage — the breaker
-    # must not trip), then re-enter the device path once the arming
-    # exhausts. Tests that pin fused/fallback counters clear the
-    # ambient arming and arm their own; the kernel-level parity tests
-    # prove the arming is inert below the dispatch seam.
-    run "tpu.fused_verify=error:2" tests/test_fused_verify.py
-    run "tpu.fused_verify=delay:1:0.05;tpu.compile=error:1" \
-        tests/test_fused_verify.py -k "Faults or Knob or Sharded"
-    run "tpu.fused_verify=error:2;tpu.dispatch=error:1" \
-        tests/test_chaos.py -k "Degradation or FaultRegistry"
-}
-
 pairing() {
     # the round-21 BLS12-381 pairing engine under fire: armed
     # tpu.bls_aggregate faults over the device-kernel suite must
@@ -278,12 +262,11 @@ case "${1:-all}" in
     net) net ;;
     devicecost) devicecost ;;
     e2e-trace) e2e_trace ;;
-    fused) fused ;;
     pairing) pairing ;;
     static) static ;;
     all) bccsp; raft; deliver; onboarding; commit; shard; order;
          schemes; overload; adaptive; mesh_health; tracing; net; devicecost;
-         e2e_trace; fused; pairing; static ;;
+         e2e_trace; pairing; static ;;
     *) echo "unknown subset: $1" >&2; exit 2 ;;
 esac
 

@@ -9,11 +9,11 @@ seconds does it take. Prints one JSON line per program with the
 compile seconds and `memory_analysis()`.
 
     python tools/chip_compile.py --list
-    python tools/chip_compile.py tree_pallas_q16 sha_dma fused_q16
-    python tools/chip_compile.py --lanes 512 tree_pallas_q16
+    python tools/chip_compile.py digest_q16 qtab16
+    python tools/chip_compile.py --lanes 2048 digest_q16
 
 Run the programs one after another (one process describes the topology
-at a time — libtpu's lock), never beside tests/test_chip_compile.py.
+at a time — libtpu's lock).
 """
 
 from __future__ import annotations
@@ -34,27 +34,24 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _provider(tree: str, fused: bool):
+def _provider():
     """A TPUProvider steered onto its TPU branches: on a described
     topology `jax.devices()` still answers CPU, so the script (not the
     program) pins what `_on_tpu()` would resolve to on the chip."""
     from fabric_tpu.bccsp import tpu as tpumod
-    from fabric_tpu.common import jaxenv
 
-    jaxenv.pallas_interpret = lambda: False
-    prov = tpumod.TPUProvider(use_g16=True, fused_verify=fused)
+    prov = tpumod.TPUProvider(use_g16=True)
     prov._on_tpu = lambda: True
-    prov._tree_impl = lambda: tree
     return prov
 
 
-def programs(lanes: int, K: int, nb: int, dev):
+def programs(lanes: int, K: int, dev):
     """name -> (jitted fn, argument shapes). Shapes mirror what
-    `TPUProvider._dispatch_{comb_digest,fused_verify}` stage for one
-    `Chunk` of `lanes` signatures over a K-slot key set."""
+    `TPUProvider._dispatch_comb_digest` stages for one span of `lanes`
+    signatures over a K-slot key set."""
     import numpy as np
 
-    from fabric_tpu.ops import comb, fused_verify as fv, limb, ptree
+    from fabric_tpu.ops import comb, limb
 
     L = limb.L
     i32, u8, u32 = np.int32, np.uint8, np.uint32
@@ -64,41 +61,13 @@ def programs(lanes: int, K: int, nb: int, dev):
     g16 = s((ent16, 3, L), i32)
     g0 = s((0, 3, L), i32)
 
-    def tree(points):
-        import jax
-        return (jax.jit(lambda p, r, rpn, pm: ptree.tree_verify_points(
-                    p, r, rpn, pm, interpret=False)),
-                (s((lanes, points, 3, L), i32), s((lanes, L), i32),
-                 s((lanes, L), i32), s((lanes,), bool)))
-
-    def sha(dma):
-        import jax
-        return (jax.jit(lambda b, n, d, h, r, w: fv.sha_windows(
-                    b, n, d, h, r, w, wbits_g=16, wbits_q=16,
-                    interpret=False, dma=dma)),
-                (s((lanes, nb, 16), u32), s((lanes,), i32),
-                 s((lanes, 8), u32), s((lanes,), bool),
-                 s((lanes, L), i32), s((lanes, L), i32)))
-
-    def digest(tree_impl, q16):
-        prov = _provider(tree_impl, fused=False)
-        fn = prov._comb_pipeline_digest(K, q16)
+    def digest(q16):
+        fn = _provider()._comb_pipeline_digest(K, q16)
         ent = ent16 if q16 else ent8
         return (fn, (s((lanes,), i32), s((ent * K, 3, L), i32),
                      g16 if q16 else g0, s((lanes, 32), u8),
                      s((lanes, 32), u8), s((lanes, 32), u8),
                      s((lanes,), bool), s((lanes, 8), u32)))
-
-    def fused(tree_impl, q16):
-        prov = _provider(tree_impl, fused=True)
-        fn = prov._fused_pipeline(K, q16)
-        ent = ent16 if q16 else ent8
-        return (fn, (s((lanes, nb, 16), u32), s((lanes,), i32),
-                     s((lanes,), i32), s((ent * K, 3, L), i32),
-                     g16 if q16 else g0, s((lanes, 32), u8),
-                     s((lanes, 32), u8), s((lanes, 32), u8),
-                     s((lanes,), bool), s((lanes, 8), u32),
-                     s((lanes,), bool)))
 
     def qtab():
         import jax
@@ -117,16 +86,8 @@ def programs(lanes: int, K: int, nb: int, dev):
                 (s((ent8 * K, 3, L), i32), K))
 
     return {
-        "tree_pallas_q16": lambda: tree(32),
-        "tree_pallas_q8": lambda: tree(64),
-        "sha_dma": lambda: sha(True),
-        "sha_plain": lambda: sha(False),
-        "digest_q16_pallas": lambda: digest("pallas", True),
-        "digest_q16_xla": lambda: digest("xla", True),
-        "digest_q8_xla": lambda: digest("xla", False),
-        "fused_q16_pallas": lambda: fused("pallas", True),
-        "fused_q16_xla": lambda: fused("xla", True),
-        "fused_q8_xla": lambda: fused("xla", False),
+        "digest_q16": lambda: digest(True),
+        "digest_q8": lambda: digest(False),
         "g16": g16tab,
         "qtab8": qtab,
         "qtab16": qtab16,
@@ -140,8 +101,6 @@ def main() -> int:
     ap.add_argument("--lanes", type=int, default=32768)
     ap.add_argument("--keys", type=int, default=4,
                     help="key-slot bucket K (3 keys -> 4)")
-    ap.add_argument("--nb", type=int, default=4,
-                    help="SHA blocks per message lane (256 B -> 8)")
     ap.add_argument("--topology", default="v5e:2x2")
     args = ap.parse_args()
 
@@ -155,14 +114,14 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
     dev = SingleDeviceSharding(topo.devices[0])
-    table = programs(args.lanes, args.keys, args.nb, dev)
+    table = programs(args.lanes, args.keys, dev)
     if args.list:
         print("\n".join(table))
         return 0
     rc = 0
     for name in args.names or list(table):
         row = {"program": name, "lanes": args.lanes, "K": args.keys,
-               "nb": args.nb, "device_kind": topo.devices[0].device_kind}
+               "device_kind": topo.devices[0].device_kind}
         try:
             fn, shapes = table[name]()
             t0 = time.perf_counter()

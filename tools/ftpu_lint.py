@@ -85,8 +85,6 @@ REQUIRED_HOT_PATHS = {
     "fabric_tpu/bccsp/tpu.py": (
         "_dispatch_arrays", "_verify_batch_pipelined",
         "_dispatch_comb_digest", "_dispatch_comb", "_shard_put",
-        # round-20 fused tier: the fused device-SHA dispatch span
-        "_dispatch_fused_verify",
         # round-11 scheme router: the Ed25519 device dispatch span
         "_dispatch_ed25519",
         # round-21 pairing engine: the batched BLS12-381

@@ -25,10 +25,6 @@
 #                                  across a device dispatch /
 #                                  injected-fault stall FAILS the run
 #                                  (tests/conftest.py sessionfinish)
-#   5. tools/perf_check.sh       — round-16 perf ledger: the
-#                                  BENCH_r*/MULTICHIP_r* history must
-#                                  parse into a trajectory and a
-#                                  seeded regression must be flagged
 #
 # Standalone: tools/static_check.sh
 # From the chaos gate: tools/chaos_check.sh static
@@ -38,16 +34,16 @@ cd "$(dirname "$0")/.."
 PYTEST=(env JAX_PLATFORMS=cpu python -m pytest -q -m 'not slow'
         -p no:cacheprovider -p no:randomly)
 
-echo "== static_check 1/5: ftpu_lint"
+echo "== static_check 1/4: ftpu_lint"
 python tools/ftpu_lint.py
 
-echo "== static_check 2/5: ftpu_check (whole-program)"
+echo "== static_check 2/4: ftpu_check (whole-program)"
 python tools/ftpu_check.py
 
-echo "== static_check 3/5: gendoc --check"
+echo "== static_check 3/4: gendoc --check"
 python -m fabric_tpu.common.gendoc --check
 
-echo "== static_check 4/5: lock-order sanitizer (threaded subset)"
+echo "== static_check 4/4: lock-order sanitizer (threaded subset)"
 FTPU_LOCKCHECK=1 "${PYTEST[@]}" \
     tests/test_lockcheck.py tests/test_ftpu_lint.py \
     tests/test_chaos.py tests/test_commit_pipeline.py \
@@ -55,10 +51,6 @@ FTPU_LOCKCHECK=1 "${PYTEST[@]}" \
     tests/test_overload.py tests/test_device_health.py \
     tests/test_tracing.py tests/test_net_chaos.py \
     tests/test_devicecost.py tests/test_cluster_trace.py \
-    tests/test_adaptive.py tests/test_fused_verify.py \
-    tests/test_bls12_381_device.py
-
-echo "== static_check 5/5: perf ledger gate"
-./tools/perf_check.sh
+    tests/test_adaptive.py tests/test_bls12_381_device.py
 
 echo "static_check: all gates green"
