@@ -163,13 +163,24 @@ def seam_child(args) -> dict:
         jax.block_until_ready(comb.g16_tables())
     out["g16_build_s"] = round(time.perf_counter() - t0, 3)
 
-    # set-up, part 2: the cold call (Q tables + pipeline compiles)
+    # set-up, part 2: what a node does before its first block —
+    # prewarm's programs through the AOT seam: loaded from the store
+    # of compiled executables on a second start on this machine,
+    # lowered and compiled (or loaded from the persistent cache) and
+    # written there on a first
+    t0 = time.perf_counter()
+    prov.prewarm(wait_restore=True)
+    out["prewarm_s"] = round(time.perf_counter() - t0, 3)
+
+    # set-up, part 3: the cold call (Q tables; a program prewarm did
+    # not name would compile here)
     t0 = time.perf_counter()
     got = prov.verify_batch(items)
     out["cold_call_s"] = round(time.perf_counter() - t0, 3)
     check(got == want, "cold verdicts differ from sw")
     out["compiles"] = [
-        {k: ev[k] for k in ("kind", "seconds", "cache_hit")}
+        {k: ev[k] for k in ("kind", "seconds", "cache_hit", "source",
+                            "aot")}
         for ev in prov._devicecost.events]
     cold = dict(prov.stats)
 
@@ -195,6 +206,8 @@ def seam_child(args) -> dict:
         stats={k: st[k] for k in FALLBACK_COUNTERS + DISPATCH_COUNTERS
                + ("q16_resident_sets", "q16_builds", "compile_total",
                   "compile_cold_total", "compile_cache_hits",
+                  "executable_store_hits", "executable_store_misses",
+                  "executable_store_errors",
                   "compile_seconds", "shard_devices",
                   "shard_dispatches", "host_hashed_lanes")},
         shard_lanes=list(prov.shard_stats.get("lanes") or []),
@@ -207,6 +220,12 @@ def seam_child(args) -> dict:
     check(out["health"] == "device", f"health() = {out['health']!r}")
     check(out["warm_compiles"] == 0,
           f"{out['warm_compiles']} compile(s) during the warm calls")
+    check(st["executable_store_errors"] == 0,
+          f"{st['executable_store_errors']} executable store entries "
+          "not served or not written")
+    check(args.rehearse or st["executable_store_hits"]
+          + st["executable_store_misses"] > 0,
+          "prewarm asked the executable store for nothing")
     if out["path"]["q16"]:
         check(st["q16_resident_sets"] >= 1,
               "the warm calls rode the 8-bit Q tables (q16 not resident)")
@@ -431,18 +450,19 @@ def node_phase(args, root: str) -> dict:
                      "the number of full blocks", 1.0)
         out["tpu_peer_metrics"] = {
             k: v for k, v in m.items() if k.startswith((
-                "bccsp_device_info", "bccsp_compile", "bccsp_q16_res",
+                "bccsp_device_info", "bccsp_compile",
+                "bccsp_executable_store", "bccsp_q16_res",
                 "bccsp_prewarm")) or k in [
                 f"bccsp_{c}" for c in FALLBACK_COUNTERS
                 + DISPATCH_COUNTERS]}
         for c in FALLBACK_COUNTERS:
             check(m.get(f"bccsp_{c}") == 0, f"TPU peer bccsp_{c} = "
                   f"{m.get(f'bccsp_{c}')}")
-        # a program prewarm compiled ahead of time is LOADED from the
-        # persistent cache at its first dispatch (a cache hit, well
-        # under a second); a COLD compile after set-up is an unplanned
-        # shape. On a CPU rehearsal the tight power-of-two buckets make
-        # one (see TPUProvider._floor), so only a real run checks it.
+        # a program prewarm made ready ahead of time is registered for
+        # its shape, so its first dispatch compiles and loads nothing;
+        # a COLD compile after set-up is an unplanned shape. On a CPU
+        # rehearsal the tight power-of-two buckets make one (see
+        # TPUProvider._floor), so only a real run checks it.
         out["cold_compiles_after_setup"] = int(
             m["bccsp_compile_cold_total"]
             - warm["bccsp_compile_cold_total"])
@@ -452,6 +472,9 @@ def node_phase(args, root: str) -> dict:
         check(args.rehearse or out["cold_compiles_after_setup"] == 0,
               f"{out['cold_compiles_after_setup']} cold compile(s) on "
               "the serving path after set-up")
+        check(m.get("bccsp_executable_store_errors") == 0,
+              "TPU peer bccsp_executable_store_errors = "
+              f"{m.get('bccsp_executable_store_errors')}")
         health = json.loads(_get(
             f"http://127.0.0.1:{ops[tpu_peer]}/healthz"))["components"]
         check(health.get("bccsp") == "device", f"healthz {health}")

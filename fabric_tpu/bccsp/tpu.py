@@ -295,6 +295,13 @@ class TPUProvider(api.BCCSP):
                       "compile_total": 0, "compile_cache_hits": 0,
                       "compile_cold_total": 0, "compile_failures": 0,
                       "compile_seconds": 0.0,
+                      # the store of compiled executables behind the
+                      # AOT seam (common/execstore.py): prewarm's
+                      # requests served without a trace, those compiled
+                      # and written, and entries or writes that failed
+                      "executable_store_hits": 0,
+                      "executable_store_misses": 0,
+                      "executable_store_errors": 0,
                       "breaker_state": 0, "breaker_trips": 0,
                       "breaker_probes": 0,
                       "breaker_deadline_timeouts": 0,
@@ -502,7 +509,8 @@ class TPUProvider(api.BCCSP):
         compile_s / mem_peak_bytes stage fields."""
         return self._devicecost
 
-    def _jit(self, kind: str, fn, **jit_kw):
+    def _jit(self, kind: str, fn, params: Optional[dict] = None,
+             **jit_kw):
         """The ONE compiled-path build seam: every jitted program the
         provider serves (comb/digest/ladder/table builders, ed25519,
         pairing, g2msm) is built here, so the `tpu.compile` fault
@@ -516,8 +524,18 @@ class TPUProvider(api.BCCSP):
         shows `jit_comb_digest(<id>)`, `jit_qtab16(<id>)`, ... and not
         one `jit_fused` for every program whose inner function happens
         to be called `fused`. (The name is part of the persistent
-        compile cache's key.)"""
+        compile cache's key.)
+
+        `params` names every static parameter of the builder that made
+        `fn` — what its closure holds beside the argument shapes. A
+        program `prewarm` requests ahead of time is kept, compiled, in
+        the executable store under them (`InstrumentedJit.aot`); the
+        store cannot see a closure, so a parameter left out here is a
+        wrong program served. The serving mesh is added here."""
         t0 = self._devicecost._clock()
+        mesh = self._mesh
+        params = dict(params or {}, mesh=None if mesh is None else (
+            mesh.axis_names, [d.id for d in mesh.devices.flat]))
 
         @functools.wraps(fn)
         def program(*args, **kwargs):
@@ -532,7 +550,9 @@ class TPUProvider(api.BCCSP):
             self._devicecost.note(kind, self._devicecost._clock() - t0,
                                   cache_hit=False, error=e)
             raise
-        return self._devicecost.wrap(kind, jitted)
+        return self._devicecost.wrap(
+            kind, jitted, params=params,
+            static_argnums=jit_kw.get("static_argnums", ()))
 
     def _sync_breaker_stats(self) -> None:
         b = self._breaker
@@ -2856,8 +2876,8 @@ class TPUProvider(api.BCCSP):
         with self._jit_lock:
             if K not in self._qtab_fns:
                 from fabric_tpu.ops import comb
-                self._qtab_fns[K] = self._jit("qtab",
-                                              comb.build_q_tables)
+                self._qtab_fns[K] = self._jit(
+                    "qtab", comb.build_q_tables, {"K": K})
             return self._qtab_fns[K]
 
     def _q16_fn(self, K: int):
@@ -2866,7 +2886,8 @@ class TPUProvider(api.BCCSP):
             if key not in self._qtab_fns:
                 from fabric_tpu.ops import comb
                 self._qtab_fns[key] = self._jit(
-                    "qtab16", comb.build_q16_tables, static_argnums=1)
+                    "qtab16", comb.build_q16_tables, {"K": K},
+                    static_argnums=1)
             return self._qtab_fns[key]
 
     def _comb_pipeline(self, K: int, q16: bool = False):
@@ -2882,6 +2903,7 @@ class TPUProvider(api.BCCSP):
             # serve the adaptive-overflow and restore-pending windows,
             # and must not block on (or embed) the ~252 MB g16 build
             use_g16 = self._g16_enabled() and q16
+            params = {"K": K, "q16": q16, "g16": use_g16}
 
             def fused(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
                       premask, digests, has_digest):
@@ -2903,9 +2925,9 @@ class TPUProvider(api.BCCSP):
                     "comb", jaxenv.shard_map(
                         fused, mesh=self._mesh,
                         in_specs=(s, s, s, rep, rep, s, s, s, s, s, s),
-                        out_specs=s))
+                        out_specs=s), params)
             else:
-                self._comb_fns[key] = self._jit("comb", fused)
+                self._comb_fns[key] = self._jit("comb", fused, params)
         return self._comb_fns[key]
 
     def _comb_pipeline_digest(self, K: int, q16: bool):
@@ -2927,6 +2949,7 @@ class TPUProvider(api.BCCSP):
                 # windows, and must not block on (or embed) the
                 # ~252 MB g16 build
                 use_g16 = self._g16_enabled() and q16
+                params = {"K": K, "q16": q16, "g16": use_g16}
 
                 def fused(key_idx, q_flat, g16, r8, rpn8, w8, premask,
                           digests):
@@ -2945,10 +2968,10 @@ class TPUProvider(api.BCCSP):
                         "comb_digest", jaxenv.shard_map(
                             fused, mesh=self._mesh,
                             in_specs=(s, rep, rep, s, s, s, s, s),
-                            out_specs=s))
+                            out_specs=s), params)
                 else:
                     self._comb_fns[key] = self._jit("comb_digest",
-                                                    fused)
+                                                    fused, params)
             return self._comb_fns[key]
 
     def _pipeline(self):
@@ -2975,9 +2998,16 @@ class TPUProvider(api.BCCSP):
     def prewarm(self, buckets=None, key_counts=(4,), msg_nbs=None,
                 wait_restore: bool = False,
                 bounded: bool = False) -> None:
-        """AOT-compile what this provider will dispatch (and build the
+        """Make ready what this provider will dispatch (and build the
         16-bit G table) BEFORE the node joins channels, so a cold peer
-        does not stall its first blocks on device compilation.
+        does not stall its first blocks on device compilation. Each
+        program goes through the AOT seam (`InstrumentedJit.aot`): a
+        restarted peer LOADS it from the store of compiled executables
+        beside the persistent compile cache and traces nothing; the
+        first process after a change of code, JAX or device lowers and
+        compiles (or loads from the persistent cache) and writes the
+        store. Either way the executable is registered for its shape,
+        and the first block's dispatch calls it directly.
         Persisted Q tables restore in a BACKGROUND thread that
         outlives this call (wait_restore=True joins it — tests): live
         batches ride the 8-bit path until each restore lands, so the
@@ -3005,8 +3035,19 @@ class TPUProvider(api.BCCSP):
             # host-hash mode only ever ships nb=1 digest lanes; device-
             # hash mode also needs the typical proposal-payload shape
             msg_nbs = (1,) if self._hash_on_host else (1, 8)
-        sd = jax.ShapeDtypeStruct
         i32, u8 = _np.int32, _np.uint8
+        lane = rep = None
+        if self._mesh is not None:
+            # a compiled executable takes only what it was compiled
+            # for: say where `_shard_put` and `_resolve_tables` put
+            # the lanes and the tables of a sharded dispatch
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            lane = NamedSharding(self._mesh, P("batch"))
+            rep = NamedSharding(self._mesh, P())
+
+        def sd(shape, dtype, sharding=None):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
         try:
             q16 = self._g16_enabled()
             if q16:
@@ -3032,32 +3073,38 @@ class TPUProvider(api.BCCSP):
                             else min(self._bucket(b), self._chunk)
                             for b in buckets})
             for K in sorted(set(key_counts) | persisted):
-                g16_sd = (sd((comb.NWIN_G16 * comb.NENT_G16, 3, 20), i32)
-                          if q16 else sd((0, 3, 20), i32))
-                q8_sd = sd((comb.NWIN * comb.NENT * K, 3, 20), i32)
-                q_sd = (sd((comb.NWIN_G16 * comb.NENT_G16 * K, 3, 20),
-                           i32) if q16 else q8_sd)
+                q8_rows = comb.NWIN * comb.NENT * K
+                q16_rows = comb.NWIN_G16 * comb.NENT_G16 * K
+                g0_sd = sd((0, 3, 20), i32, rep)
+                g16_sd = (sd((comb.NWIN_G16 * comb.NENT_G16, 3, 20), i32,
+                             rep) if q16 else g0_sd)
+                q_sd = sd((q16_rows if q16 else q8_rows, 3, 20), i32, rep)
                 if lanes:
-                    self._qtab_fn(K).lower(
-                        sd((K, 20), i32), sd((K, 20), i32)).compile()
+                    # the table builders run on one device, mesh or not
+                    self._qtab_fn(K).aot(
+                        sd((K, 20), i32), sd((K, 20), i32))
                     if q16:
-                        self._q16_fn(K).lower(q8_sd, K).compile()
+                        self._q16_fn(K).aot(
+                            sd((q8_rows, 3, 20), i32), K)
                     logger.info("prewarmed table builders K=%d q16=%s",
                                 K, q16)
                 for n in lanes:
                     def dshapes(q, g):
-                        return (sd((n,), i32), q, g, sd((n, 32), u8),
-                                sd((n, 32), u8), sd((n, 32), u8),
-                                sd((n,), bool), sd((n, 8), _np.uint32))
+                        return (sd((n,), i32, lane), q, g,
+                                sd((n, 32), u8, lane),
+                                sd((n, 32), u8, lane),
+                                sd((n, 32), u8, lane),
+                                sd((n,), bool, lane),
+                                sd((n, 8), _np.uint32, lane))
 
-                    self._comb_pipeline_digest(K, q16).lower(
-                        *dshapes(q_sd, g16_sd)).compile()
+                    self._comb_pipeline_digest(K, q16).aot(
+                        *dshapes(q_sd, g16_sd))
                     logger.info("prewarmed digest comb pipeline K=%d "
                                 "lanes=%d q16=%s", K, n, q16)
                     if q16 and persisted and not bounded:
-                        self._comb_pipeline_digest(K, False).lower(
-                            *dshapes(q8_sd, sd((0, 3, 20), i32))
-                        ).compile()
+                        self._comb_pipeline_digest(K, False).aot(
+                            *dshapes(sd((q8_rows, 3, 20), i32, rep),
+                                     g0_sd))
                         logger.info("prewarmed digest comb pipeline "
                                     "K=%d lanes=%d q16=False "
                                     "(restore-window path)", K, n)
@@ -3065,13 +3112,14 @@ class TPUProvider(api.BCCSP):
                         continue      # SHA+comb pipeline not used
                     fn = self._comb_pipeline(K, q16)
                     for nb in msg_nbs:
-                        fn.lower(
-                            sd((n, nb, 16), _np.uint32), sd((n,), i32),
-                            sd((n,), i32), q_sd, g16_sd,
-                            sd((n, 20), i32), sd((n, 20), i32),
-                            sd((n, 20), i32), sd((n,), bool),
-                            sd((n, 8), _np.uint32), sd((n,), bool)
-                        ).compile()
+                        fn.aot(
+                            sd((n, nb, 16), _np.uint32, lane),
+                            sd((n,), i32, lane), sd((n,), i32, lane),
+                            q_sd, g16_sd, sd((n, 20), i32, lane),
+                            sd((n, 20), i32, lane),
+                            sd((n, 20), i32, lane), sd((n,), bool, lane),
+                            sd((n, 8), _np.uint32, lane),
+                            sd((n,), bool, lane))
                         logger.info("prewarmed comb pipeline K=%d "
                                     "lanes=%d nb=%d q16=%s", K, n, nb,
                                     q16)
@@ -3082,6 +3130,17 @@ class TPUProvider(api.BCCSP):
                              "will pay the compile)")
         finally:
             self.stats["prewarm_done"] = 1
+            st = self.stats
+            logger.info(
+                "prewarm done: executable_store_hits=%d "
+                "executable_store_misses=%d executable_store_errors=%d "
+                "compile_cold_total=%d; %s",
+                st["executable_store_hits"],
+                st["executable_store_misses"],
+                st["executable_store_errors"], st["compile_cold_total"],
+                "; ".join("%s source=%s lower_s=%s load_s=%s" % (
+                    e["kind"], e["source"], e["lower_s"], e["load_s"])
+                    for e in self._devicecost.events if e["aot"]))
 
     # -- pairings (idemix stretch: BASELINE config 4) --
 

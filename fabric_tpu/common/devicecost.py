@@ -9,8 +9,10 @@ span OOMs — none of which was observable. Three instruments fix that:
 
   * ``CompileRecorder`` — the ONE seam every compiled-path build in
     `bccsp/tpu.py` goes through (``TPUProvider._jit``). Each first
-    dispatch of a new argument shape (and each AOT
-    ``lower(...).compile()`` from prewarm) is timed, classified
+    dispatch of a new argument shape (and each program prewarm names
+    ahead of time, ``InstrumentedJit.aot`` — served from the store of
+    compiled executables, common/execstore.py, without a trace where
+    it holds the program) is timed, classified
     cache-hit vs cold (persistent-cache-dir delta + a wall-time
     threshold: a cold compile WRITES a new cache entry and takes
     seconds-to-minutes; a warm load does neither), annotated with
@@ -45,7 +47,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from fabric_tpu.common import tracing
+from fabric_tpu.common import execstore, tracing
 
 logger = logging.getLogger("common.devicecost")
 
@@ -70,6 +72,7 @@ ANALYSIS_ENABLED = os.environ.get("FTPU_DEVICECOST_ANALYSIS",
                                   "1") == "1"
 
 _EVENT_CAP = 256        # bounded per-compile event history
+_UNRESOLVED = object()  # CompileRecorder.store before its first use
 
 # JAX's own word on a compile request that found its program in the
 # persistent cache (jax.monitoring). Where it speaks it beats the
@@ -96,15 +99,17 @@ def _jax_cache_hit_count() -> int:
     return _jax_cache_hits[0]
 
 
-def _shape_key(args) -> tuple:
+def _shape_key(args, static=()) -> tuple:
     """A compiled-program shape key: (shape, dtype) per argument —
     the same data XLA keys its own dispatch cache by. Non-array
-    arguments degrade to their type name."""
+    arguments degrade to their type name, and an argument at one of
+    the program's `static` positions is keyed by its value."""
     return tuple(
+        ("static", a) if i in static else
         (getattr(a, "shape", None),
          getattr(a, "dtype", None) if getattr(a, "dtype", None)
          is not None else type(a).__name__)
-        for a in args)
+        for i, a in enumerate(args))
 
 
 def _normalize_cost(ca) -> Optional[dict]:
@@ -180,23 +185,41 @@ class CompileRecorder:
       compile_failures    builds/compiles that raised (armed
                           ``tpu.compile`` faults land here)
       compile_seconds     cumulative wall seconds inside the seam
+      executable_store_hits    AOT requests served from the store of
+                               compiled executables (no trace, no
+                               lowering; each is also a cache hit)
+      executable_store_misses  AOT requests the store had no entry
+                               for: lowered, compiled or loaded from
+                               the persistent cache, then written
+      executable_store_errors  entries that could not be read, checked
+                               or loaded (served as a miss and
+                               replaced), and failed writes
 
     ``cache_dir`` may be a path, a zero-arg callable resolving one
     (``jaxenv.cache_dir`` — the persistent cache may be enabled after
     the provider is built), or None (threshold-only classification).
+    ``store`` is the executable store behind the AOT seam; left unset
+    it is ``ExecutableStore.beside_compile_cache`` of that directory,
+    resolved at the first AOT request (tests hand one over a temporary
+    directory).
     """
 
     def __init__(self, stats: Optional[dict] = None,
                  clock: Callable[[], float] = time.perf_counter,
                  cache_dir=None,
                  cold_threshold_s: Optional[float] = None,
-                 analysis: Optional[bool] = None):
+                 analysis: Optional[bool] = None,
+                 store: Optional[execstore.ExecutableStore] = None):
         self.stats = stats if stats is not None else {}
         self.stats.setdefault("compile_total", 0)
         self.stats.setdefault("compile_cache_hits", 0)
         self.stats.setdefault("compile_cold_total", 0)
         self.stats.setdefault("compile_failures", 0)
         self.stats.setdefault("compile_seconds", 0.0)
+        self.stats.setdefault("executable_store_hits", 0)
+        self.stats.setdefault("executable_store_misses", 0)
+        self.stats.setdefault("executable_store_errors", 0)
+        self._store = _UNRESOLVED if store is None else store
         self._clock = clock
         self._cache_dir = cache_dir
         self.cold_threshold_s = (COLD_COMPILE_THRESHOLD_S
@@ -244,18 +267,47 @@ class CompileRecorder:
         except OSError:
             return -1
 
+    # -- the executable store behind the AOT seam --
+
+    @property
+    def store(self) -> Optional[execstore.ExecutableStore]:
+        if self._store is _UNRESOLVED:
+            try:
+                self._store = \
+                    execstore.ExecutableStore.beside_compile_cache(
+                        self._cache_dir_path())
+            except Exception:       # noqa: BLE001 (no backend: no store)
+                logger.exception("no executable store")
+                self._store = None
+        return self._store
+
+    @store.setter
+    def store(self, store: Optional[execstore.ExecutableStore]) -> None:
+        self._store = store
+
+    def count_store(self, outcome: str) -> None:
+        """``hits`` | ``misses`` | ``errors`` += 1."""
+        with self._lock:
+            self.stats["executable_store_" + outcome] += 1
+
     # -- recording --
 
     def note(self, kind: str, seconds: float, *, cache_hit: bool,
              key=None, cost: Optional[dict] = None,
              error: Optional[BaseException] = None,
-             aot: bool = False) -> None:
+             aot: bool = False, detail: Optional[dict] = None) -> None:
         """Book one pass through the seam. ``error`` records a failed
         build/compile (counter only — the caller re-raises and the
-        enclosing ``tpu.compile`` span stamps error status)."""
+        enclosing ``tpu.compile`` span stamps error status).
+        ``detail`` is what the ``tpu.compile`` span carries beside
+        kind and aot (`run_compile`)."""
+        detail = detail or {}
         ev = {"kind": kind, "seconds": round(float(seconds), 6),
               "cache_hit": bool(cache_hit) and error is None,
               "cold": error is None and not cache_hit,
+              "source": detail.get("source"),
+              "lower_s": detail.get("lower_s"),
+              "load_s": detail.get("load_s"),
               "aot": aot, "steady": self._steady,
               "key": repr(key) if key is not None else None,
               "cost": cost or None,
@@ -291,55 +343,100 @@ class CompileRecorder:
                 if self._steady else "")
 
     def run_compile(self, kind: str, key, thunk, *,
-                    cost: Optional[dict] = None, aot: bool = False):
+                    cost: Optional[dict] = None, aot: bool = False,
+                    detail: Optional[dict] = None):
         """THE classification path: run `thunk` (a first-shape
-        dispatch or an AOT ``lower().compile()``) inside a
-        ``tpu.compile`` span, time it, classify hit-vs-cold
-        (JAX's cache-hit event, else cache-dir entry delta + wall
-        threshold) and book the event. A raising thunk books a failure
-        and re-raises."""
+        dispatch or an AOT request) inside a ``tpu.compile`` span,
+        time it, classify hit-vs-cold (JAX's cache-hit event, else
+        cache-dir entry delta + wall threshold) and book the event. A
+        raising thunk books a failure and re-raises.
+
+        ``detail`` is the AOT seam's account of what the thunk did,
+        filled in by the thunk and set on the span: ``lower_s`` and
+        ``load_s`` (seconds of tracing-and-lowering, and of loading or
+        compiling, apart), ``cost``, and ``source: "store"`` where the
+        executable store served the request — no compile of any kind.
+        Every span leaves with ``source`` = ``store`` | ``cache`` |
+        ``cold``."""
+        detail = {} if detail is None else detail
         before = self.cache_entries()
         hits0 = _jax_cache_hit_count()
         t0 = self._clock()
+        sp = tracing.span("tpu.compile", kind=kind, aot=aot)
         try:
-            with tracing.span("tpu.compile", kind=kind, aot=aot):
+            with sp:
                 out = thunk()
+                dt = self._clock() - t0
+                if detail.get("source") == "store":
+                    hit = True
+                else:
+                    wrote = before >= 0 and self.cache_entries() > before
+                    hit = (not wrote) and (
+                        detail.get("load_s", dt) < self.cold_threshold_s
+                        or _jax_cache_hit_count() > hits0)
+                    detail["source"] = "cache" if hit else "cold"
+                cost = detail.pop("cost", cost)
+                sp.set(**detail)
         except BaseException as e:
             self.note(kind, self._clock() - t0, cache_hit=False,
                       key=key, cost=cost, error=e, aot=aot)
             raise
-        dt = self._clock() - t0
-        wrote = before >= 0 and self.cache_entries() > before
-        hit = (not wrote) and (dt < self.cold_threshold_s
-                               or _jax_cache_hit_count() > hits0)
         self.note(kind, dt, cache_hit=hit, key=key, cost=cost,
-                  aot=aot)
+                  aot=aot, detail=detail)
         return out
 
-    def wrap(self, kind: str, jitted) -> "InstrumentedJit":
+    def wrap(self, kind: str, jitted, *, params: Optional[dict] = None,
+             static_argnums=()) -> "InstrumentedJit":
         """Instrument one jitted program — the return value of the
-        provider's ``_jit`` seam."""
-        return InstrumentedJit(self, kind, jitted)
+        provider's ``_jit`` seam. ``params`` (every static parameter of
+        the builder that made the program) and ``static_argnums`` (as
+        given to ``jax.jit``) are what the AOT seam keys the executable
+        store by, beside the shapes."""
+        return InstrumentedJit(self, kind, jitted, params=params,
+                               static_argnums=static_argnums)
+
+
+def _devices_of(shapes) -> list:
+    """The devices an AOT request's executable runs on: those of the
+    mesh its shapes are sharded over, else the default device (what
+    ``lower()`` itself resolves a shape without a sharding to)."""
+    for a in shapes:
+        mesh = getattr(getattr(a, "sharding", None), "mesh", None)
+        if mesh is not None:
+            return list(mesh.devices.flat)
+    import jax
+    return jax.devices()[:1]
 
 
 class InstrumentedJit:
     """A jitted callable whose first dispatch per argument shape (and
-    AOT ``lower().compile()``) runs inside the compile seam. Steady
-    dispatches of a seen shape pay one set lookup."""
+    each AOT request, `aot`) runs inside the compile seam. Steady
+    dispatches of a seen shape pay one dict lookup: `_ready` maps a
+    shape key to what serves it — the jitted function itself for a
+    shape that arrived by dispatch, the compiled executable for one
+    that `aot` registered, which is then called directly (jit never
+    sees the shape, so the process never traces it)."""
 
-    __slots__ = ("_rec", "_kind", "_fn", "_seen", "_seen_lock")
+    __slots__ = ("_rec", "_kind", "_fn", "_ready", "_lock", "_params",
+                 "_static")
 
-    def __init__(self, recorder: CompileRecorder, kind: str, jitted):
+    def __init__(self, recorder: CompileRecorder, kind: str, jitted, *,
+                 params: Optional[dict] = None, static_argnums=()):
         self._rec = recorder
         self._kind = kind
         self._fn = jitted
-        self._seen: set = set()
-        self._seen_lock = threading.Lock()
+        self._ready: dict = {}
+        self._lock = threading.Lock()
+        self._params = dict(params or {})
+        self._static = ((static_argnums,)
+                        if isinstance(static_argnums, int)
+                        else tuple(static_argnums))
 
     def __call__(self, *args):
-        key = _shape_key(args)
-        if key in self._seen:
-            return self._fn(*args)
+        key = _shape_key(args, self._static)
+        serve = self._ready.get(key)
+        if serve is not None:
+            return serve(*args)
         return self._compile_call(key, args)
 
     def _compile_call(self, key, args):
@@ -350,20 +447,21 @@ class InstrumentedJit:
         hit/cold classification is the recorder's shared
         ``run_compile`` path, inside its ``tpu.compile`` span."""
         rec = self._rec
-        with self._seen_lock:
-            first = key not in self._seen
+        with self._lock:
+            first = key not in self._ready
             if first:
-                self._seen.add(key)
+                self._ready[key] = self._fn
         if not first:
-            return self._fn(*args)
+            return self._ready[key](*args)
         cost = self._cost_analysis(args)
         try:
             return rec.run_compile(self._kind, key,
                                    lambda: self._fn(*args),
                                    cost=cost)
         except BaseException:
-            with self._seen_lock:
-                self._seen.discard(key)
+            with self._lock:
+                if self._ready.get(key) is self._fn:
+                    del self._ready[key]
             raise
 
     def _cost_analysis(self, args) -> Optional[dict]:
@@ -379,41 +477,85 @@ class InstrumentedJit:
         except Exception:           # noqa: BLE001
             return None
 
-    def lower(self, *args):
-        """AOT seam: prewarm's ``fn.lower(shapes).compile()`` records
-        through the same bookkeeping (``aot=True``). The shape is NOT
-        marked seen — jit keeps its own dispatch cache, so the first
-        real call still pays (and records) a persistent-cache hit."""
-        return _InstrumentedLowered(self, _shape_key(args),
-                                    self._fn.lower(*args))
+    def aot(self, *shapes):
+        """The AOT seam: make the executable for `shapes`
+        (``jax.ShapeDtypeStruct``s, a static argument by its value)
+        ready BEFORE the first dispatch, and register it so that the
+        dispatch of that shape calls it directly.
+
+        With an executable store (``CompileRecorder.store``) the
+        request is first looked up there, by a key that needs no trace
+        (common/execstore.py): a hit deserializes and loads the entry
+        and nothing is traced or lowered in this process. A miss — and
+        any entry that cannot be read, checked or loaded, which counts
+        in ``executable_store_errors`` — does ``lower().compile()``
+        (JAX's persistent cache, or a cold compile) and writes the
+        entry for the next process; a failed write only logs and
+        counts. Either way it is one ``tpu.compile`` span
+        (``aot=True``; ``source``, ``lower_s``, ``load_s``) and one
+        event. The executable is strict about what it is given: call
+        the shape with arrays of other dtypes or shardings than
+        `shapes` said and it raises."""
+        key = _shape_key(shapes, self._static)
+        ready = self._ready.get(key)
+        if ready is not None and ready is not self._fn:
+            return
+        rec = self._rec
+        detail: dict = {}
+        self._ready[key] = rec.run_compile(
+            self._kind, key, lambda: self._aot(shapes, detail),
+            aot=True, detail=detail)
+
+    def _aot(self, shapes, detail: dict):
+        rec, store, static = self._rec, self._rec.store, self._static
+        clock = rec._clock
+        compiled = req = None
+        if store is not None:
+            t0 = clock()
+            try:
+                req = store.request(self._kind, self._params, shapes,
+                                    static, _devices_of(shapes))
+                compiled = store.load(req)
+            except Exception:       # noqa: BLE001 (served as a miss)
+                rec.count_store("errors")
+                logger.exception("executable store: %s entry not "
+                                 "served; compiling", self._kind)
+            else:
+                rec.count_store("misses" if compiled is None else "hits")
+            if compiled is not None:
+                detail.update(source="store", lower_s=0.0,
+                              load_s=round(clock() - t0, 6))
+        if compiled is None:
+            t0 = clock()
+            lowered = self._fn.lower(*shapes)
+            t1 = clock()
+            if rec.analysis:
+                try:
+                    detail["cost"] = _normalize_cost(
+                        lowered.cost_analysis())
+                except Exception:   # noqa: BLE001 (where jax has none)
+                    detail["cost"] = None
+            t2 = clock()
+            compiled = lowered.compile()
+            detail.update(lower_s=round(t1 - t0, 6),
+                          load_s=round(clock() - t2, 6))
+            if req is not None:
+                t0 = clock()
+                try:
+                    size = store.save(req, compiled)
+                    detail.update(store_bytes=size)
+                except Exception:   # noqa: BLE001 (logs and counts)
+                    rec.count_store("errors")
+                    logger.exception("executable store: %s entry not "
+                                     "written", self._kind)
+                detail.update(store_write_s=round(clock() - t0, 6))
+        if not static:
+            return compiled
+        return lambda *args: compiled(
+            *[a for i, a in enumerate(args) if i not in static])
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
-
-
-class _InstrumentedLowered:
-    __slots__ = ("_ijit", "_key", "_lowered")
-
-    def __init__(self, ijit: InstrumentedJit, key, lowered):
-        self._ijit = ijit
-        self._key = key
-        self._lowered = lowered
-
-    def compile(self, *args, **kwargs):
-        ijit, rec = self._ijit, self._ijit._rec
-        cost = None
-        if rec.analysis:
-            try:
-                cost = _normalize_cost(self._lowered.cost_analysis())
-            except Exception:       # noqa: BLE001
-                cost = None
-        return rec.run_compile(
-            ijit._kind, self._key,
-            lambda: self._lowered.compile(*args, **kwargs),
-            cost=cost, aot=True)
-
-    def __getattr__(self, name):
-        return getattr(self._lowered, name)
 
 
 # ---------------------------------------------------------------------------

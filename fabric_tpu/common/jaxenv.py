@@ -15,9 +15,14 @@ otherwise the cache is ``<checkout>/.cache/xla`` (git-ignored) — the
 same directory for nodes, bench, tools, tests and chip_smoke.py. The
 path is part of a cache entry's key, so a directory that moves never
 hits. The other derived state the framework keeps across processes
-(host-built constant tables, bench warm keys) lives beside it under
-``<checkout>/.cache`` (`local_cache`): nothing is read from or written
-to the user's home directory.
+lives with it: the store of compiled executables that lets a restarted
+process skip tracing its verify programs (common/execstore.py) is the
+sub-directory ``executables/`` of the cache directory itself — it
+follows ``JAX_COMPILATION_CACHE_DIR`` as the cache does and is on
+exactly when the cache is — and host-built constant tables and bench
+warm keys sit beside the cache under ``<checkout>/.cache``
+(`local_cache`). Nothing is read from or written to the user's home
+directory.
 """
 
 from __future__ import annotations
@@ -44,7 +49,8 @@ def cache_dir() -> str | None:
     """The persistent-compile-cache directory once enabled, or None.
     The compile seam (common/devicecost.py) probes this dir's entry
     count around each compile: a cold compile WRITES an entry, a warm
-    load only reads — the cache-hit-vs-miss signal."""
+    load only reads — the cache-hit-vs-miss signal. Its AOT path keeps
+    the executable store in this dir's ``executables/``."""
     if not _done:
         return None
     return os.environ.get(_ENV, _DEFAULT) or None

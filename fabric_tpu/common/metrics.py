@@ -279,6 +279,27 @@ BCCSP_COMPILE_SECONDS_OPTS = GaugeOpts(
          "start — the device-side cost the bench's compile_s stage "
          "field and the perf ledger track across rounds.")
 
+BCCSP_EXECUTABLE_STORE_HITS_OPTS = GaugeOpts(
+    namespace="bccsp", subsystem="executable_store", name="hits",
+    help="Programs prewarm asked for ahead of time that were loaded "
+         "from the store of compiled executables "
+         "(common/execstore.py) since process start: nothing traced, "
+         "lowered or compiled. A restarted peer reads its whole "
+         "inventory here.")
+
+BCCSP_EXECUTABLE_STORE_MISSES_OPTS = GaugeOpts(
+    namespace="bccsp", subsystem="executable_store", name="misses",
+    help="Programs prewarm asked for that the store held no entry "
+         "for (first start after a change of code, JAX or device): "
+         "lowered, loaded from the persistent compile cache or "
+         "compiled cold, then written to the store.")
+
+BCCSP_EXECUTABLE_STORE_ERRORS_OPTS = GaugeOpts(
+    namespace="bccsp", subsystem="executable_store", name="errors",
+    help="Store entries that could not be read, checked (sha256, "
+         "request, input shapes) or loaded — served by compiling and "
+         "replaced — plus entries that could not be written.")
+
 BCCSP_DEVICE_MEM_USED_BYTES_OPTS = GaugeOpts(
     namespace="bccsp", subsystem="device", name="mem_used_bytes",
     help="Per-device bytes currently allocated (memory_stats "

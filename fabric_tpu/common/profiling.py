@@ -210,6 +210,12 @@ def publish_provider_stats(metrics_provider, csp, poll_s: float = 5.0):
         "compile_cache_hits":
             metrics_mod.BCCSP_COMPILE_CACHE_HITS_OPTS,
         "compile_seconds": metrics_mod.BCCSP_COMPILE_SECONDS_OPTS,
+        "executable_store_hits":
+            metrics_mod.BCCSP_EXECUTABLE_STORE_HITS_OPTS,
+        "executable_store_misses":
+            metrics_mod.BCCSP_EXECUTABLE_STORE_MISSES_OPTS,
+        "executable_store_errors":
+            metrics_mod.BCCSP_EXECUTABLE_STORE_ERRORS_OPTS,
         # round-21 pairing engine: serving/demotion counters spanning
         # both device pairing paths (BLS12-381 aggregates, BN254
         # idemix products)

@@ -65,7 +65,11 @@ class _FakeLowered:
 
     def compile(self):
         self._jit._run_once()
-        return "compiled"
+
+        def compiled(*args):
+            self._jit.compiled_calls += 1
+            return "out of the executable"
+        return compiled
 
 
 class _FakeJit:
@@ -82,6 +86,7 @@ class _FakeJit:
         self.cost = cost
         self.raises = raises
         self.calls = 0
+        self.compiled_calls = 0
 
     def _run_once(self):
         if self.raises is not None:
@@ -177,18 +182,50 @@ class TestCompileSeam:
         assert rec.stats["compile_cold_total"] == 1
         assert rec.stats["compile_cache_hits"] == 1
 
-    def test_aot_lower_compile_records(self, tmp_path):
+    def test_aot_records_and_registers_the_executable(self, tmp_path):
         rec, clock = self._recorder(tmp_path)
-        fn = rec.wrap("comb_digest",
-                      _FakeJit(clock, durations=[10.0, 0.01]))
-        fn.lower(np.zeros((8,), np.int32)).compile()
+        fake = _FakeJit(clock, durations=[10.0, 0.01])
+        fn = rec.wrap("comb_digest", fake)
+        fn.aot(np.zeros((8,), np.int32))
         assert rec.stats["compile_total"] == 1
+        assert rec.stats["compile_cold_total"] == 1
         assert rec.events[0]["aot"] is True
-        # the jit's own dispatch cache still pays (and records) the
-        # first real call — a persistent-cache hit
-        fn(np.zeros((8,), np.int32))
+        assert rec.events[0]["source"] == "cold"
+        # the shape is served by the executable the AOT request made:
+        # its first real call never reaches jit and records nothing
+        assert fn(np.zeros((8,), np.int32)) == "out of the executable"
+        assert (fake.calls, fake.compiled_calls) == (1, 1)
+        assert rec.stats["compile_total"] == 1
+        # a shape nobody asked for ahead of time goes through jit
+        assert fn(np.zeros((16,), np.int32)) == "out"
         assert rec.stats["compile_total"] == 2
         assert rec.stats["compile_cache_hits"] == 1
+        assert rec.events[1]["source"] == "cache"
+        # no store on a recorder nobody gave one (CPU backend)
+        assert rec.store is None
+        assert rec.stats["executable_store_misses"] == 0
+
+    def test_aot_failure_counts_and_leaves_the_shape_to_jit(
+            self, tmp_path):
+        rec, clock = self._recorder(tmp_path)
+        boom = RuntimeError("XLA died")
+        fake = _FakeJit(clock, durations=[0.01], raises=boom)
+        fn = rec.wrap("comb_digest", fake)
+        with pytest.raises(RuntimeError):
+            fn.aot(np.zeros((8,), np.int32))
+        assert rec.stats["compile_failures"] == 1
+        assert rec.stats["compile_total"] == 0
+        fake.raises = None
+        assert fn(np.zeros((8,), np.int32)) == "out"
+        assert rec.stats["compile_total"] == 1
+
+    def test_aot_cost_analysis_rides_the_event(self, tmp_path):
+        rec, clock = self._recorder(tmp_path, analysis=True)
+        fake = _FakeJit(clock, durations=[10.0],
+                        cost={"flops": 128.0, "bytes accessed": 64.0})
+        rec.wrap("comb_digest", fake).aot(np.zeros((8,), np.int32))
+        assert rec.events[0]["cost"] == {"flops": 128.0,
+                                         "bytes_accessed": 64.0}
 
     def test_failure_counts_and_propagates(self, tmp_path):
         rec, clock = self._recorder(tmp_path)
