@@ -11,8 +11,12 @@ Phases (one JSON object per phase on stdout, then the result line):
         seam with `{"Default": "TPU"}` and nothing else, on one
         real-size block (10,240 transactions x (2-of-3 endorsements +
         creator) = 30,720 signatures, ~256-byte messages, a seeded
-        share tampered). Verdicts must equal the sw provider's lane for
-        lane, and the counters must prove the DEVICE served them.
+        share tampered), and one 2,048-lane batch under as many
+        distinct keys as the provider's table pool has slots (13 as
+        shipped: the widest channel the comb program serves). Verdicts
+        must equal the sw provider's
+        lane for lane, and the counters must prove the DEVICE served
+        them, from the same compiled program.
   node  real processes, the README's quick start: one sw orderer, one
         peer with `BCCSP: {Default: TPU}` (the chip's only owner), one
         sw peer as the plain reference; 1,500 `assetcc` puts at the
@@ -77,9 +81,12 @@ def emit(obj: dict) -> None:
 # phase `seam` (and the two `mesh` children) — runs in a CHILD process
 # ---------------------------------------------------------------------------
 
-def make_items(seed: int, txs: int):
-    """3 endorser keys + 1 creator key; per transaction a creator
-    signature and 2-of-3 endorsements over ~256-byte messages. A
+WIDE_LANES = 2048       # the wide batch: one span
+
+
+def make_items(seed: int, txs: int, n_endorsers: int = 3):
+    """`n_endorsers` endorser keys + 1 creator key; per transaction a
+    creator signature and 2 endorsements over ~256-byte messages. A
     seeded ~3% of lanes is tampered in each of the differential
     tests' ways (tests/test_bccsp.py `_corpus`). Keys, messages and
     tamper positions come from `seed`."""
@@ -95,8 +102,8 @@ def make_items(seed: int, txs: int):
     keys = [sw.key_import(
         ec.derive_private_key(rng.randrange(1, utils.P256_N),
                               ec.SECP256R1()),
-        ECDSAPrivateKeyImportOpts()) for _ in range(4)]
-    endorsers, creator = keys[:3], keys[3]
+        ECDSAPrivateKeyImportOpts()) for _ in range(n_endorsers + 1)]
+    endorsers, creator = keys[:-1], keys[-1]
     items, tampered = [], 0
     for t in range(txs):
         signers = [creator] + rng.sample(endorsers, 2)
@@ -111,7 +118,8 @@ def make_items(seed: int, txs: int):
                 if how == 0:        # bad signature: message changed
                     msg = msg + b"!"
                 elif how == 1:      # wrong key
-                    pub = keys[(keys.index(k) + 1) % 4].public_key()
+                    pub = keys[(keys.index(k) + 1)
+                               % len(keys)].public_key()
                 elif how == 2:      # high-S twin
                     r, s = utils.unmarshal_signature(sig)
                     sig = utils.marshal_signature(r, utils.P256_N - s)
@@ -152,6 +160,8 @@ def seam_child(args) -> dict:
     cfg: dict = {"Default": "TPU"}
     if args.devices is not None:
         cfg["TPU"] = {"Devices": args.devices}
+    if args.warm_keys_dir:
+        cfg.setdefault("TPU", {})["WarmKeysDir"] = args.warm_keys_dir
     prov = factory.new_bccsp(factory.FactoryOpts.from_config(cfg))
     out["compile_cache"] = jaxenv.cache_dir()
     ndev = prov.stats["shard_devices"]
@@ -195,6 +205,32 @@ def seam_child(args) -> dict:
                  if prov.stats[c] > before[c]]
         check(moved, "no device dispatch counter moved on a warm call")
         served_by.append(moved)
+    # the wide batch: a key a slot of the pool, one span. Its cold call
+    # admits the keys the block above did not bring (a slab build and a
+    # pool write each, the block's own keys evicted for the last of
+    # them); its warm calls find every key in its slot. The same
+    # compiled program serves it: no compile from here on.
+    wide, _ = make_items(args.seed + 1,
+                         min(WIDE_LANES // 3, max(args.txs, 16)),
+                         prov.stats["key_slot_capacity"] - 1)
+    wide_want = SWProvider().verify_batch(wide)
+    wide_s = []
+    for _ in range(3):
+        before = dict(prov.stats)
+        t0 = time.perf_counter()
+        got_wide = prov.verify_batch(wide)
+        wide_s.append(round(time.perf_counter() - t0, 3))
+        check(got_wide == wide_want, "wide-batch verdicts differ from sw")
+        check(prov.stats["comb_batches"] > before["comb_batches"],
+              "the wide batch did not go to the comb program")
+    built = prov.stats["key_slot_builds"] - cold["key_slot_builds"]
+    out["wide"] = {
+        "lanes": len(wide),
+        "keys": len({(it.key.x, it.key.y) for it in wide}),
+        "cold_call_s": wide_s[0], "warm_call_s": wide_s[1:],
+        "key_slot_builds": built,
+        "slab_build_s": round((wide_s[0] - min(wide_s[1:]))
+                              / max(built, 1), 3)}
     st = prov.stats
     out.update(
         warm_call_s=warm_s, served_by=served_by,
@@ -204,7 +240,10 @@ def seam_child(args) -> dict:
               "pipeline_span": prov._pipeline_span(),
               "bucket": prov._bucket(len(items))},
         stats={k: st[k] for k in FALLBACK_COUNTERS + DISPATCH_COUNTERS
-               + ("q16_resident_sets", "q16_builds", "compile_total",
+               + ("key_slots_resident", "key_slot_capacity",
+                  "key_slot_builds", "key_slot_evictions",
+                  "key_slot_lookups", "key_slot_hits",
+                  "key_table_bytes", "compile_total",
                   "compile_cold_total", "compile_cache_hits",
                   "executable_store_hits", "executable_store_misses",
                   "executable_store_errors",
@@ -214,6 +253,14 @@ def seam_child(args) -> dict:
         verdict_sha256=hashlib.sha256(bytes(got)).hexdigest())
     ms = getattr(dev, "memory_stats", lambda: None)() or {}
     out["peak_bytes_in_use"] = ms.get("peak_bytes_in_use")
+    if args.warm_keys_dir:
+        t0 = time.perf_counter()
+        prov.flush_warm_tables()
+        out["warm_keys"] = {
+            "flush_s": round(time.perf_counter() - t0, 3),
+            "files": len([n for n in os.listdir(args.warm_keys_dir)
+                          if n.endswith(".npy")]),
+            "persist_failures": st["warm_table_persist_failures"]}
 
     for c in FALLBACK_COUNTERS:
         check(st[c] == 0, f"{c} = {st[c]}: a host path served lanes")
@@ -226,9 +273,15 @@ def seam_child(args) -> dict:
     check(args.rehearse or st["executable_store_hits"]
           + st["executable_store_misses"] > 0,
           "prewarm asked the executable store for nothing")
-    if out["path"]["q16"]:
-        check(st["q16_resident_sets"] >= 1,
-              "the warm calls rode the 8-bit Q tables (q16 not resident)")
+    check(st["key_slots_resident"] >= out["wide"]["keys"],
+          f"{st['key_slots_resident']} keys resident after a batch of "
+          f"{out['wide']['keys']}")
+    # the first block's 4 keys and the wide batch's: least recently
+    # used keys go only where the pool has no slot left
+    evictions = max(0, 4 + out["wide"]["keys"] - st["key_slot_capacity"])
+    check(st["key_slot_evictions"] == evictions,
+          f"{st['key_slot_evictions']} keys evicted, {evictions} "
+          "expected")
     if not args.rehearse:
         check(out["path"]["q16"], "q16 tables resolved off on a TPU")
     if ndev > 1:
@@ -451,7 +504,7 @@ def node_phase(args, root: str) -> dict:
         out["tpu_peer_metrics"] = {
             k: v for k, v in m.items() if k.startswith((
                 "bccsp_device_info", "bccsp_compile",
-                "bccsp_executable_store", "bccsp_q16_res",
+                "bccsp_executable_store", "bccsp_key_slot",
                 "bccsp_prewarm")) or k in [
                 f"bccsp_{c}" for c in FALLBACK_COUNTERS
                 + DISPATCH_COUNTERS]}
@@ -522,6 +575,8 @@ def run_child(args, name: str, devices=None) -> dict:
         argv += ["--devices", str(devices)]
     if args.rehearse:
         argv.append("--rehearse")
+    if args.warm_keys_dir:
+        argv += ["--warm-keys-dir", args.warm_keys_dir]
     t0 = time.perf_counter()
     proc = subprocess.run(argv, stdout=subprocess.PIPE, text=True,
                           timeout=CHILD_TIMEOUT_S)
@@ -550,6 +605,11 @@ def main() -> int:
     ap.add_argument("--out", help="work directory (default: a "
                     "temporary one, removed afterwards)")
     ap.add_argument("--logs-to", help="copy the node logs here")
+    ap.add_argument("--warm-keys-dir",
+                    help="give the seam phase's provider this "
+                         "BCCSP.TPU.WarmKeysDir (not part of the "
+                         "bring-up check: it measures what persisting "
+                         "every built slab costs)")
     ap.add_argument("--child", help=argparse.SUPPRESS)
     ap.add_argument("--devices", type=int, help=argparse.SUPPRESS)
     args = ap.parse_args()

@@ -58,16 +58,27 @@ class TpuOpts:
     # chip. 0 disables the overlapped pipeline (whole-batch staging, the
     # pre-round-6 behavior).
     pipeline_chunk: Optional[int] = None
-    max_keys: int = 16
-    table_cache_bytes: int = 6 << 30
+    # the key-table pool (bccsp/tpu.py, "the key-table pool"): one
+    # resident device array of per-key comb tables. MaxKeys is its
+    # capacity in slots — the most distinct P-256 keys a batch may
+    # carry and still be served by the comb program (more go to the
+    # ladder) — as far as TableCacheMB (the pool's byte budget, in
+    # bytes as the device holds them: 4,000 MB hold 13 slabs of 16-bit
+    # windows, 302 MB a key on the chip — the most that costs the comb
+    # program no device time, PERF.md Findings PR 34; 8-bit windows
+    # are 2.4 MB a key) and half of a chip's memory hold that many.
+    # One capacity, sized once; neither is a limit of its own.
+    max_keys: int = 32
+    table_cache_bytes: int = 4000 << 20
     # True (default): hash message lanes on host, ship 32-byte digests
     # (reference-matching CPU hash; minimal device transfer). False:
     # fuse SHA-256 into the device pipeline (PCIe-attached hosts).
     hash_on_host: bool = True
-    # directory where the provider persists the org key sets it has
-    # built Q tables for, so `prewarm()` rebuilds them BEFORE the first
-    # block after a restart (node assembly defaults this under
-    # peer.fileSystemPath); None disables persistence
+    # directory where the provider keeps a file for every key's table
+    # in the pool, so `prewarm()` reads them back BEFORE the first
+    # block after a restart instead of building them (node assembly
+    # defaults this under peer.fileSystemPath); None disables
+    # persistence
     warm_keys_dir: Optional[str] = None
     # pad device batches up to this bucket (0 = off): pins modest
     # windows (e.g. orderer sig-filter ingest) to an AOT-compiled
@@ -125,9 +136,9 @@ class FactoryOpts:
                 pipeline_chunk=(int(tpu_cfg["PipelineChunk"])
                                 if tpu_cfg.get("PipelineChunk") is not None
                                 else None),
-                max_keys=int(tpu_cfg.get("MaxKeys", 16)),
+                max_keys=int(tpu_cfg.get("MaxKeys", 32)),
                 table_cache_bytes=(
-                    int(tpu_cfg.get("TableCacheMB", 6144)) << 20),
+                    int(tpu_cfg.get("TableCacheMB", 4000)) << 20),
                 hash_on_host=bool(tpu_cfg.get("HashOnHost", True)),
                 warm_keys_dir=tpu_cfg.get("WarmKeysDir") or None,
                 bucket_floor=int(tpu_cfg.get("BucketFloor", 0)),

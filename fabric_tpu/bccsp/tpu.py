@@ -22,6 +22,7 @@ outage must degrade, not halt (SURVEY §7 step 3).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import logging
@@ -98,11 +99,11 @@ def aligned_span(lanes: int, mesh_size: int = 1) -> int:
 
 class TPUProvider(api.BCCSP):
     def __init__(self, keystore=None, min_batch: int = 16,
-                 max_blocks: int = 64, mesh=None, max_keys: int = 16,
+                 max_blocks: int = 64, mesh=None, max_keys: int = 32,
                  chunk: int = 32768,
                  pipeline_chunk: Optional[int] = None,
                  use_g16: Optional[bool] = None,
-                 table_cache_bytes: int = 6 << 30,
+                 table_cache_bytes: int = 4000 << 20,
                  hash_on_host: bool = True,
                  warm_keys_dir: Optional[str] = None,
                  bucket_floor: int = 0,
@@ -179,7 +180,6 @@ class TPUProvider(api.BCCSP):
         # first-sampled chip's reading inflates every later one, so a
         # compute-slow chip PERMANENTLY first would never show a jump
         self._ready_rot = 0
-        self._max_keys = max_keys   # comb path cutoff (distinct pubkeys)
         self._chunk = chunk         # double-buffer chunk size (sigs)
         # overlapped dispatch pipeline (BCCSP.TPU.PipelineChunk): a
         # device batch is split into spans of this many lanes; span
@@ -194,65 +194,41 @@ class TPUProvider(api.BCCSP):
         self._prep_pool = None      # lazy 1-worker host-prep executor
         # 16-bit windows on BOTH bases: the per-signature tree drops
         # from 64 to 32 points (measured 1.6x on the v5e) at the cost
-        # of large resident device tables (~252 MB for G, ~252*K MB per
-        # cached key set for Q). None = auto: on for TPU backends, off
-        # for CPU meshes (where the table build takes minutes and HBM
-        # budgets don't apply). The Q tables are cached per key set
-        # because a validating peer sees the same org keys on every
-        # block; the cache is bounded by BYTES (not entries) and
-        # evicted least-recently-used.
+        # of large resident device tables (~252 MB for G, ~252 MB a
+        # key for Q). None = auto: on for TPU backends, off for CPU
+        # meshes (where a table build takes minutes and HBM budgets
+        # don't apply). One width serves the whole process.
         self._use_g16 = use_g16
+        # the key-table pool (below, "the key-table pool"): MaxKeys
+        # slots, as far as TableCacheMB and the device's free memory
+        # hold them; WarmKeysDir keeps a file a resident key, so a
+        # restarted node reads its tables back instead of building them
+        self._max_keys = max_keys
         self._table_cache_bytes = table_cache_bytes
-        # org key sets persist across restarts so prewarm can rebuild
-        # their Q tables BEFORE the first block needs them (the comb
-        # tables are data, not code — the XLA cache can't carry them)
         self._warm_keys_dir = warm_keys_dir
-        self._qflat_cache: dict = {}     # key-set tuple -> q16 table (LRU)
-        self._qflat_cache_bytes = 0
-        # 8-bit Q tables (~1.9 MB per key slot) cost a device round
-        # trip to rebuild; a peer/orderer sees the same key set every
-        # batch, so cache a handful (entry-count LRU — worst case
-        # 16 sets x MaxKeys is ~500 MB, well under the q16 budget the
-        # TableCacheMB knob governs)
-        self._q8_cache: dict = {}
-        self._Q8_CACHE_MAX = 16
-        # adaptive anti-thrash state: when the working set of key sets
-        # exceeds the byte budget, pin the resident tables and serve
-        # the overflow sets on the 8-bit path instead of rebuilding
-        # multi-minute tables every few blocks (see _q16_cached)
-        self._q16_batch_no = 0           # lookup counter (time base)
-        self._q16_last_use: dict = {}    # cache_key -> batch no
-        self._q16_denied: dict = {}      # cache_key -> batch no denied
-        self._q16_heat: dict = {}        # cache_key -> decayed req rate
-        self._q16_last_req: dict = {}    # cache_key -> batch no requested
-        # built by prewarm from PERSISTED sets, not yet requested by a
-        # live batch: cold (first eviction candidates) until real use.
-        # BENCH_r04 postmortem: marking these hot let stale persisted
-        # sets (org key rotation, channel churn) pin the whole byte
-        # budget and deny the live working set the flagship path.
-        self._q16_prewarmed: set = set()
-        # sets the BACKGROUND restore thread is still streaming to the
-        # device: live misses must NOT block on the disk read + H2D
-        # of a GB-scale table (~252 MB per key slot; seconds not
-        # re-measured on the v5e) — they ride the 8-bit
-        # path until the restore lands, restoring availability-first
-        # semantics (reference peers validate immediately on start)
-        self._q16_loading: set = set()
+        self._pool = None           # the resident device array
+        self._capacity = None       # its slots, sized at first use
+        # key bytes (x || y) -> slot, least recently used first
+        self._slot_of: collections.OrderedDict = \
+            collections.OrderedDict()
         self._restore_thread = None
         self._fn = None             # lazily-built generic jitted pipeline
-        self._comb_fns = {}         # (K, q16) -> jitted comb pipeline
-        self._qtab_fns = {}         # K -> jitted table builder
+        self._comb_fns = {}         # mesh-bound programs, by kind
+        self._qtab_fns = {}         # single-device programs, by kind
         self._jit_lock = threading.Lock()   # prewarm thread vs first
         #                                     block: build each jit once
         # observability: perf-cliff counters surfaced via provider stats
         self.stats = {"comb_batches": 0, "ladder_batches": 0,
                       "host_hash_fallbacks": 0, "sw_fallbacks": 0,
                       "host_hashed_lanes": 0,
-                      "q16_builds": 0, "q16_evictions": 0,
-                      "q16_oversize_skips": 0, "q16_cache_bytes": 0,
-                      "q16_adaptive_skips": 0, "q16_resident_sets": 0,
-                      "q16_disk_loads": 0, "q8_disk_loads": 0,
-                      "q16_loading_skips": 0,
+                      # the key-table pool: keys asked for by
+                      # batches and those found resident; slabs built,
+                      # read back from WarmKeysDir, evicted; keys
+                      # resident now, slots and bytes of the pool
+                      "key_slot_lookups": 0, "key_slot_hits": 0,
+                      "key_slot_builds": 0, "key_slot_disk_loads": 0,
+                      "key_slot_evictions": 0, "key_slots_resident": 0,
+                      "key_slot_capacity": 0, "key_table_bytes": 0,
                       "nonp256_sw_lanes": 0,
                       "ed25519_batches": 0,
                       "bls_aggregate_checks": 0,
@@ -339,9 +315,10 @@ class TPUProvider(api.BCCSP):
         self._ed_tab = None         # replicated device B-comb table
         self._g16_rep = None        # mesh-replicated g16 cache
         self._persist_threads: list = []
-        # serializes warm-file mutations (record/trim/drop) with the
-        # background table-byte writers' publish step, so a concurrent
-        # trim can never resurrect a just-reclaimed table file
+        self._persist_slots = threading.BoundedSemaphore(2)
+        # serializes warm-file removals (an evicted key's slab) with
+        # the background table-byte writers' publish step, so a
+        # concurrent eviction can never resurrect a reclaimed file
         self._warm_lock = threading.Lock()
         # round-16 device-cost recorder: every compiled-path build
         # rides the _jit seam below; counters mirror into self.stats
@@ -351,15 +328,14 @@ class TPUProvider(api.BCCSP):
         # construction time
         self._devicecost = devicecost.CompileRecorder(
             stats=self.stats, cache_dir=jaxenv.cache_dir)
-        # guards ALL q16/q8 cache bookkeeping (_qflat_cache,
-        # _qflat_cache_bytes, _q16_heat/_q16_last_use/_q16_denied/
-        # _q16_prewarmed/_q16_loading, _q8_cache): the background
-        # restore thread and concurrent live batches mutate these
-        # together. Deliberately SEPARATE from _warm_lock — the slow
-        # warm-file I/O must never serialize cache lookups — and an
-        # RLock so helpers can nest. The multi-minute table build and
-        # the disk read happen OUTSIDE this lock (availability first).
-        self._q16_lock = threading.RLock()
+        # guards the pool and its bookkeeping (_pool, _slot_of,
+        # _capacity): the background restore thread and concurrent
+        # live batches mutate these together, and a batch holds it
+        # from its slot lookup to its last enqueue (the pool write
+        # donates the array). Deliberately SEPARATE from _warm_lock —
+        # the slow warm-file I/O must never serialize dispatches — and
+        # an RLock so helpers can nest.
+        self._pool_lock = threading.RLock()
         try:
             d = self.device_info()
             logger.info("BCCSP TPU provider on platform=%s "
@@ -737,10 +713,10 @@ class TPUProvider(api.BCCSP):
         indices): drain in-flight dispatch spans (bounded — a wedged
         span must not hold the rebuild forever), drop every compiled
         program and replicated table handle bound to the old mesh,
-        then install the new one. Tables re-replicate lazily on the
-        first dispatch (`_resolve_tables` re-places them under the
-        new mesh); span/bucket floors re-derive per batch from the
-        serving mesh size."""
+        then install the new one. The key-table pool is dropped with
+        them and filled again by the first dispatches (`_key_slots`);
+        span/bucket floors re-derive per batch from the serving mesh
+        size."""
         lockcheck.note_blocking("tpu.mesh_rebuild")
         import time as _time
         with self._mesh_lock:
@@ -783,14 +759,11 @@ class TPUProvider(api.BCCSP):
                     self._comb_fns.clear()
                     self._fn = None
                     self._ed_tab = None
-                    self._g16_rep = None
-                # cached Q tables replicated over the OLD mesh hold a
-                # shard on the benched chip: re-materialize each from
-                # a known-healthy replica so the first dispatch
-                # re-places clean bytes (an unreadable entry is
-                # dropped — the disk/rebuild path heals it)
-                self._rehost_cached_tables(
-                    {self._dev_all[i] for i in healthy})
+                # the pool replicated over the OLD mesh holds a copy
+                # on the benched chip: drop it, the first dispatch on
+                # the new mesh admits its keys again (from WarmKeysDir
+                # where their slabs are persisted, else rebuilt)
+                self._drop_pool()
                 self._mesh = mesh
             finally:
                 with self._dispatch_cv:
@@ -813,52 +786,6 @@ class TPUProvider(api.BCCSP):
                 logger.info(
                     "serving mesh restored to the full %d device(s)",
                     mesh.size)
-
-    def _rehost_cached_tables(self, keep: set) -> None:
-        """After a mesh swap, cached Q tables replicated over the OLD
-        mesh are poisoned handles — one replica lives on the benched
-        chip, and on real hardware the next `device_put` re-placement
-        may read from it. Re-materialize each cached table on the
-        host from a replica on a KEPT device (`keep` = the new
-        mesh's device objects); entries that cannot be read are
-        dropped (the persisted-bytes / rebuild path heals them on the
-        next miss). Host copies re-replicate through the normal
-        `_resolve_tables` device_put on first dispatch."""
-        with self._q16_lock:
-            for cache in (self._qflat_cache, self._q8_cache):
-                for key in list(cache):
-                    arr = cache[key]
-                    shards = getattr(arr, "addressable_shards", None)
-                    if shards is None:
-                        continue        # already a host array
-                    try:
-                        devs = {getattr(sh, "device", None)
-                                for sh in shards}
-                        if devs <= keep:
-                            continue    # no replica on a benched chip
-                        pick = next((sh for sh in shards
-                                     if sh.device in keep), None)
-                        # ftpu-lint: allow-host-sync(deliberate D2H
-                        # rescue of a replicated table from a healthy
-                        # replica during the rare mesh swap)
-                        host = np.asarray(pick.data if pick is not None
-                                          else arr)
-                        cache[key] = host
-                    except Exception:
-                        evicted = cache.pop(key)
-                        if cache is self._qflat_cache:
-                            self._qflat_cache_bytes -= \
-                                getattr(evicted, "size", 0) * 4
-                            self._q16_last_use.pop(key, None)
-                            self.stats["q16_cache_bytes"] = \
-                                self._qflat_cache_bytes
-                            self.stats["q16_resident_sets"] = \
-                                len(self._qflat_cache)
-                        logger.warning(
-                            "cached table for one key set was "
-                            "unreadable after the mesh swap; dropped "
-                            "(rebuilds from persisted bytes on the "
-                            "next miss)", exc_info=True)
 
     # -- the batch path --
 
@@ -1117,7 +1044,7 @@ class TPUProvider(api.BCCSP):
             # every lane is a digest (or dead) lane: dispatch the
             # transfer-minimal digest pipeline — compact u8 scalars,
             # on-device limb conversion, no SHA stage at all
-            if 0 < len(key_map) <= self._max_keys:
+            if 0 < len(key_map) <= self._key_capacity():
                 self.stats["comb_batches"] += 1
                 out = self._dispatch_comb_digest(
                     bucket, key_map, key_idx, r_b, rpn_b, w_b,
@@ -1172,7 +1099,7 @@ class TPUProvider(api.BCCSP):
                          nblocks, r_l, rpn_l, w_l, premask, digests,
                          has_digest, qx_b, qy_b, async_out=False):
         """Array core shared by the item path and the prepared-block
-        path: comb (bounded key count) or generic ladder dispatch.
+        path: comb (the keys fit the pool) or generic ladder dispatch.
         With async_out the DISPATCH happens now and a thunk returning
         the materialized np result is returned (jax compute proceeds
         in the background while the caller works)."""
@@ -1182,7 +1109,7 @@ class TPUProvider(api.BCCSP):
 
         from fabric_tpu.ops import limb
 
-        if 0 < len(key_map) <= self._max_keys:
+        if 0 < len(key_map) <= self._key_capacity():
             self.stats["comb_batches"] += 1
             thunk = self._dispatch_comb(
                 bucket, key_map, key_idx, blocks, nblocks, r_l, rpn_l,
@@ -1541,8 +1468,8 @@ class TPUProvider(api.BCCSP):
 
         Returns None when this batch should take the whole-batch
         staging path instead: pipeline disabled, fewer than two spans,
-        or a key set outside the comb window (the generic ladder path
-        keeps its own staging). Verdicts are bit-identical to the
+        or more distinct keys than the pool has slots (the generic
+        ladder path keeps its own staging). Verdicts are bit-identical to the
         whole-batch path (pipeline-parity tested)."""
         import time as _time
 
@@ -1554,8 +1481,8 @@ class TPUProvider(api.BCCSP):
         from fabric_tpu import native as native_mod
 
         # host signature gates FIRST, over the whole batch — exactly
-        # the whole-batch path's order, so key-set MEMBERSHIP (and
-        # therefore K and the q16 cache key) is identical across the
+        # the whole-batch path's order, so key MEMBERSHIP (and
+        # therefore which slabs are built) is identical across the
         # two paths: a lane whose signature fails the DER/low-S/range
         # gates must not register its key. Native parses the batch in
         # one GIL-released C call (fast — the EXPENSIVE host half,
@@ -1598,16 +1525,13 @@ class TPUProvider(api.BCCSP):
             lane_ok[i] = True
             kb = pubs[i].x_bytes().tobytes() + pubs[i].y_bytes().tobytes()
             key_idx[i] = key_map.setdefault(kb, len(key_map))
-        if not (0 < len(key_map) <= self._max_keys):
+        if not (0 < len(key_map) <= self._key_capacity()):
             return None             # ladder/empty batches: legacy path
 
         lockcheck.note_blocking("tpu.dispatch")
         faults.check("tpu.dispatch")
         import jax
 
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
-                                                            key_idx)
-        fn = self._comb_pipeline_digest(K, q16)
         nspans = (n + pc - 1) // pc
 
         def prep(ci: int):
@@ -1658,28 +1582,33 @@ class TPUProvider(api.BCCSP):
                 return self._shard_put(arrs, tdev)
             return tuple(jax.device_put(a) for a in arrs)
 
-        pool = self._prep_executor()
-        fut = pool.submit(prep, 0)
+        prep_pool = self._prep_executor()
         outs = []
         prep_ivs = []
         host_s = transfer_s = dispatch_s = 0.0
         hashed_total = 0
         t_disp0 = None
-        for ci in range(nspans):
-            arrs, iv, hashed = fut.result()
-            prep_ivs.append(iv)
-            host_s += iv[1] - iv[0]
-            hashed_total += hashed
-            if ci + 1 < nspans:
-                fut = pool.submit(prep, ci + 1)
-            t0 = _time.perf_counter()
-            dev = put(arrs)
-            transfer_s += _time.perf_counter() - t0
-            t0 = _time.perf_counter()
-            if t_disp0 is None:
-                t_disp0 = t0
-            outs.append(fn(dev[0], q_flat, g16, *dev[1:]))
-            dispatch_s += _time.perf_counter() - t0
+        # the pool lock from the slot lookup to the last enqueue: a
+        # concurrent batch's table write donates the array in hand
+        with self._pool_lock:
+            key_idx, table, g16 = self._key_slots(key_map, key_idx)
+            fn = self._comb_pipeline_digest()
+            fut = prep_pool.submit(prep, 0)
+            for ci in range(nspans):
+                arrs, iv, hashed = fut.result()
+                prep_ivs.append(iv)
+                host_s += iv[1] - iv[0]
+                hashed_total += hashed
+                if ci + 1 < nspans:
+                    fut = prep_pool.submit(prep, ci + 1)
+                t0 = _time.perf_counter()
+                dev = put(arrs)
+                transfer_s += _time.perf_counter() - t0
+                t0 = _time.perf_counter()
+                if t_disp0 is None:
+                    t_disp0 = t0
+                outs.append(fn(dev[0], table, g16, *dev[1:]))
+                dispatch_s += _time.perf_counter() - t0
         if self._mesh is not None:
             # per-device stage gauges BEFORE the gather: the final
             # span's shard readiness is the per-chip signal; the
@@ -1904,7 +1833,7 @@ class TPUProvider(api.BCCSP):
                 out[:n] = a
                 return out
 
-            comb = 0 < len(key_map) <= self._max_keys
+            comb = 0 < len(key_map) <= self._key_capacity()
             if comb:
                 # transfer-minimal digest pipeline (the common case)
                 scalars = (pad8(r), pad8(rpn), pad8(w))
@@ -1942,425 +1871,303 @@ class TPUProvider(api.BCCSP):
             return result
         return resolve
 
-    @staticmethod
-    def _canonical_key_order(key_map: dict, key_idx: np.ndarray):
-        """Reassign key indices by sorted key bytes.
+    # -- the key-table pool --
+    #
+    # ONE resident device array holds the comb table of every P-256
+    # key the provider serves, slot-major: a key's table is one
+    # contiguous slab, row (slot * windows + window) * entries + w
+    # (ops/comb.py), at one width for the life of the process (16-bit
+    # windows where `_g16_enabled()`, ~252 MB a slab; 8-bit otherwise,
+    # ~2 MB). A lane carries its key's slot, so ONE compiled program a
+    # lane shape serves a batch of 3 keys and a batch of 25 alike, and
+    # two blocks that share 24 of 25 keys share 24 slabs. A key is
+    # admitted by building its slab (or reading it back from
+    # WarmKeysDir) and writing it into a free slot, or the least
+    # recently used one that the batch in hand does not use, IN PLACE:
+    # the write donates the pool, so no second copy ever exists. That
+    # donation is why `_pool_lock` is held from a batch's slot lookup
+    # until its last dispatch is enqueued — a handle fetched before
+    # another thread's write would be a deleted array — and why a
+    # write is ordered after every execution already enqueued on it.
 
-        key_map is built in first-appearance order, which varies between
-        batches over the SAME key set; table slot order and the cache key
-        must not depend on it (a cache hit with mismatched slot order
-        would comb every signature against the wrong public key).
-        Returns (ordered key bytes, remapped key_idx).
-        """
-        order = sorted(key_map)
-        remap = np.zeros(len(key_map), dtype=np.int32)
-        for j, kb in enumerate(order):
-            remap[key_map[kb]] = j
-        return order, remap[key_idx]
+    def _slab_rows(self) -> int:
+        from fabric_tpu.ops import comb
+        return (comb.NWIN_G16 * comb.NENT_G16 if self._g16_enabled()
+                else comb.NWIN * comb.NENT)
 
-    def _q16_est_bytes(self, K: int) -> int:
-        from fabric_tpu.ops import comb, limb
-        return comb.NWIN_G16 * K * comb.NENT_G16 * 3 * limb.L * 4
+    def _slab_shape(self) -> tuple:
+        from fabric_tpu.ops import limb
+        return (self._slab_rows(), 3, limb.L)
 
-    # a victim used within this many lookups is "hot" — never evicted
-    # for a no-hotter newcomer; the newcomer is denied q16 for
-    # _DENY_TTL lookups instead (stability beats fairness: a working
-    # set larger than the budget pins the resident tables and serves
-    # the overflow on the 8-bit path, rather than rebuilding
-    # multi-minute tables per block). _HOT_WINDOW also sets the
-    # half-life of the per-key-set request-heat estimate.
-    _HOT_WINDOW = 16
-    _DENY_TTL = 256
-    _HEAT_MAX_ENTRIES = 4096
+    def _slab_bytes(self) -> int:
+        """A slab's bytes as the DEVICE holds them — what the budget
+        buys and the gauges report. The chip keeps a row's three
+        20-limb coordinates in 24 words each (tiles of 8 limbs;
+        `tools/chip_compile.py pool_write`): 301,989,888 B a key at
+        16-bit windows, where the rows alone are 251,658,240."""
+        rows, coords, limbs = self._slab_shape()
+        if self._on_tpu():
+            limbs = -(-limbs // 8) * 8
+        return rows * coords * limbs * 4
 
-    def _q16_heat_bump(self, cache_key, now) -> float:
-        """Exponentially-decayed request rate per key set (half-life
-        _HOT_WINDOW lookups). Denied sets accrue heat too, so a live
-        working set can out-bid cooling residents instead of serving a
-        fixed 256-lookup sentence (the BENCH_r04 starvation)."""
-        heat = self._q16_heat
-        last = self._q16_last_req.get(cache_key, now)
-        h = (heat.get(cache_key, 0.0)
-             * 0.5 ** ((now - last) / self._HOT_WINDOW) + 1.0)
-        heat[cache_key] = h
-        self._q16_last_req[cache_key] = now
-        if len(heat) > self._HEAT_MAX_ENTRIES:
-            # bound the bookkeeping for long-lived nodes seeing many
-            # distinct org key sets (advisor: unbounded accretion)
-            stale = [k for k, t in self._q16_last_req.items()
-                     if now - t > 4 * self._DENY_TTL
-                     and k not in self._qflat_cache]
-            for k in stale:
-                heat.pop(k, None)
-                self._q16_last_req.pop(k, None)
-                self._q16_denied.pop(k, None)
-        return h
+    def _key_capacity(self) -> int:
+        """Slots of the pool, sized once: MaxKeys, as far as
+        TableCacheMB and half of a chip's memory hold that many slabs
+        (every chip of a mesh holds the whole pool). A batch with more
+        distinct keys goes to the ladder."""
+        with self._pool_lock:
+            if self._capacity is None:
+                slab = self._slab_bytes()
+                cap = min(self._max_keys,
+                          self._table_cache_bytes // slab)
+                # of the chip's memory, not of what is free just now:
+                # prewarm's g16 thread allocates while this is read,
+                # and the pool's shape is part of a program's key
+                limit = [r["bytes_limit"]
+                         for r in devicecost.device_memory()
+                         if r["bytes_limit"]]
+                if limit:
+                    cap = min(cap, min(limit) // 2 // slab)
+                self._capacity = max(0, int(cap))
+                self.stats["key_slot_capacity"] = self._capacity
+            return self._capacity
 
-    def _q16_cached(self, cache_key, K, qx_k, qy_k, prewarm=False):
-        """LRU per-key-set 16-bit Q table, bounded by total bytes.
+    def _replicated(self, arr):
+        """`arr` on every chip of the serving mesh (as is without)."""
+        if self._mesh is None:
+            return arr
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(arr, NamedSharding(self._mesh, P()))
 
-        Returns None when this key set should stay on the 8-bit Q path:
-        a single table would blow the byte budget (oversize), or the
-        budget is full of hotter recently-used tables (adaptive
-        anti-thrash). The G side keeps its 16-bit table either way.
+    def _pool_array(self):
+        """The pool, allocated whole at its first use (`_pool_lock`
+        held): a program is compiled for its shape."""
+        if self._pool is None:
+            import jax.numpy as jnp
+            rows = self._key_capacity() * self._slab_rows()
+            where = None
+            if self._mesh is not None:
+                # on every chip at once: made on one chip and then
+                # replicated, that chip would hold two pools a moment
+                from jax.sharding import NamedSharding, PartitionSpec
+                where = NamedSharding(self._mesh, PartitionSpec())
+            self._pool = jnp.zeros((rows,) + self._slab_shape()[1:],
+                                   dtype=jnp.int32, device=where)
+            self.stats["key_table_bytes"] = \
+                self._key_capacity() * self._slab_bytes()
+        return self._pool
 
-        prewarm=True marks a restore of a PERSISTED key set: the table
-        goes in cold (evictable by any live request, never displacing a
-        live resident) and is not re-persisted as most-recently-used —
-        both halves of the BENCH_r04 prewarm-poisoning fix.
+    def _drop_pool(self) -> None:
+        """Forget the pool and every key in it (a mesh swap, a write
+        that lost the donated array): the next batch allocates it anew
+        and admits its keys again, from WarmKeysDir where they are."""
+        with self._pool_lock:
+            self._pool = None
+            self._slot_of.clear()
+            self._g16_rep = None
+            self.stats["key_slots_resident"] = 0
 
-        Misses consult the warm dir's persisted table BYTES before
-        paying the multi-minute device build (the
-        restart-to-first-validated-block fast path; also live sets
-        rotating back inside the byte budget).
-
-        Concurrency: all cache bookkeeping runs under `_q16_lock`
-        (the background restore thread and live batches race here —
-        round-5 advisor finding); the slow disk read and the
-        multi-minute device build run OUTSIDE the lock, with a raced
-        re-insert check at publish time."""
-        with self._q16_lock:
-            self._q16_batch_no += 1
-            now = self._q16_batch_no
-            my_heat = (0.0 if prewarm
-                       else self._q16_heat_bump(cache_key, now))
-            q_flat = self._qflat_cache.pop(cache_key, None)
-            if q_flat is not None:
-                self._qflat_cache[cache_key] = q_flat   # move to MRU
-                if not prewarm:
-                    self._q16_last_use[cache_key] = now
-                    # first live use of a prewarmed table claims it
-                    self._q16_prewarmed.discard(cache_key)
-                return q_flat
-            est = self._q16_est_bytes(K)
-            if est > self._table_cache_bytes:
-                self.stats["q16_oversize_skips"] += 1
-                logger.warning(
-                    "16-bit Q table for %d keys needs %.1f GB > "
-                    "TableCacheMB budget (%.1f GB); staying on the "
-                    "8-bit Q path for this key set — raise "
-                    "BCCSP.TPU.TableCacheMB to restore the flagship "
-                    "configuration", K, est / 2**30,
-                    self._table_cache_bytes / 2**30)
-                return None
-            denied_at = self._q16_denied.get(cache_key)
-            if denied_at is not None and now - denied_at < self._DENY_TTL:
-                # a denied set that has grown hotter than the coldest
-                # resident re-earns an eviction attempt before its TTL
-                # expires; otherwise one bad denial sticks for 256
-                # batches even after the residents cool off
-                coldest = min((self._q16_heat.get(k, 0.0)
-                               for k in self._qflat_cache), default=0.0)
-                if my_heat <= coldest:
-                    self.stats["q16_adaptive_skips"] += 1
-                    return None
-            if not prewarm and cache_key in self._q16_loading:
-                # the background restore is still streaming this set's
-                # table to the device: serve the batch on the 8-bit
-                # path NOW rather than stalling validation on a
-                # minutes-scale transfer (availability first — the q16
-                # path takes over the moment the restore lands).
-                # Checked BEFORE the eviction loop (round-5 advisor):
-                # a set mid-restore must never evict residents — or
-                # drop a just-persisted prewarmed set's warm state —
-                # on a path that then returns None anyway.
-                self.stats["q16_loading_skips"] += 1
-                return None
-            while (self._qflat_cache
-                   and self._qflat_cache_bytes + est >
-                   self._table_cache_bytes):
-                if prewarm:
-                    # prewarm fills whatever budget is FREE, MRU-first;
-                    # it neither displaces live tables nor churns the
-                    # sets it just restored (evicting those would
-                    # misclassify them as stale and delete their
-                    # persisted bytes)
-                    return None
-                victim = next(iter(self._qflat_cache))
-                victim_hot = (
-                    victim not in self._q16_prewarmed
-                    and now - self._q16_last_use.get(victim, 0) <
-                    self._HOT_WINDOW
-                    and self._q16_heat.get(victim, 0.0) >= my_heat)
-                if victim_hot:
-                    # every evictable resident is in active, hotter
-                    # use: adding this set would thrash — deny it the
-                    # 16-bit path for a while and surface the decision
-                    self._q16_denied[cache_key] = now
-                    if len(self._q16_denied) > self._HEAT_MAX_ENTRIES:
-                        self._q16_denied = {
-                            k: t for k, t in self._q16_denied.items()
-                            if now - t < self._DENY_TTL}
-                    self.stats["q16_adaptive_skips"] += 1
-                    logger.warning(
-                        "q16 table budget (%.1f GB) is full of hot key "
-                        "sets; serving this %d-key set on the 8-bit "
-                        "path (bccsp_q16_adaptive_skips counts these — "
-                        "raise BCCSP.TPU.TableCacheMB to fit the "
-                        "working set)",
-                        self._table_cache_bytes / 2**30, K)
-                    return None
-                evicted = self._qflat_cache.pop(victim)
-                self._q16_last_use.pop(victim, None)
-                self._qflat_cache_bytes -= evicted.size * 4
-                self.stats["q16_evictions"] += 1
-                self.stats["q16_cache_bytes"] = self._qflat_cache_bytes
-                self.stats["q16_resident_sets"] = len(self._qflat_cache)
-                if victim in self._q16_prewarmed:
-                    # a persisted set the live workload never asked for
-                    # is stale (org key rotation, channel churn): drop
-                    # it from the warm file so the next restart skips
-                    # the rebuild
-                    self._q16_prewarmed.discard(victim)
-                    self._drop_warm_keys(victim)
-            # mark the restore/build in flight (the same marker the
-            # background restore thread uses): a concurrent live miss
-            # for the SAME set rides the 8-bit path instead of paying
-            # a duplicate multi-minute device build
-            self._q16_loading.add(cache_key)
-        # -- slow path, deliberately OUTSIDE the cache lock: disk read
-        #    + H2D, or the multi-minute device build. Other key sets'
-        #    lookups proceed meanwhile.
-        try:
-            preloaded = None
-            if self._warm_keys_dir:
-                # persisted bytes serve BOTH prewarm and live misses:
-                # a set evicted from RAM but still on disk re-enters
-                # via a disk read + H2D instead of the multi-minute
-                # device rebuild. Loaded only now — after the budget
-                # and denial gates — so over-budget sets never touch
-                # the disk.
-                preloaded = self._load_q16_table(cache_key, K)
-            if preloaded is not None:
-                import jax.numpy as jnp
-                q_flat = jnp.asarray(preloaded)
-                if prewarm:
-                    # the restore thread owns this H2D: block HERE (in
-                    # the background) so the table is genuinely
-                    # device-resident before the loading marker clears
-                    import jax
-                    jax.block_until_ready(q_flat)
-                self.stats["q16_disk_loads"] += 1
-            else:
-                if not prewarm:
-                    # record the key set BEFORE the persist threads
-                    # start: their publish step deletes any table file
-                    # whose set is absent from the warm file (the
-                    # reclaim-race guard), so the record must win
-                    self._record_warm_keys(cache_key)
-                q_flat = self._build_q16_table(cache_key, K, qx_k,
-                                               qy_k)
-                self._persist_q16_table(cache_key, q_flat)
-            with self._q16_lock:
-                raced = self._qflat_cache.pop(cache_key, None)
-                if raced is not None:
-                    # another thread restored/built this set while we
-                    # were off the lock: keep the resident table
-                    # (accounting already done), discard ours
-                    q_flat = raced
-                    self._qflat_cache[cache_key] = q_flat
-                    if not prewarm:
-                        self._q16_last_use[cache_key] = now
-                        self._q16_prewarmed.discard(cache_key)
-                        self._q16_denied.pop(cache_key, None)
-                    return q_flat
-                self._qflat_cache[cache_key] = q_flat
-                self._qflat_cache_bytes += q_flat.size * 4
-                if prewarm:
-                    self._q16_prewarmed.add(cache_key)
-                    self._q16_last_use[cache_key] = 0  # cold until live
-                else:
-                    self._q16_last_use[cache_key] = now
-                    self._q16_denied.pop(cache_key, None)
-                    # restore the byte-budget invariant: concurrent
-                    # misses for DIFFERENT sets may both have passed
-                    # the pre-build eviction check — shed cold LRU
-                    # victims now (hot residents stay; a bounded
-                    # transient overshoot beats evicting live tables)
-                    while (self._qflat_cache_bytes >
-                           self._table_cache_bytes
-                           and len(self._qflat_cache) > 1):
-                        victim = next(iter(self._qflat_cache))
-                        if victim == cache_key or (
-                                victim not in self._q16_prewarmed
-                                and now - self._q16_last_use.get(
-                                    victim, 0) < self._HOT_WINDOW):
-                            break
-                        evicted = self._qflat_cache.pop(victim)
-                        self._q16_last_use.pop(victim, None)
-                        self._qflat_cache_bytes -= evicted.size * 4
-                        self.stats["q16_evictions"] += 1
-                        if victim in self._q16_prewarmed:
-                            self._q16_prewarmed.discard(victim)
-                            self._drop_warm_keys(victim)
-                self.stats["q16_cache_bytes"] = self._qflat_cache_bytes
-                self.stats["q16_resident_sets"] = len(self._qflat_cache)
-        finally:
-            with self._q16_lock:
-                self._q16_loading.discard(cache_key)
-        if not prewarm and preloaded is not None:
-            # a disk-restored set is live again: refresh its MRU
-            # position in the warm file (file I/O — outside the lock)
-            self._record_warm_keys(cache_key)
-        return q_flat
-
-    def _build_q16_table(self, cache_key, K, qx_k, qy_k):
-        import jax.numpy as jnp
-        q8 = self._qtab_fn(K)(jnp.asarray(qx_k), jnp.asarray(qy_k))
-        # persist the small 8-bit table too: it is the availability
-        # path a restarted node serves on while this set's 16-bit
-        # bytes stream back to the device
-        self._persist_q8_table(cache_key, q8)
-        q_flat = self._q16_fn(K)(q8, K)
-        self.stats["q16_builds"] += 1
-        return q_flat
-
-    # -- warm-key persistence (restart-to-first-block latency) --
-
-    _WARM_FILE = "warm_keysets.json"
-    _WARM_MAX_SETS = 8
-
-    def _record_warm_keys(self, cache_key) -> None:
-        """Persist the key set (pubkey bytes, canonical order) so the
-        next process's prewarm rebuilds its tables before the first
-        block arrives. Best-effort: failures only log."""
-        if not self._warm_keys_dir:
-            return
-        try:
-            import json
-            os.makedirs(self._warm_keys_dir, exist_ok=True)
-            path = os.path.join(self._warm_keys_dir, self._WARM_FILE)
-            with self._warm_lock:
-                sets = self._load_warm_keys()
-                entry = [kb.hex() for kb in cache_key]
-                if entry in sets:
-                    sets.remove(entry)
-                sets.insert(0, entry)      # MRU first
-                trimmed = sets[self._WARM_MAX_SETS:]
-                del sets[self._WARM_MAX_SETS:]
-                tmp = path + ".tmp"
-                with open(tmp, "w") as f:
-                    json.dump(sets, f)
-                os.replace(tmp, path)
-                for old in trimmed:
-                    # reclaim the displaced set's table bytes
-                    # (~252*K MB); without this a long-lived node
-                    # orphans one file per rotated-out key set
-                    try:
-                        from fabric_tpu.ops import comb
-                        okey = tuple(bytes.fromhex(k) for k in old)
-                        for prefix in ("qtab16", "qtab8"):
-                            tab = self._table_path(okey, prefix)
-                            if os.path.exists(tab):
-                                os.remove(tab)
-                            comb.drop_digest_sidecar(tab)
-                    except Exception:
-                        logger.exception("could not reclaim trimmed "
-                                         "warm table")
-        except Exception:
-            logger.exception("could not persist warm key set")
-
-    def _drop_warm_keys(self, cache_key) -> None:
-        """Remove a stale persisted key set (prewarmed but never used
-        by a live batch before eviction) and its table bytes.
-        Best-effort."""
-        if not self._warm_keys_dir:
-            return
-        try:
-            import json
-            path = os.path.join(self._warm_keys_dir, self._WARM_FILE)
-            with self._warm_lock:
-                sets = self._load_warm_keys()
-                entry = [kb.hex() for kb in cache_key]
-                if entry in sets:
-                    sets.remove(entry)
-                    tmp = path + ".tmp"
-                    with open(tmp, "w") as f:
-                        json.dump(sets, f)
-                    os.replace(tmp, path)
+    def _g16_table(self):
+        """The 16-bit G table the pool's width calls for, or the empty
+        stand-in of the 8-bit width (replicated under a mesh, once)."""
+        if self._g16_rep is None:
+            if self._g16_enabled():
                 from fabric_tpu.ops import comb
-                for prefix in ("qtab16", "qtab8"):
-                    tab = self._table_path(cache_key, prefix)
-                    if os.path.exists(tab):
-                        os.remove(tab)   # reclaim ~252*K MB of disk
-                    comb.drop_digest_sidecar(tab)
-        except Exception:
-            logger.exception("could not drop stale warm key set")
+                g16 = comb.g16_tables()
+            else:
+                import jax.numpy as jnp
 
-    # -- q16 table-byte persistence: the dominant restart cost is the
-    #    multi-minute per-key-set device table build, which the XLA
-    #    code cache cannot carry (it is data). Persist the built table
-    #    (~252 MB x K, tmp+rename) and stream it back at prewarm —
-    #    restart-to-first-validated-block becomes a disk read + H2D
-    #    copy instead of a rebuild. Mirrors the availability intent of
-    #    the reference's on-disk MSP/ledger warm state; there is no
-    #    reference analog because CPU verify has no precompute.
+                from fabric_tpu.ops import limb
+                g16 = jnp.zeros((0, 3, limb.L), dtype=jnp.int32)
+            self._g16_rep = self._replicated(g16)
+        return self._g16_rep
 
-    def _table_path(self, cache_key, prefix: str = "qtab16") -> str:
-        import hashlib
-        h = hashlib.sha256(b"".join(cache_key)).hexdigest()[:32]
+    def _key_slots(self, key_map, key_idx):
+        """Each lane's slot in the pool, every key of `key_map` resident
+        when this returns: (lane slots, pool, g16). The caller holds
+        `_pool_lock` until its dispatches are enqueued. One `tpu.tables`
+        span a batch, one `tpu.table_build` under it a key admitted."""
+        sp = tracing.span("tpu.tables", keys=len(key_map))
+        with sp:
+            slot_of, st = self._slot_of, self.stats
+            slots = np.zeros(len(key_map), dtype=np.int32)
+            missing = []
+            for kb, j in key_map.items():
+                slot = slot_of.get(kb)
+                if slot is None:
+                    missing.append(kb)
+                else:
+                    slot_of.move_to_end(kb)
+                    slots[j] = slot
+            evicted = 0
+            for kb in missing:
+                slots[key_map[kb]], ev = self._admit_key(kb, key_map)
+                evicted += ev
+            st["key_slot_lookups"] += len(key_map)
+            st["key_slot_hits"] += len(key_map) - len(missing)
+            sp.set(hits=len(key_map) - len(missing), built=len(missing),
+                   evicted=evicted, q16=self._g16_enabled())
+            return slots[key_idx], self._pool_array(), self._g16_table()
+
+    def _admit_key(self, kb: bytes, keep=(), cold: bool = False,
+                   slab=None):
+        """Write `kb`'s slab into a free slot or, where none is left,
+        the least recently used one whose key is not in `keep`
+        (`_pool_lock` held). `cold` (a restore of persisted bytes no
+        batch has asked for yet) takes free slots only and queues first
+        for eviction. Returns (slot, keys evicted); (None, 0) where a
+        cold key finds no room."""
+        sp = tracing.span("tpu.table_build")
+        with sp:
+            pool = self._pool_array()
+            source = "disk"
+            if slab is None:
+                slab = self._load_slab(kb)
+            if slab is None:
+                source = "build"
+                slab = self._build_slab(kb)
+            evicted = 0
+            used = set(self._slot_of.values())
+            slot = next((i for i in range(self._key_capacity())
+                         if i not in used), None)
+            if slot is None:
+                if cold:
+                    return None, 0
+                victim = next(k for k in self._slot_of if k not in keep)
+                slot = self._slot_of.pop(victim)
+                self._drop_slab_file(victim)
+                self.stats["key_slot_evictions"] += 1
+                evicted = 1
+            try:
+                import jax
+                self._pool = self._pool_write_fn()(
+                    pool, self._replicated(slab),
+                    np.int32(slot * self._slab_rows()))
+                # the next admission waits for this write: a program's
+                # outputs are allocated when it is enqueued, so the
+                # builds of a wide channel's first block, enqueued one
+                # behind the other, would hold a slab each beside the
+                # pool
+                jax.block_until_ready(self._pool)
+            except BaseException:
+                # the write donates the pool: where it failed after the
+                # runtime took the array, nothing resident is readable
+                # (before that, the slot simply stays free)
+                if pool.is_deleted():
+                    self._drop_pool()
+                raise
+            self._slot_of[kb] = slot
+            if cold:
+                self._slot_of.move_to_end(kb, last=False)
+            if source == "build":
+                self.stats["key_slot_builds"] += 1
+                self._persist_slab(kb, slab)
+            else:
+                self.stats["key_slot_disk_loads"] += 1
+            self.stats["key_slots_resident"] = len(self._slot_of)
+            sp.set(slot=slot, bytes=self._slab_bytes(), source=source)
+            return slot, evicted
+
+    def _build_slab(self, kb: bytes):
+        """One key's comb table at the pool's width, on the device."""
+        import jax.numpy as jnp
+
+        from fabric_tpu.ops import limb
+        qk = np.frombuffer(kb, dtype=np.uint8).reshape(1, 64)
+        slab = self._qtab_fn()(
+            jnp.asarray(limb.be_bytes_to_limbs(qk[:, :32])),
+            jnp.asarray(limb.be_bytes_to_limbs(qk[:, 32:])))
+        if self._g16_enabled():
+            slab = self._q16_fn()(slab)
+        return slab
+
+    # -- slab persistence (BCCSP.TPU.WarmKeysDir): the directory mirrors
+    #    the pool, one file a resident key (`slab<width>_<key hex>.npy`,
+    #    tmp + rename, a sha256 sidecar beside it), written in the
+    #    background when a slab is built and removed when its key is
+    #    evicted. A restarted node reads them back into slots (prewarm's
+    #    restore thread, and any miss that gets there first) instead of
+    #    building: the compile cache carries code, not data. A file
+    #    that fails its sidecar or its shape is rebuilt, never combed
+    #    against.
+
+    def _slab_prefix(self) -> str:
+        return f"slab{16 if self._g16_enabled() else 8}_"
+
+    def _slab_path(self, kb: bytes) -> str:
         return os.path.join(self._warm_keys_dir,
-                            f"{prefix}_{h}.npy")
+                            f"{self._slab_prefix()}{kb.hex()}.npy")
 
-    def _q8_est_bytes(self, K: int) -> int:
-        from fabric_tpu.ops import comb, limb
-        return comb.NWIN * K * comb.NENT * 3 * limb.L * 4
-
-    def _persist_table(self, cache_key, q_flat, prefix: str) -> None:
-        """Write built table bytes in a background thread (the serving
-        path must not block on a transfer + write)."""
+    def _drop_slab_file(self, kb: bytes) -> None:
         if not self._warm_keys_dir:
             return
+        from fabric_tpu.ops import comb
+        try:
+            with self._warm_lock:
+                path = self._slab_path(kb)
+                if os.path.exists(path):
+                    os.remove(path)
+                comb.drop_digest_sidecar(path)
+        except Exception:
+            logger.exception("could not remove an evicted key's "
+                             "persisted table")
+
+    def _persist_slab(self, kb: bytes, slab) -> None:
+        """Write a built slab's bytes in a background thread: the
+        serving path never waits for the disk, and for a copy to the
+        host only where two slabs already wait for theirs."""
+        if not self._warm_keys_dir:
+            return
+        # no more than two slabs wait for their copy to the host: the
+        # builds of a wide channel's first block come one behind the
+        # other, and each would leave its slab on the device beside the
+        # pool until a writer got to it. After the copy the device
+        # buffer is free and the bytes (252 MB a key) wait for the disk
+        # in host memory.
+        self._persist_slots.acquire()
+        box = [slab]
 
         def work():
             try:
-                faults.check("tpu.table_persist")
+                try:
+                    faults.check("tpu.table_persist")
+                    arr = np.asarray(box.pop())
+                finally:
+                    self._persist_slots.release()
                 from fabric_tpu.ops import comb
-                arr = np.asarray(q_flat)
                 os.makedirs(self._warm_keys_dir, exist_ok=True)
-                path = self._table_path(cache_key, prefix)
+                path = self._slab_path(kb)
                 tmp = path + ".tmp"
                 with open(tmp, "wb") as f:
                     np.save(f, arr)
                     f.flush()
                     os.fsync(f.fileno())
-                # integrity: a sha256 sidecar rides with the bytes so
-                # a load can detect rot/truncation and rebuild instead
-                # of combing against corrupt points
                 digest = comb.file_sha256(tmp)
-                # publish under the warm lock: a concurrent drop/trim
-                # either sees the file (and deletes it) or has already
-                # removed the owning entry (and we delete our own
-                # write) — a reclaimed file can never be resurrected
+                # publish under the warm lock: an eviction either sees
+                # the file (and deletes it) or has already dropped the
+                # key (and we delete our own write) — a reclaimed file
+                # can never be resurrected
                 with self._warm_lock:
                     os.replace(tmp, path)
-                    entry = [kb.hex() for kb in cache_key]
-                    if entry not in self._load_warm_keys():
-                        os.remove(path)
-                        comb.drop_digest_sidecar(path)
-                    else:
+                    # ftpu-check: allow-lockset(one dict lookup; taking
+                    # _pool_lock under _warm_lock would invert the
+                    # eviction's order)
+                    if kb in self._slot_of:
                         comb.write_digest_sidecar(path, digest)
+                    else:
+                        os.remove(path)
             except Exception:
                 # surfaced as bccsp_warm_table_persist_failures: a node
-                # silently losing its warm bytes pays the multi-minute
-                # rebuild on every restart, which operators must SEE
+                # silently losing its warm bytes rebuilds every slab on
+                # every restart, which operators must SEE
                 self.stats["warm_table_persist_failures"] += 1
-                logger.exception("could not persist %s table bytes",
-                                 prefix)
+                logger.exception("could not persist a key's table bytes")
 
         t = threading.Thread(target=work, daemon=True,
-                             name=f"{prefix}-table-persist")
+                             name="key-table-persist")
         self._persist_threads.append(t)
         t.start()
-
-    def _persist_q16_table(self, cache_key, q_flat) -> None:
-        self._persist_table(cache_key, q_flat, "qtab16")
-
-    def _persist_q8_table(self, cache_key, q8) -> None:
-        # ~2 MB per key slot: makes the 8-bit availability path (the
-        # one serving blocks while the big q16 table streams in)
-        # restorable in roughly a second
-        self._persist_table(cache_key, q8, "qtab8")
 
     def flush_warm_tables(self, timeout: float = 120.0) -> None:
         """Join outstanding table-persist writers and the background
@@ -2382,219 +2189,73 @@ class TPUProvider(api.BCCSP):
                 len(stuck), timeout)
         self._persist_threads = stuck
 
-    def _load_table(self, cache_key, want_bytes: int, prefix: str):
-        from fabric_tpu.ops import comb
+    def _load_slab(self, kb: bytes):
+        """`kb`'s persisted slab as a host array, or None (no
+        WarmKeysDir, no file, or one that fails its checks)."""
         if not self._warm_keys_dir:
             return None
-        path = self._table_path(cache_key, prefix)
+        from fabric_tpu.ops import comb
+        path = self._slab_path(kb)
         try:
             if comb.verify_digest_sidecar(path) is False:
                 logger.warning(
-                    "persisted %s table %s fails its sha256 sidecar "
-                    "(disk corruption?); rebuilding", prefix, path)
+                    "persisted key table %s fails its sha256 sidecar "
+                    "(disk corruption?); rebuilding", path)
                 return None
             arr = np.load(path)
         except FileNotFoundError:
             return None
         except Exception:
-            logger.exception("unreadable persisted %s table; "
-                             "rebuilding", prefix)
+            logger.exception("unreadable persisted key table; "
+                             "rebuilding")
             return None
-        if arr.dtype != np.int32 or arr.nbytes != want_bytes:
+        if arr.dtype != np.int32 or arr.shape != self._slab_shape():
             logger.warning(
-                "persisted %s table %s is %d bytes (%s), want %d; "
-                "rebuilding", prefix, path, arr.nbytes, arr.dtype,
-                want_bytes)
+                "persisted key table %s is %s %s, want int32 %s; "
+                "rebuilding", path, arr.dtype, arr.shape,
+                self._slab_shape())
             return None
         return arr
 
-    def _load_q16_table(self, cache_key, K):
-        return self._load_table(cache_key, self._q16_est_bytes(K),
-                                "qtab16")
-
-    def _load_q8_table(self, cache_key, K):
-        return self._load_table(cache_key, self._q8_est_bytes(K),
-                                "qtab8")
-
-    def _load_warm_keys(self) -> list:
+    def _restore_slabs(self) -> int:
+        """Read WarmKeysDir's slabs back into free slots, newest first
+        (prewarm's background thread). The disk read runs off the pool
+        lock; a batch that misses a key meanwhile admits it itself, as
+        any miss, and the restore then finds it resident. Returns the
+        keys restored."""
         if not self._warm_keys_dir:
-            return []
-        import json
-        path = os.path.join(self._warm_keys_dir, self._WARM_FILE)
+            return 0
+        prefix = self._slab_prefix()
         try:
-            with open(path) as f:
-                sets = json.load(f)
-            return [s for s in sets
-                    if isinstance(s, list) and
-                    all(isinstance(k, str) and len(k) == 128
-                        for k in s)]
-        except FileNotFoundError:
-            return []
-        except Exception:
-            logger.exception("unreadable warm key sets; ignoring")
-            return []
-
-    def _prewarm_tables(self) -> int:
-        """Restore the Q tables for persisted key sets, MRU-first,
-        until the byte budget is full, from persisted table BYTES only
-        (no device rebuilds at startup: a live miss builds on demand).
-        Runs in prewarm()'s background restore thread on a node; each
-        set carries a `_q16_loading` marker so concurrent live batches
-        ride the 8-bit path instead of blocking on the GB-scale disk
-        read + H2D. Returns sets warmed."""
-        from fabric_tpu.ops import limb
-        sets = self._load_warm_keys()      # MRU first
-        candidates = []
-        with self._q16_lock:
-            for entry in sets:
-                order = [bytes.fromhex(k) for k in entry]
-                cache_key = tuple(order)
-                if os.path.exists(self._table_path(cache_key)):
-                    candidates.append((cache_key, order))
-                    self._q16_loading.add(cache_key)
-        warmed = 0
-        try:
-            for cache_key, order in candidates:
-                try:
-                    K = 1
-                    while K < len(order):
-                        K *= 2
-                    qk = np.zeros((K, 64), dtype=np.uint8)
-                    for i, kb in enumerate(order):
-                        qk[i] = np.frombuffer(kb, dtype=np.uint8)
-                    got = self._q16_cached(
-                        cache_key, K,
-                        limb.be_bytes_to_limbs(qk[:, :32]),
-                        limb.be_bytes_to_limbs(qk[:, 32:]),
-                        prewarm=True)
-                    if got is not None:
-                        warmed += 1
-                    elif self._qflat_cache_bytes and \
-                            self._q16_est_bytes(K) + \
-                            self._qflat_cache_bytes > \
-                            self._table_cache_bytes:
-                        # budget full: older sets stay on disk for
-                        # live misses to stream in
-                        break
-                except Exception:
-                    self.stats["warm_restore_failures"] += 1
-                    logger.exception("warm table restore failed for "
-                                     "one set")
-                finally:
-                    # _q16_lock: the marker set is read (`in`) and
-                    # cleared by live verifiers under the cache lock
-                    with self._q16_lock:
-                        self._q16_loading.discard(cache_key)
-        finally:
-            with self._q16_lock:
-                for cache_key, _ in candidates:
-                    self._q16_loading.discard(cache_key)
-        if warmed:
-            logger.info("prewarmed Q tables for %d persisted key "
-                        "set(s) from persisted bytes", warmed)
-        return warmed
-
-    def _resolve_tables(self, key_map, key_idx):
-        """Canonical slot order + per-key-set tables (q16 when cached/
-        buildable under budget, else the 8-bit LRU cache). Returns
-        (key_idx remapped, K, q_flat, g16, q16?). Under a mesh the
-        table arrays come back replicated (stored back, so repeat
-        dispatches short-circuit the device_put)."""
-        sp = tracing.span("tpu.tables", keys=len(key_map))
-        with sp:
-            out = self._resolve_tables_traced(key_map, key_idx)
-            sp.set(q16=out[4])
-        return out
-
-    def _resolve_tables_traced(self, key_map, key_idx):
-        import jax.numpy as jnp
-
-        from fabric_tpu.ops import limb
-
-        order, key_idx = self._canonical_key_order(key_map, key_idx)
-        K = 1
-        while K < len(order):
-            K *= 2
-        qk = np.zeros((K, 64), dtype=np.uint8)
-        for i, kb in enumerate(order):
-            qk[i] = np.frombuffer(kb, dtype=np.uint8)
-        qx_k = limb.be_bytes_to_limbs(qk[:, :32])
-        qy_k = limb.be_bytes_to_limbs(qk[:, 32:])
-
-        def q8_cached():
-            with self._q16_lock:
-                q8 = self._q8_cache.pop(tuple(order), None)
-                if q8 is not None:
-                    self._q8_cache[tuple(order)] = q8   # MRU refresh
-                    return q8
-            pre = self._load_q8_table(tuple(order), K)
-            if pre is not None:
-                q8 = jnp.asarray(pre)
-                self.stats["q8_disk_loads"] += 1
-                if not self._g16_enabled():
-                    self._record_warm_keys(tuple(order))  # MRU refresh
-            else:
-                q8 = self._qtab_fn(K)(jnp.asarray(qx_k),
-                                      jnp.asarray(qy_k))
-                if not self._g16_enabled():
-                    # pure-q8 deployments (UseG16: false): the q8 file
-                    # IS the warm state. Record the key set BEFORE the
-                    # persist thread's publish step consults the warm
-                    # file, or it deletes the file it just wrote and
-                    # q8_disk_loads stays 0 forever across restarts.
-                    self._record_warm_keys(tuple(order))
-                    self._persist_q8_table(tuple(order), q8)
-                elif [kb.hex() for kb in order] in \
-                        self._load_warm_keys():
-                    # g16 path: only recorded sets (q16-resident, mid-
-                    # restore) keep a restorable q8 availability copy;
-                    # persisting an unrecorded (q16-denied) set would
-                    # just write bytes the publish guard deletes
-                    self._persist_q8_table(tuple(order), q8)
-            with self._q16_lock:
-                self._q8_cache[tuple(order)] = q8   # (re-)insert as MRU
-                while len(self._q8_cache) > self._Q8_CACHE_MAX:
-                    self._q8_cache.pop(next(iter(self._q8_cache)))
-            return q8
-
-        q16 = False
-        if self._g16_enabled():
-            from fabric_tpu.ops import comb
-            q_flat = self._q16_cached(tuple(order), K, qx_k, qy_k)
-            if q_flat is not None:
-                q16 = True
-                g16 = comb.g16_tables()
-            else:
-                # 8-bit fallback (adaptive overflow / restore pending):
-                # pure 8/8 pipeline — independent of the g16 build, so
-                # a restarting node validates immediately
-                q_flat = q8_cached()
-                g16 = jnp.zeros((0, 3, limb.L), dtype=jnp.int32)
-        else:
-            q_flat = q8_cached()
-            g16 = jnp.zeros((0, 3, limb.L), dtype=jnp.int32)
-
-        if self._mesh is not None:
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            rep = NamedSharding(self._mesh, P())
-            q_flat = jax.device_put(q_flat, rep)
-            with self._q16_lock:
-                if q16 and tuple(order) in self._qflat_cache:
-                    self._qflat_cache[tuple(order)] = q_flat
-                elif not q16 and tuple(order) in self._q8_cache:
-                    # keep the REPLICATED copy so repeat dispatches
-                    # short-circuit the broadcast
-                    self._q8_cache[tuple(order)] = q_flat
-            if getattr(g16, "size", 0):
-                cached = getattr(self, "_g16_rep", None)
-                if cached is None:
-                    cached = jax.device_put(g16, rep)
-                    self._g16_rep = cached
-                g16 = cached
-            else:
-                g16 = jax.device_put(g16, rep)
-        return key_idx, K, q_flat, g16, q16
+            names = [n for n in os.listdir(self._warm_keys_dir)
+                     if n.startswith(prefix) and n.endswith(".npy")]
+        except OSError:
+            return 0
+        names.sort(key=lambda n: os.path.getmtime(
+            os.path.join(self._warm_keys_dir, n)), reverse=True)
+        restored = 0
+        for name in names:
+            try:
+                kb = bytes.fromhex(name[len(prefix):-4])
+                if len(kb) != 64 or kb in self._slot_of:
+                    continue
+                if self._key_capacity() <= len(self._slot_of):
+                    break       # older keys stay on disk for a miss
+                slab = self._load_slab(kb)
+                if slab is None:
+                    continue
+                with self._pool_lock:
+                    if kb not in self._slot_of and self._admit_key(
+                            kb, cold=True, slab=slab)[0] is not None:
+                        restored += 1
+            except Exception:
+                self.stats["warm_restore_failures"] += 1
+                logger.exception("restoring one persisted key table "
+                                 "failed")
+        if restored:
+            logger.info("restored %d key table(s) from persisted bytes",
+                        restored)
+        return restored
 
     @hot_path
     @tracing.traced("tpu.shard_put")
@@ -2756,27 +2417,31 @@ class TPUProvider(api.BCCSP):
         prepared-block fast path."""
         lockcheck.note_blocking("tpu.dispatch")
         faults.check("tpu.dispatch")
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
-                                                            key_idx)
         # above the span this is the overlapped item path's span
         # shape: one compiled program serves both paths
         chunk = self._mesh_chunk(bucket)
-        fn = self._comb_pipeline_digest(K, q16)
-        return self._dispatch_chunks(
-            bucket, chunk,
-            (key_idx, r8, rpn8, w8, premask, digests),
-            lambda c: fn(c[0], q_flat, g16, *c[1:]), async_out)
+        # the pool lock from the slot lookup to the last enqueue: a
+        # concurrent batch's table write donates the array in hand
+        with self._pool_lock:
+            key_idx, table, g16 = self._key_slots(key_map, key_idx)
+            fn = self._comb_pipeline_digest()
+            thunk = self._dispatch_chunks(
+                bucket, chunk,
+                (key_idx, r8, rpn8, w8, premask, digests),
+                lambda c: fn(c[0], table, g16, *c[1:]))
+        return thunk if async_out else thunk()
 
     @hot_path
-    def _dispatch_chunks(self, bucket, chunk, operands, run, async_out):
+    def _dispatch_chunks(self, bucket, chunk, operands, run):
         """The transfer-ahead double buffer of the prepared-block
         dispatches: chunk k+1's async device_put is enqueued BEFORE
         chunk k's dispatch, so the H2D copy rides under device
         execution instead of serializing with it (host prep already
         happened in native/blockprep.cpp). `run(staged)` enqueues the
         program on one chunk's staged operands. One `tpu.h2d` and one
-        `tpu.enqueue` span a chunk; the thunk holds `tpu.wait` (the
-        host blocked on the device, nothing else) and `tpu.readback`.
+        `tpu.enqueue` span a chunk; the thunk returned holds `tpu.wait`
+        (the host blocked on the device, nothing else) and
+        `tpu.readback`.
         The spans' own clock readings feed the prepared_* gauges."""
         import jax
 
@@ -2838,99 +2503,108 @@ class TPUProvider(api.BCCSP):
             self.stats["prepared_device_s"] = round(
                 dispatch_s + readback.t1 - wait.t0, 6)
             return out
-        return thunk if async_out else thunk()
+        return thunk
 
     @hot_path
     @tracing.traced("tpu.comb")
     def _dispatch_comb(self, bucket, key_map, key_idx, blocks, nblocks,
                        r_l, rpn_l, w_l, premask, digests, has_digest,
                        async_out=False):
-        """Comb-method path: per-key tables built once, then the batch is
-        dispatched in chunks so host staging of chunk k+1 overlaps device
-        execution of chunk k (jax dispatch is async)."""
+        """Comb-method path: every key's table resident in the pool,
+        then the batch is dispatched in chunks so host staging of chunk
+        k+1 overlaps device execution of chunk k (jax dispatch is
+        async)."""
         import jax.numpy as jnp
 
-        key_idx, K, q_flat, g16, q16 = self._resolve_tables(key_map,
-                                                            key_idx)
         chunk = self._mesh_chunk(bucket)
-        fn = self._comb_pipeline(K, q16)
         outs = []
         stage = ((lambda a: a) if self._mesh is not None
                  else jnp.asarray)   # uncommitted under a mesh: the
         #                              shard_map jit deals lanes out
-        for lo in range(0, bucket, chunk):
-            hi = lo + chunk
-            outs.append(fn(
-                stage(blocks[lo:hi]), stage(nblocks[lo:hi]),
-                stage(key_idx[lo:hi]), q_flat, g16,
-                stage(r_l[lo:hi]), stage(rpn_l[lo:hi]),
-                stage(w_l[lo:hi]), stage(premask[lo:hi]),
-                stage(digests[lo:hi]),
-                stage(has_digest[lo:hi])))
+        with self._pool_lock:       # as `_dispatch_comb_digest` holds it
+            key_idx, table, g16 = self._key_slots(key_map, key_idx)
+            fn = self._comb_pipeline()
+            for lo in range(0, bucket, chunk):
+                hi = lo + chunk
+                outs.append(fn(
+                    stage(blocks[lo:hi]), stage(nblocks[lo:hi]),
+                    stage(key_idx[lo:hi]), table, g16,
+                    stage(r_l[lo:hi]), stage(rpn_l[lo:hi]),
+                    stage(w_l[lo:hi]), stage(premask[lo:hi]),
+                    stage(digests[lo:hi]),
+                    stage(has_digest[lo:hi])))
         thunk = lambda: np.concatenate(  # noqa: E731
             # ftpu-lint: allow-host-sync(deliberate materialization)
             [np.asarray(o) for o in outs])
         return thunk if async_out else thunk()
 
-    def _qtab_fn(self, K: int):
+    def _qtab_fn(self):
+        """The 8-bit table builder at one key: a slab of the 8-bit
+        pool, the input of `_q16_fn` for the 16-bit one."""
         with self._jit_lock:
-            if K not in self._qtab_fns:
+            if "qtab" not in self._qtab_fns:
                 from fabric_tpu.ops import comb
-                self._qtab_fns[K] = self._jit(
-                    "qtab", comb.build_q_tables, {"K": K})
-            return self._qtab_fns[K]
+                self._qtab_fns["qtab"] = self._jit(
+                    "qtab", comb.build_q_tables)
+            return self._qtab_fns["qtab"]
 
-    def _q16_fn(self, K: int):
-        key = ("q16", K)
+    def _q16_fn(self):
         with self._jit_lock:
-            if key not in self._qtab_fns:
+            if "qtab16" not in self._qtab_fns:
                 from fabric_tpu.ops import comb
-                self._qtab_fns[key] = self._jit(
-                    "qtab16", comb.build_q16_tables, {"K": K},
-                    static_argnums=1)
-            return self._qtab_fns[key]
+                self._qtab_fns["qtab16"] = self._jit(
+                    "qtab16", comb.build_q16_tables)
+            return self._qtab_fns["qtab16"]
 
-    def _comb_pipeline(self, K: int, q16: bool = False):
-        key = (K, q16)
+    def _pool_write_fn(self):
+        """slab -> its rows of the pool, in place (the pool is donated:
+        a copy of a 6 GB array would double the peak)."""
         with self._jit_lock:
-            return self._comb_pipeline_locked(key, K, q16)
+            if "pool_write" not in self._comb_fns:
+                def pool_write(pool, slab, row0):
+                    from jax import lax
+                    return lax.dynamic_update_slice_in_dim(
+                        pool, slab, row0, axis=0)
 
-    def _comb_pipeline_locked(self, key, K: int, q16: bool):
-        if key not in self._comb_fns:
-            from fabric_tpu.ops import comb, sha256
+                self._comb_fns["pool_write"] = self._jit(
+                    "pool_write", pool_write, donate_argnums=0)
+            return self._comb_fns["pool_write"]
 
-            # q16=False pipelines run pure 8-bit on BOTH bases: they
-            # serve the adaptive-overflow and restore-pending windows,
-            # and must not block on (or embed) the ~252 MB g16 build
-            use_g16 = self._g16_enabled() and q16
-            params = {"K": K, "q16": q16, "g16": use_g16}
+    def _comb_pipeline(self):
+        """SHA-256 + comb in one program (HashOnHost: false), against
+        the pool at its width."""
+        with self._jit_lock:
+            if "comb" not in self._comb_fns:
+                from fabric_tpu.ops import comb, sha256
 
-            def fused(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
-                      premask, digests, has_digest):
-                import jax.numpy as jnp
-                hashed = sha256.sha256_blocks(blocks, nblocks)
-                words = jnp.where(has_digest[:, None], digests, hashed)
-                return comb.comb_verify_with_tables(
-                    words, key_idx, q_flat, r, rpn, w, premask,
-                    g16=g16 if use_g16 else None, q16=q16)
+                q16 = self._g16_enabled()
 
-            if self._mesh is not None:
-                # shard_map, not GSPMD: as a per-shard program each
-                # chip combs its own batch slice against replicated
-                # tables — no collectives in the main path at all
-                from jax.sharding import PartitionSpec as P
-                s = P("batch")
-                rep = P()
-                self._comb_fns[key] = self._jit(
-                    "comb", jaxenv.shard_map(
+                def fused(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
+                          premask, digests, has_digest):
+                    import jax.numpy as jnp
+                    hashed = sha256.sha256_blocks(blocks, nblocks)
+                    words = jnp.where(has_digest[:, None], digests,
+                                      hashed)
+                    return comb.comb_verify_with_tables(
+                        words, key_idx, q_flat, r, rpn, w, premask,
+                        g16=g16 if q16 else None, q16=q16)
+
+                if self._mesh is not None:
+                    # shard_map, not GSPMD: as a per-shard program each
+                    # chip combs its own batch slice against replicated
+                    # tables — no collectives in the main path at all
+                    from jax.sharding import PartitionSpec as P
+                    s = P("batch")
+                    rep = P()
+                    fused = jaxenv.shard_map(
                         fused, mesh=self._mesh,
                         in_specs=(s, s, s, rep, rep, s, s, s, s, s, s),
-                        out_specs=s), params)
-            else:
-                self._comb_fns[key] = self._jit("comb", fused, params)
-        return self._comb_fns[key]
+                        out_specs=s)
+                self._comb_fns["comb"] = self._jit("comb", fused,
+                                                   {"q16": q16})
+            return self._comb_fns["comb"]
 
-    def _comb_pipeline_digest(self, K: int, q16: bool):
+    def _comb_pipeline_digest(self):
         """Digest-lane-only comb pipeline: no SHA stage, no block
         tensors, and the scalar operands arrive as 32-byte big-endian
         u8 rows converted to limbs ON DEVICE — the transfer-minimal
@@ -2938,18 +2612,14 @@ class TPUProvider(api.BCCSP):
         dispatch (32+96 B/lane instead of ~346 B/lane; the difference
         is H2D bytes per span). The overlapped item path and the
         prepared-block path dispatch this SAME program at the same
-        span shape — one compile per (K, q16) serves both."""
-        key = ("digest", K, q16)
+        span shape, and a lane names its key by its slot in the pool:
+        one compile a lane shape serves every batch, whatever its
+        keys."""
         with self._jit_lock:
-            if key not in self._comb_fns:
+            if "digest" not in self._comb_fns:
                 from fabric_tpu.ops import comb, limb
 
-                # q16=False pipelines run pure 8-bit on BOTH bases:
-                # they serve the adaptive-overflow and restore-pending
-                # windows, and must not block on (or embed) the
-                # ~252 MB g16 build
-                use_g16 = self._g16_enabled() and q16
-                params = {"K": K, "q16": q16, "g16": use_g16}
+                q16 = self._g16_enabled()
 
                 def fused(key_idx, q_flat, g16, r8, rpn8, w8, premask,
                           digests):
@@ -2958,21 +2628,19 @@ class TPUProvider(api.BCCSP):
                     w = limb.be_bytes_to_limbs_jnp(w8)
                     return comb.comb_verify_with_tables(
                         digests, key_idx, q_flat, r, rpn, w, premask,
-                        g16=g16 if use_g16 else None, q16=q16)
+                        g16=g16 if q16 else None, q16=q16)
 
                 if self._mesh is not None:
                     from jax.sharding import PartitionSpec as P
                     s = P("batch")
                     rep = P()
-                    self._comb_fns[key] = self._jit(
-                        "comb_digest", jaxenv.shard_map(
-                            fused, mesh=self._mesh,
-                            in_specs=(s, rep, rep, s, s, s, s, s),
-                            out_specs=s), params)
-                else:
-                    self._comb_fns[key] = self._jit("comb_digest",
-                                                    fused, params)
-            return self._comb_fns[key]
+                    fused = jaxenv.shard_map(
+                        fused, mesh=self._mesh,
+                        in_specs=(s, rep, rep, s, s, s, s, s),
+                        out_specs=s)
+                self._comb_fns["digest"] = self._jit(
+                    "comb_digest", fused, {"q16": q16})
+            return self._comb_fns["digest"]
 
     def _pipeline(self):
         if self._fn is None:
@@ -2995,7 +2663,7 @@ class TPUProvider(api.BCCSP):
                 self._fn = self._jit("ladder", fused)
         return self._fn
 
-    def prewarm(self, buckets=None, key_counts=(4,), msg_nbs=None,
+    def prewarm(self, buckets=None, msg_nbs=None,
                 wait_restore: bool = False,
                 bounded: bool = False) -> None:
         """Make ready what this provider will dispatch (and build the
@@ -3008,25 +2676,21 @@ class TPUProvider(api.BCCSP):
         compiles (or loads from the persistent cache) and writes the
         store. Either way the executable is registered for its shape,
         and the first block's dispatch calls it directly.
-        Persisted Q tables restore in a BACKGROUND thread that
-        outlives this call (wait_restore=True joins it — tests): live
-        batches ride the 8-bit path until each restore lands, so the
-        node validates immediately like a reference peer. Safe to call
-        on any backend; failures only log. `stats["prewarm_done"]`
-        (gauge bccsp_prewarm_done) turns 1 when the compiles are in.
+        Persisted key tables are read back into the pool by a
+        BACKGROUND thread that outlives this call (wait_restore=True
+        joins it — tests): a batch that needs a key before its restore
+        lands admits it itself, as any miss, so the node validates
+        immediately like a reference peer. Safe to call on any
+        backend; failures only log. `stats["prewarm_done"]` (gauge
+        bccsp_prewarm_done) turns 1 when the compiles are in.
 
-        The inventory is what the compiler's seconds allow (each
-        program is minutes on the TPU compiler — tools/chip_compile.py):
-        per key-slot count K in `key_counts` — default 4, the 2-4
-        distinct keys of a two-org channel, plus every persisted key
-        set's K — the two table builders and the digest pipeline at
-        each lane shape a batch of `buckets` signatures dispatches
-        (default: the smallest device bucket, which on a TPU is the
-        one span shape every batch uses — see `_floor`). The pure
-        8-bit variant is compiled only when persisted tables exist,
-        i.e. when there will BE a restore window for it to serve
-        (never with bounded=True), and the SHA+comb programs only with
-        HashOnHost off."""
+        The inventory does not depend on how many keys a channel has:
+        the two table builders at one key, the pool write, and the
+        digest pipeline against the pool at each lane shape a batch of
+        `buckets` signatures dispatches (default: the smallest device
+        bucket, which on a TPU is the one span shape every batch uses
+        — see `_floor`); the SHA+comb programs only with HashOnHost
+        off (never with bounded=True)."""
         import jax  # noqa: F401  (jax.ShapeDtypeStruct below)
         import numpy as _np
 
@@ -3039,8 +2703,8 @@ class TPUProvider(api.BCCSP):
         lane = rep = None
         if self._mesh is not None:
             # a compiled executable takes only what it was compiled
-            # for: say where `_shard_put` and `_resolve_tables` put
-            # the lanes and the tables of a sharded dispatch
+            # for: say where `_shard_put` puts the lanes of a sharded
+            # dispatch, and that the pool and g16 sit on every chip
             from jax.sharding import NamedSharding, PartitionSpec as P
             lane = NamedSharding(self._mesh, P("batch"))
             rep = NamedSharding(self._mesh, P())
@@ -3050,21 +2714,19 @@ class TPUProvider(api.BCCSP):
 
         try:
             q16 = self._g16_enabled()
-            if q16:
-                # the g16 G-table build AND the persisted Q-table
-                # restores run in ONE background thread (g16 first —
-                # any q16 dispatch needs it): H2D of GB-scale tables
-                # must not hold up the node's first blocks, which the
-                # 8-bit path serves meanwhile
+            if q16 or self._warm_keys_dir:
+                # the g16 G-table build AND the persisted key tables'
+                # restore run in ONE background thread (g16 first —
+                # any 16-bit dispatch needs it): H2D of GB-scale
+                # tables must not hold up the compiles below
                 def restore():
-                    comb.g16_tables()
-                    self._prewarm_tables()
+                    if q16:
+                        comb.g16_tables()
+                    self._restore_slabs()
 
                 self._restore_thread = threading.Thread(
                     target=restore, daemon=True, name="qtab-restore")
                 self._restore_thread.start()
-            persisted = {1 << max(0, len(ks) - 1).bit_length()
-                         for ks in self._load_warm_keys()}
             pc = self._pipeline_span()
             if buckets is None:
                 buckets = (self._bucket(1),)
@@ -3072,57 +2734,43 @@ class TPUProvider(api.BCCSP):
             lanes = sorted({pc if pc is not None and b > pc
                             else min(self._bucket(b), self._chunk)
                             for b in buckets})
-            for K in sorted(set(key_counts) | persisted):
-                q8_rows = comb.NWIN * comb.NENT * K
-                q16_rows = comb.NWIN_G16 * comb.NENT_G16 * K
-                g0_sd = sd((0, 3, 20), i32, rep)
-                g16_sd = (sd((comb.NWIN_G16 * comb.NENT_G16, 3, 20), i32,
-                             rep) if q16 else g0_sd)
-                q_sd = sd((q16_rows if q16 else q8_rows, 3, 20), i32, rep)
-                if lanes:
-                    # the table builders run on one device, mesh or not
-                    self._qtab_fn(K).aot(
-                        sd((K, 20), i32), sd((K, 20), i32))
-                    if q16:
-                        self._q16_fn(K).aot(
-                            sd((q8_rows, 3, 20), i32), K)
-                    logger.info("prewarmed table builders K=%d q16=%s",
-                                K, q16)
-                for n in lanes:
-                    def dshapes(q, g):
-                        return (sd((n,), i32, lane), q, g,
-                                sd((n, 32), u8, lane),
-                                sd((n, 32), u8, lane),
-                                sd((n, 32), u8, lane),
-                                sd((n,), bool, lane),
-                                sd((n, 8), _np.uint32, lane))
-
-                    self._comb_pipeline_digest(K, q16).aot(
-                        *dshapes(q_sd, g16_sd))
-                    logger.info("prewarmed digest comb pipeline K=%d "
-                                "lanes=%d q16=%s", K, n, q16)
-                    if q16 and persisted and not bounded:
-                        self._comb_pipeline_digest(K, False).aot(
-                            *dshapes(sd((q8_rows, 3, 20), i32, rep),
-                                     g0_sd))
-                        logger.info("prewarmed digest comb pipeline "
-                                    "K=%d lanes=%d q16=False "
-                                    "(restore-window path)", K, n)
-                    if self._hash_on_host or bounded:
-                        continue      # SHA+comb pipeline not used
-                    fn = self._comb_pipeline(K, q16)
-                    for nb in msg_nbs:
-                        fn.aot(
-                            sd((n, nb, 16), _np.uint32, lane),
-                            sd((n,), i32, lane), sd((n,), i32, lane),
-                            q_sd, g16_sd, sd((n, 20), i32, lane),
-                            sd((n, 20), i32, lane),
-                            sd((n, 20), i32, lane), sd((n,), bool, lane),
-                            sd((n, 8), _np.uint32, lane),
-                            sd((n,), bool, lane))
-                        logger.info("prewarmed comb pipeline K=%d "
-                                    "lanes=%d nb=%d q16=%s", K, n, nb,
-                                    q16)
+            slots = self._key_capacity()
+            slab_sd = sd(self._slab_shape(), i32, rep)
+            pool_sd = sd((slots * self._slab_rows(),)
+                         + self._slab_shape()[1:], i32, rep)
+            g16_sd = sd((comb.NWIN_G16 * comb.NENT_G16 if q16 else 0,
+                         3, 20), i32, rep)
+            if lanes and slots:
+                # the table builders run on one device, mesh or not
+                self._qtab_fn().aot(sd((1, 20), i32), sd((1, 20), i32))
+                if q16:
+                    self._q16_fn().aot(
+                        sd((comb.NWIN * comb.NENT, 3, 20), i32))
+                self._pool_write_fn().aot(pool_sd, slab_sd, sd((), i32))
+                logger.info("prewarmed the table builders and the pool "
+                            "write: %d slots, q16=%s", slots, q16)
+            for n in lanes if slots else ():
+                self._comb_pipeline_digest().aot(
+                    sd((n,), i32, lane), pool_sd, g16_sd,
+                    sd((n, 32), u8, lane), sd((n, 32), u8, lane),
+                    sd((n, 32), u8, lane), sd((n,), bool, lane),
+                    sd((n, 8), _np.uint32, lane))
+                logger.info("prewarmed digest comb pipeline lanes=%d "
+                            "q16=%s", n, q16)
+                if self._hash_on_host or bounded:
+                    continue      # SHA+comb pipeline not used
+                fn = self._comb_pipeline()
+                for nb in msg_nbs:
+                    fn.aot(
+                        sd((n, nb, 16), _np.uint32, lane),
+                        sd((n,), i32, lane), sd((n,), i32, lane),
+                        pool_sd, g16_sd, sd((n, 20), i32, lane),
+                        sd((n, 20), i32, lane),
+                        sd((n, 20), i32, lane), sd((n,), bool, lane),
+                        sd((n, 8), _np.uint32, lane),
+                        sd((n,), bool, lane))
+                    logger.info("prewarmed comb pipeline lanes=%d "
+                                "nb=%d q16=%s", n, nb, q16)
             if wait_restore and self._restore_thread is not None:
                 self._restore_thread.join()
         except Exception:
@@ -3267,8 +2915,8 @@ class TPUProvider(api.BCCSP):
         to the pipeline span (2,048 lanes a device unless
         PipelineChunk says otherwise): every device batch up to the
         span pads to ONE lane shape, and larger ones go span by span
-        (`_bucket`), so a (K, q16) pair costs one pipeline compile
-        whatever the block size. The TPU compiler takes ~2 min per
+        (`_bucket`), so the provider costs one pipeline compile
+        whatever the block size and the number of keys. The TPU compiler takes ~2 min per
         shape (tools/chip_compile.py) — a cliff per new bucket that
         padded, premasked lanes are cheap against. Not free: device
         time grows with the lanes (PERF.md, Findings PR 28), which is

@@ -33,15 +33,17 @@ EPILOGUE = """\
 ## Dynamically-named instruments
 
 - `bccsp_<stat>` — one gauge per `TPUProvider.stats` counter
-  (comb/ladder dispatches, q16 table cache bytes and evictions, sw
-  fallbacks …), published by
+  (comb/ladder dispatches, the key-table pool's lookups, hits,
+  builds, evictions, resident keys and bytes, sw fallbacks …),
+  published by
   `fabric_tpu/common/profiling.py publish_provider_stats`.
 - `trace_stage_seconds{stage=<span>}` — the stage label is the span
   name. The block-intake spans, a fixed number per block and per
   provider call (registry and nesting: ARCHITECTURE.md, "Block-intake
   span tree"): `peer.verify_block`, `peer.block`, `commit.validate`,
   `validate.prep`, `validate.policy`, `validate.flags`, `tpu.verify`,
-  `tpu.stage`, `tpu.comb_digest`, `tpu.tables`, `tpu.h2d`,
+  `tpu.stage`, `tpu.comb_digest`, `tpu.tables`, `tpu.table_build`
+  (one a key admitted to the pool, none in a steady block), `tpu.h2d`,
   `tpu.enqueue`, `tpu.wait`, `tpu.readback`, `intake.rwsets`,
   `intake.txids`, `commit.commit`, `commit.pvt`, `commit.notify`,
   `ledger.mvcc`, `ledger.blockstore`, `blockstore.append`,

@@ -84,7 +84,7 @@ def shard_map(fn, mesh, in_specs, out_specs):
     """`jax.shard_map` for the sharded verify pipeline, with
     replication checking off: every output is batch-sharded, so there
     is no replicated result for it to vouch for, and the tables really
-    are replicated by construction (`TPUProvider._resolve_tables`
+    are replicated by construction (`TPUProvider._replicated`
     places them with an empty PartitionSpec)."""
     import jax
 

@@ -13,9 +13,12 @@ method on BOTH bases:
 
 with 8-bit windows (NWIN = 32 per scalar):
   * T_G[i][j] = j * 2^(8i) * G  — host-precomputed constants (1.9 MB).
-  * T_Q[k][i][j] = j * 2^(8i) * Q_k — built ON DEVICE once per batch with
-    two lax.scans (~500 point ops at width NWIN*K), amortized over every
-    signature that shares the key.
+  * T_Q[k][i][j] = j * 2^(8i) * Q_k — built ON DEVICE once per key with
+    two lax.scans (~500 point ops at width NWIN), amortized over every
+    signature that shares the key. A key's table is one contiguous
+    slab (key-major rows), so tables of many keys are one array that a
+    lane indexes by its key's slot: the provider keeps one resident
+    pool of such slabs (fabric_tpu/bccsp/tpu.py).
   * Per signature: 64 gathered points, tree-reduced with 6 vectorized
     complete-add levels (63 adds) — and ZERO doublings, vs the generic
     Shamir ladder's 256 doublings + 128 adds (fabric_tpu/ops/p256.py
@@ -23,8 +26,8 @@ with 8-bit windows (NWIN = 32 per scalar):
 
 Everything is branchless/fixed-shape; window j=0 gathers the point at
 infinity and the complete addition law absorbs it, so zero scalars and
-padded lanes need no special casing. Batches with many distinct keys fall
-back to the generic ladder in the provider (fabric_tpu/bccsp/tpu.py).
+padded lanes need no special casing. A batch with more distinct keys than
+the provider's pool has slots falls back to the generic ladder there.
 """
 
 from __future__ import annotations
@@ -190,26 +193,27 @@ def g16_tables():
         g8 = jnp.asarray(g_tables())        # (32*256, 3, L)
 
         _g16_cache.append(jax.jit(_combine_windows)(
-            g8, jnp.arange(NWIN_G16, dtype=jnp.int32) * 2 * NENT,
-            NENT))
+            g8, jnp.arange(NWIN_G16, dtype=jnp.int32) * 2 * NENT))
         return _g16_cache[0]
 
 
-def _combine_windows(t8, base, stride: int):
+def _combine_windows(t8, base):
     """Pairwise 8-bit -> 16-bit window combining, one table row-block
     per scan step: out[b*65536 + j] = t8[base[b] + (j & 255)]
-                                     + t8[base[b] + stride + (j >> 8)].
+                                     + t8[base[b] + NENT + (j >> 8)]
+    (a table's windows 2i and 2i+1 are neighbours, G's and a key's
+    alike).
 
     A `lax.map` over the blocks, NOT a Python loop: the program holds
     ONE complete-add body whatever the block count. Unrolled, the
-    TPU compiler took minutes per table (16 bodies for G, 16*K for a
-    key set; tools/chip_compile.py) for a program that runs once."""
+    TPU compiler took minutes per table (16 bodies a table;
+    tools/chip_compile.py) for a program that runs once."""
     idx = jnp.arange(NENT_G16, dtype=jnp.int32)
     lo, hi = idx & 255, idx >> 8
 
     def block(b0):
         a = jnp.take(t8, b0 + lo, axis=0)
-        b = jnp.take(t8, b0 + stride + hi, axis=0)
+        b = jnp.take(t8, b0 + NENT + hi, axis=0)
         X, Y, Z = cadd((a[:, 0], a[:, 1], a[:, 2]),
                        (b[:, 0], b[:, 1], b[:, 2]))
         return jnp.stack([X, Y, Z], axis=1)
@@ -222,26 +226,27 @@ def _combine_windows(t8, base, stride: int):
 # Q-side tables (device, per distinct key)
 # ---------------------------------------------------------------------------
 
-def build_q16_tables(q_flat, K: int):
+def build_q16_tables(q_flat):
     """8-bit Q tables -> 16-bit Q tables by pairwise window combining:
-    T16_{i,k}[j] = T8_{2i,k}[j & 255] + T8_{2i+1,k}[j >> 8].
+    T16_{k,i}[j] = T8_{k,2i}[j & 255] + T8_{k,2i+1}[j >> 8].
 
-    ~1M*K point adds as ONE vectorized complete add — expensive per
-    call (and ~252*K MB resident), so callers cache the result per key
-    set: a validating peer sees the same org keys on every block, which
-    makes this a once-per-channel-config cost, not a per-block one.
-    Layout: flat16[(i * K + k) * 65536 + j].
+    ~1M point adds a key as ONE vectorized complete add — expensive
+    per call (and ~252 MB a key resident), so the provider builds a
+    key's slab once and keeps it in its pool: a validating peer sees
+    the same org keys on every block.
+    Layout, as `build_q_tables`' (K from the rows): key-major,
+    flat16[(k * NWIN_G16 + i) * 65536 + j].
     """
-    i = jnp.arange(NWIN_G16, dtype=jnp.int32)[:, None]
-    k = jnp.arange(K, dtype=jnp.int32)[None, :]
-    base = (((2 * i) * K + k) * NENT).reshape(-1)   # i-major, k-minor
-    return _combine_windows(q_flat, base, K * NENT)
+    base = jnp.arange(q_flat.shape[0] // (2 * NENT),
+                      dtype=jnp.int32) * 2 * NENT
+    return _combine_windows(q_flat, base)
 
 
 def build_q_tables(qx, qy):
-    """(K, L) affine key coords -> (NWIN * K * NENT, 3, L) projective table.
+    """(K, L) affine key coords -> (K * NWIN * NENT, 3, L) projective table.
 
-    flat[(i * K + k) * NENT + j] = j * 2^(8i) * Q_k.  Two scans:
+    flat[(k * NWIN + i) * NENT + j] = j * 2^(8i) * Q_k: a key's table is
+    one contiguous slab.  Two scans:
       1. window bases b_i = 2^(8i) * Q (31 steps of 8 doublings, width K);
       2. running multiples j*b (NENT-2 adds, width NWIN*K).
     Entries are semi-reduced projective coordinates — gathers copy bits,
@@ -278,10 +283,10 @@ def build_q_tables(qx, qy):
     for c in range(3):
         ent = jnp.concatenate(
             [inf[c][None], bases[c][None], multiples[c]], axis=0)
-        flat.append(jnp.transpose(ent, (1, 2, 0, 3)))   # (NWIN, K, NENT, L)
-    # (NWIN*K*NENT, 3, L)
+        flat.append(jnp.transpose(ent, (2, 1, 0, 3)))   # (K, NWIN, NENT, L)
+    # (K*NWIN*NENT, 3, L)
     return jnp.stack(
-        [f.reshape(NWIN * K * NENT, L) for f in flat], axis=1)
+        [f.reshape(K * NWIN * NENT, L) for f in flat], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -324,12 +329,15 @@ def _tree_reduce(X, Y, Z):
     return X[:, 0], Y[:, 0], Z[:, 0]
 
 
-def comb_gather_points(u1, u2, key_idx, g_flat, q_flat, K: int,
+def comb_gather_points(u1, u2, key_idx, g_flat, q_flat,
                        g16=None, q16: bool = False):
     """Gather the per-signature comb points: (B, M, 3, L).
 
     M = (16 or 32 G-side) + (16 or 32 Q-side) depending on window
-    widths. `_tree_reduce` sums them.
+    widths. `_tree_reduce` sums them. `key_idx` is the slot of the
+    lane's key in `q_flat` (key-major: slot * windows + window), so
+    the program does not depend on how many slots `q_flat` holds
+    beyond its shape.
     """
     if g16 is not None:
         w1 = _windows(u1, 16)               # (B, 16)
@@ -342,26 +350,26 @@ def comb_gather_points(u1, u2, key_idx, g_flat, q_flat, K: int,
     if q16:                             # 16-bit Q tables (build_q16_tables)
         w2 = _windows(u2, 16)
         win = jnp.arange(NWIN_G16, dtype=jnp.int32)[None, :]
-        q_idx = (win * K + key_idx[:, None]) * NENT_G16 + w2
+        q_idx = (key_idx[:, None] * NWIN_G16 + win) * NENT_G16 + w2
     else:
         w2 = _windows(u2)
         win = jnp.arange(NWIN, dtype=jnp.int32)[None, :]
-        q_idx = (win * K + key_idx[:, None]) * NENT + w2
+        q_idx = (key_idx[:, None] * NWIN + win) * NENT + w2
     pts_q = jnp.take(q_flat, q_idx, axis=0)
     return jnp.concatenate([pts_g, pts_q], axis=1)
 
 
-def comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat, K: int,
+def comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat,
                            g16=None, q16: bool = False):
     """R = u1*G + u2*Q_{key_idx} for a batch, via two combs.
 
     u1, u2: (B, L) canonical scalars; key_idx: (B,) int32 in [0, K);
-    g_flat: (NWIN*NENT, 3, L); q_flat: (NWIN*K*NENT, 3, L).
+    g_flat: (NWIN*NENT, 3, L); q_flat: (K*NWIN*NENT, 3, L).
     With g16 (the 16-bit G table), the G side contributes 16 points
     instead of 32 — a 48-point tree (25% fewer adds per signature).
     Returns projective (X, Y, Z) each (B, L).
     """
-    pts = comb_gather_points(u1, u2, key_idx, g_flat, q_flat, K,
+    pts = comb_gather_points(u1, u2, key_idx, g_flat, q_flat,
                              g16=g16, q16=q16)
     return _tree_reduce(pts[:, :, 0], pts[:, :, 1], pts[:, :, 2])
 
@@ -371,18 +379,16 @@ def comb_verify_with_tables(digest_words, key_idx, q_flat, r, rpn, w,
     """Batched ECDSA accept/reject against a prebuilt Q-table.
 
     q_flat: from build_q_tables (8-bit windows; q16=False) or
-    build_q16_tables (16-bit; q16=True) — built once per key set and
-    reused across blocks/chunks. g16: optional 16-bit G-window table
-    (g16_tables()); with both 16-bit sides the per-signature tree has
-    32 points.
+    build_q16_tables (16-bit; q16=True), or a pool of such slabs —
+    built once per key and reused across blocks/chunks. g16: optional
+    16-bit G-window table (g16_tables()); with both 16-bit sides the
+    per-signature tree has 32 points.
     """
-    ent = NWIN_G16 * NENT_G16 if q16 else NWIN * NENT
-    K = q_flat.shape[0] // ent
     g_flat = jnp.asarray(g_tables()) if g16 is None else None
     e = limb.words_be_to_limbs(digest_words)
     u1 = FN.canonical(FN.mulmod(e, w))
     u2 = FN.canonical(FN.mulmod(r, w))
-    X, _, Z = comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat, K,
+    X, _, Z = comb_double_scalar_mul(u1, u2, key_idx, g_flat, q_flat,
                                      g16=g16, q16=q16)
     nonzero = jnp.any(FP.canonical(Z) != 0, axis=-1)
     x_canon = FP.canonical(X)

@@ -59,7 +59,7 @@ def shardmap_comb_verify(mesh: Mesh, q16: bool):
     """The flagship comb pipeline as a per-shard program (shard_map).
 
     This is the SAME layout the TPU provider compiles under a mesh
-    (bccsp/tpu.py _comb_pipeline_locked): batch-sharded operand lanes,
+    (bccsp/tpu.py _comb_pipeline): batch-sharded operand lanes,
     replicated tables, no collectives — shard_map rather than GSPMD so
     each chip runs the whole per-shard program on its own lanes and
     the partitioner has nothing to decide. With q16=True the 16-bit

@@ -123,11 +123,13 @@ class TestFactory:
         assert opts.sw.keystore_path == "/tmp/ks"
         assert opts.tpu.min_batch == 8
         # flagship comb knobs default sanely: use_g16 auto (None); the
-        # 6 GiB table budget admits a max_keys=16 q16 table (~4 GiB)
+        # 4,000 MB table budget holds 13 of MaxKeys' 32 slots at 16-bit
+        # windows as the chip lays them out (302 MB a key)
         assert opts.tpu.use_g16 is None
         assert opts.tpu.chunk == 32768
-        assert opts.tpu.max_keys == 16
-        assert opts.tpu.table_cache_bytes == 6 << 30
+        assert opts.tpu.max_keys == 32
+        assert opts.tpu.table_cache_bytes == 4000 << 20
+        assert opts.tpu.table_cache_bytes // (16 * 65536 * 288) == 13
 
     def test_config_parse_comb_knobs(self):
         """UseG16/Chunk/MaxKeys/TableCacheMB reach the provider through
@@ -157,61 +159,55 @@ class TestFactory:
         factory._reset_for_tests()
 
 
-class TestQ16TableCache:
-    """Regression tests for the q16 table cache (round-2 advisor HIGH:
-    cache keyed by sorted keys but slots in first-appearance order —
-    a later batch with a different appearance order combed every
-    signature against the wrong key)."""
+class TestKeyPoolDispatch:
+    """The pool under the real dispatch path (round-2 advisor HIGH,
+    as it reads today: a key's slot must not depend on where the key
+    first appears in a batch — a later batch with another appearance
+    order would comb every signature against the wrong key). Slot
+    bookkeeping alone: tests/test_key_pool.py."""
 
-    @staticmethod
-    def _stubbed_provider(monkeypatch, **kw):
-        """TPUProvider with the heavy table builds and the jitted comb
-        pipeline replaced by recorders, so cache keying/slot-order
-        logic runs the real dispatch path without device math."""
+    ROWS = 8        # a stub slab: the pool is a few hundred bytes
+
+    @classmethod
+    def _stubbed_provider(cls, monkeypatch, **kw):
+        """TPUProvider with the table build and the jitted comb
+        pipeline replaced by recorders, so the slot logic runs the
+        real dispatch path (and the real pool write) without device
+        math. A stub slab holds its key's first limb in every row."""
         import jax.numpy as jnp
 
-        from fabric_tpu.ops import comb, limb
-
         kw.setdefault("min_batch", 1)
-        kw.setdefault("use_g16", True)
+        kw.setdefault("use_g16", False)
         tpu = TPUProvider(**kw)
-        calls = {"q8_builds": [], "pipeline_key_idx": []}
-        monkeypatch.setattr(comb, "g16_tables",
-                            lambda: jnp.zeros((0, 3, limb.L), jnp.int32))
+        calls = {"builds": [], "key_idx": [], "pool": [], "ladder": 0}
 
-        def fake_qtab_fn(K):
+        def fake_qtab_fn():
             def build(qx, qy):
-                calls["q8_builds"].append(np.asarray(qx).copy())
-                return np.zeros((K,))
+                calls["builds"].append(int(np.asarray(qx)[0, 0]))
+                return jnp.full((cls.ROWS, 3, 20), calls["builds"][-1],
+                                dtype=jnp.int32)
             return build
 
-        def fake_q16_fn(K):
-            return lambda q8, K_: FakeTable(10)
-
-        class FakeTable:
-            def __init__(self, n):
-                self.size = n
-
-        def fake_pipeline_digest(K, q16=False):
+        def fake_pipeline_digest():
             def run(key_idx, q_flat, g16, r8, rpn8, w8, premask,
                     digests):
-                calls["pipeline_key_idx"].append(
-                    np.asarray(key_idx).copy())
+                calls["key_idx"].append(np.asarray(key_idx).copy())
+                calls["pool"].append(np.asarray(q_flat).copy())
                 return np.asarray(premask)
             return run
 
-        def fake_pipeline(K, q16=False):
-            def run(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
-                    premask, digests, has_digest):
-                calls["pipeline_key_idx"].append(np.asarray(key_idx).copy())
+        def fake_ladder():
+            def run(blocks, nblocks, qx, qy, r, rpn, w, premask,
+                    digests, has_digest):
+                calls["ladder"] += 1
                 return np.asarray(premask)
             return run
 
+        monkeypatch.setattr(tpu, "_slab_rows", lambda: cls.ROWS)
         monkeypatch.setattr(tpu, "_qtab_fn", fake_qtab_fn)
-        monkeypatch.setattr(tpu, "_q16_fn", fake_q16_fn)
-        monkeypatch.setattr(tpu, "_comb_pipeline", fake_pipeline)
         monkeypatch.setattr(tpu, "_comb_pipeline_digest",
                             fake_pipeline_digest)
+        monkeypatch.setattr(tpu, "_pipeline", fake_ladder)
         return tpu, calls
 
     @staticmethod
@@ -227,71 +223,94 @@ class TestQ16TableCache:
                                   message=m))
         return out
 
-    def test_canonical_key_order_pure(self):
-        key_map = {b"bbb": 0, b"aaa": 1, b"ccc": 2}
-        key_idx = np.array([0, 1, 2, 0], dtype=np.int32)
-        order, remapped = TPUProvider._canonical_key_order(key_map, key_idx)
-        assert order == [b"aaa", b"bbb", b"ccc"]
-        assert remapped.tolist() == [1, 0, 2, 1]
+    @staticmethod
+    def _marker(key) -> int:
+        """What the stub builder writes into `key`'s slab."""
+        from fabric_tpu.ops import limb
+        return int(limb.be_bytes_to_limbs(np.asarray(
+            key.public_key().x_bytes(), np.uint8).reshape(1, 32))[0, 0])
 
-    def test_cache_hit_with_different_appearance_order(self, monkeypatch):
+    def test_a_key_keeps_its_slot_whatever_the_appearance_order(
+            self, monkeypatch):
         keys = [SWProvider().key_gen(ECDSAKeyGenOpts(ephemeral=True))
                 for _ in range(2)]
         tpu, calls = self._stubbed_provider(monkeypatch)
-        # appearance order key0-first, then key1-first: same key SET
+        # appearance order key0-first, then key1-first: the same keys
         tpu.verify_batch(self._items(keys, [0, 1, 0, 1]))
         tpu.verify_batch(self._items(keys, [1, 0, 1, 0]))
-        # one cache entry, one build — the second batch HIT the cache
-        assert len(tpu._qflat_cache) == 1
-        assert len(calls["q8_builds"]) == 1
-        assert tpu.stats["q16_builds"] == 1
-        # and the key_idx sent to the kernel is canonical in BOTH
-        # batches: same key must get the same slot regardless of
-        # appearance order
-        ki1, ki2 = calls["pipeline_key_idx"]
+        # two slabs, built once each — the second batch HIT both
+        assert len(calls["builds"]) == 2
+        assert tpu.stats["key_slot_builds"] == 2
+        assert tpu.stats["key_slot_hits"] == 2
+        assert tpu.stats["key_slots_resident"] == 2
+        ki1, ki2 = calls["key_idx"]
         slot = {0: ki1[0], 1: ki1[1]}          # batch-1 slot per key
+        assert slot[0] != slot[1]
         assert ki1.tolist()[:4] == [slot[0], slot[1], slot[0], slot[1]]
         assert ki2.tolist()[:4] == [slot[1], slot[0], slot[1], slot[0]]
 
-    def test_lru_eviction_by_bytes(self, monkeypatch):
+    def test_every_lane_indexes_its_own_keys_slab(self, monkeypatch):
+        """What the kernel is handed: the rows a lane's slot selects
+        in the pool are the rows built for that lane's key."""
+        keys = [SWProvider().key_gen(ECDSAKeyGenOpts(ephemeral=True))
+                for _ in range(5)]
+        tpu, calls = self._stubbed_provider(monkeypatch)
+        order = [3, 0, 4, 1, 2, 0, 3]
+        tpu.verify_batch(self._items(keys, order))
+        kidx, pool = calls["key_idx"][0], calls["pool"][0]
+        assert pool.shape[0] == tpu._key_capacity() * self.ROWS
+        for lane, ki in enumerate(order):
+            rows = pool[kidx[lane] * self.ROWS:
+                        (kidx[lane] + 1) * self.ROWS]
+            assert (rows == self._marker(keys[ki])).all()
+
+    def test_lru_eviction_by_key(self, monkeypatch):
         keys = [SWProvider().key_gen(ECDSAKeyGenOpts(ephemeral=True))
                 for _ in range(3)]
-        tpu, calls = self._stubbed_provider(monkeypatch)
-        # fake tables are 40 bytes each (size 10 * 4); budget fits two
-        tpu._table_cache_bytes = 100
-        monkeypatch.setattr(tpu, "_q16_est_bytes", lambda K: 40)
-        tpu.verify_batch(self._items(keys, [0, 0]))      # set {0}
-        tpu.verify_batch(self._items(keys, [1, 1]))      # set {1}
-        tpu.verify_batch(self._items(keys, [0, 0]))      # hit {0} -> MRU
-        # round-4 adaptive policy: a newcomer may not evict a victim
-        # still inside the hot window — it rides the 8-bit path instead
-        tpu.verify_batch(self._items(keys, [2, 2]))
-        assert tpu.stats["q16_evictions"] == 0
-        assert tpu.stats["q16_adaptive_skips"] == 1
-        assert len(tpu._qflat_cache) == 2
-        # once the LRU victim has gone cold, the eviction happens and
-        # the newcomer builds its table
-        tpu._q16_batch_no += tpu._HOT_WINDOW
-        tpu._q16_denied.clear()
+        tpu, calls = self._stubbed_provider(monkeypatch, max_keys=2)
+        tpu.verify_batch(self._items(keys, [0, 0]))
+        tpu.verify_batch(self._items(keys, [1, 1]))
+        tpu.verify_batch(self._items(keys, [0, 0]))      # hit -> MRU
+        assert tpu.stats["key_slot_evictions"] == 0
         tpu.verify_batch(self._items(keys, [2, 2]))      # evicts LRU {1}
-        assert tpu.stats["q16_evictions"] == 1
-        assert len(tpu._qflat_cache) == 2
-        tpu._q16_batch_no += tpu._HOT_WINDOW
+        assert tpu.stats["key_slot_evictions"] == 1
+        assert tpu.stats["key_slots_resident"] == 2
+        tpu.verify_batch(self._items(keys, [0, 0]))      # still there
+        assert tpu.stats["key_slot_builds"] == 3
         tpu.verify_batch(self._items(keys, [1, 1]))      # {1} rebuilt
-        assert tpu.stats["q16_builds"] == 4
+        assert tpu.stats["key_slot_builds"] == 4
+        assert tpu.stats["key_slot_evictions"] == 2
+        # the newest batch's lanes read key 1's rows
+        kidx, pool = calls["key_idx"][-1], calls["pool"][-1]
+        rows = pool[kidx[0] * self.ROWS:(kidx[0] + 1) * self.ROWS]
+        assert (rows == self._marker(keys[1])).all()
 
-    def test_oversize_key_set_skips_q16(self, monkeypatch):
+    def test_two_batches_sharing_all_but_one_key_build_one_slab(
+            self, monkeypatch):
+        keys = [SWProvider().key_gen(ECDSAKeyGenOpts(ephemeral=True))
+                for _ in range(25)]
+        tpu, calls = self._stubbed_provider(monkeypatch)
+        tpu.verify_batch(self._items(keys, list(range(24))))
+        before = dict(tpu.stats)
+        tpu.verify_batch(self._items(keys, list(range(24, -1, -1))))
+        moved = _moved(tpu, before)
+        assert moved["key_slot_lookups"] == 25
+        assert moved["key_slot_hits"] == 24
+        assert moved["key_slot_builds"] == 1
+        assert "key_slot_evictions" not in moved
+        assert calls["builds"][-1] == self._marker(keys[24])
+
+    def test_a_budget_under_one_slab_goes_to_the_ladder(self,
+                                                        monkeypatch):
         keys = [SWProvider().key_gen(ECDSAKeyGenOpts(ephemeral=True))
                 for _ in range(2)]
-        tpu, calls = self._stubbed_provider(monkeypatch)
-        tpu._table_cache_bytes = 8   # smaller than any table estimate
-        monkeypatch.setattr(tpu, "_q16_est_bytes", lambda K: 40)
+        tpu, calls = self._stubbed_provider(monkeypatch,
+                                            table_cache_bytes=8)
+        assert tpu._key_capacity() == 0
         out = tpu.verify_batch(self._items(keys, [0, 1]))
         assert out == [True, True]   # stub premask passthrough
-        assert tpu.stats["q16_oversize_skips"] == 1
-        assert not tpu._qflat_cache
-        # q8 tables were built instead (uncached fallback)
-        assert len(calls["q8_builds"]) == 1
+        assert tpu.stats["ladder_batches"] == 1 and calls["ladder"] == 1
+        assert not calls["builds"] and tpu._pool is None
 
 
 def _corpus():
@@ -370,13 +389,17 @@ FALLBACK_COUNTERS = ("sw_fallbacks", "host_hash_fallbacks",
                      "breaker_trips", "compile_failures")
 
 
+POOL_SLOTS = 25     # slots of `lane_provider`'s pool
+
+
 @functools.lru_cache(maxsize=None)
 def _lane_keys():
-    """17 P-256 keys (one more than MaxKeys) and one P-384 key."""
+    """26 P-256 keys (one more than `lane_provider`'s pool has slots:
+    the wide consortium's 25 and one over) and one P-384 key."""
     from fabric_tpu.bccsp.bccsp import ECDSAPrivateKeyImportOpts
     sw = SWProvider()
     p256 = [sw.key_gen(ECDSAKeyGenOpts(ephemeral=True))
-            for _ in range(17)]
+            for _ in range(POOL_SLOTS + 1)]
     p384 = sw.key_import(ec.generate_private_key(ec.SECP384R1()),
                          ECDSAPrivateKeyImportOpts())
     return p256, p384
@@ -492,9 +515,10 @@ def _moved(prov, before):
 
 @pytest.fixture(scope="module")
 def lane_provider():
-    """ONE provider for every lane-kind case: each (K, lanes) program
-    is traced and loaded once for the module."""
-    return TPUProvider(min_batch=4)
+    """ONE provider for every lane-kind case: the program of a lane
+    shape is traced and loaded once for the module, whatever the keys
+    (8-bit windows on the CPU: a pool of 25 slots is 49 MB)."""
+    return TPUProvider(min_batch=4, max_keys=POOL_SLOTS)
 
 
 class TestDifferential:
@@ -598,9 +622,9 @@ class TestDifferential:
         assert "ladder_batches" not in moved
         assert "pipeline_batches" not in moved  # one span: no overlap
         assert {e["kind"] for e in prov.device_cost.events[events:]} \
-            <= {"qtab", "comb_digest"}
+            <= {"qtab", "pool_write", "comb_digest"}
 
-    @pytest.mark.parametrize("entry, kinds", [
+    KIND_SETS = [
         ("verify_batch", ("message", "digest", "empty_message",
                           "long_message")),
         ("verify_batch", ("tampered", "wrong_key", "high_s",
@@ -608,15 +632,48 @@ class TestDifferential:
                           "short_digest", "non_p256")),
         ("verify_prepared", ("digest", "tampered", "wrong_key", "high_s",
                              "malformed_der", "non_p256")),
-    ], ids=["accept_kinds", "reject_kinds", "prepared"])
-    def test_more_than_max_keys_served_by_ladder(self, lane_provider,
-                                                 entry, kinds):
-        """The other side of the one choice: 17 distinct keys are one
-        more than MaxKeys, and the batch goes to the ladder."""
+    ]
+    KIND_IDS = ["accept_kinds", "reject_kinds", "prepared"]
+
+    @pytest.mark.parametrize("nkeys", [3, 17, 25])
+    @pytest.mark.parametrize("entry, kinds", KIND_SETS, ids=KIND_IDS)
+    def test_any_number_of_keys_the_pool_holds_served_by_comb_digest(
+            self, lane_provider, entry, kinds, nkeys):
+        """3, 17 and 25 distinct keys (the last a wide consortium's
+        block): every tamper kind, both entries, the sw provider's
+        verdicts — and the SAME compiled program: once the 64-lane
+        shape is in, no batch moves the compile counters or names a
+        program to the seam, whatever its keys."""
         prov = lane_provider
-        items, want = _kind_batch(kinds, lanes=32, nkeys=17)
+        warm, _ = _kind_batch(("digest",), lanes=64, nkeys=3)
+        prov.verify_batch(warm)         # the 64-lane shape, once
+        fn = prov._comb_pipeline_digest()
+        items, want = _kind_batch(kinds, lanes=64, nkeys=nkeys)
+        before = dict(prov.stats)
+        events = len(prov.device_cost.events)
+        assert _verify(prov, entry, items) == want
+        moved = _moved(prov, before)
+        assert moved.pop("comb_batches") == 1
+        assert moved.pop("key_slot_lookups") == nkeys
+        assert moved.get("key_slot_hits", 0) + \
+            moved.get("key_slot_builds", 0) == nkeys
+        assert "ladder_batches" not in moved
+        assert not set(moved) & set(FALLBACK_COUNTERS), moved
+        assert not [k for k in moved if k.startswith("compile_")], moved
+        assert prov.device_cost.events[events:] == []
+        assert prov._comb_pipeline_digest() is fn
+
+    @pytest.mark.parametrize("entry, kinds", KIND_SETS, ids=KIND_IDS)
+    def test_more_keys_than_slots_served_by_ladder(self, lane_provider,
+                                                   entry, kinds):
+        """The other side of the one choice: 26 distinct keys are one
+        more than the pool has slots, and the batch goes to the
+        ladder."""
+        prov = lane_provider
+        items, want = _kind_batch(kinds, lanes=64,
+                                  nkeys=POOL_SLOTS + 1)
         assert len({(it.key.x, it.key.y) for it in items
-                    if it.key.is_p256()}) > prov._max_keys
+                    if it.key.is_p256()}) == prov._key_capacity() + 1
         before = dict(prov.stats)
         assert _verify(prov, entry, items) == want
         moved = _moved(prov, before)
@@ -626,23 +683,24 @@ class TestDifferential:
 
     def test_program_inventory(self, lane_provider):
         """What stops a sixth tier arriving unannounced: after
-        prewarm() and one batch on each side of MaxKeys, every program
-        the compile seam has named — here and in every case above that
-        ran on this provider — is one of the five a P-256 batch can
-        need."""
+        prewarm() and one batch on each side of the pool's capacity,
+        every program the compile seam has named — here and in every
+        case above that ran on this provider — is one of the six a
+        P-256 batch can need."""
         prov = lane_provider
         prov.prewarm(buckets=(LANES,), bounded=True)
         assert prov.stats["prewarm_done"] == 1
-        for lanes, nkeys, counter in ((LANES, 3, "comb_batches"),
-                                      (32, 17, "ladder_batches")):
+        for lanes, nkeys, counter in (
+                (LANES, 3, "comb_batches"),
+                (64, POOL_SLOTS + 1, "ladder_batches")):
             items, want = _kind_batch(("digest", "tampered"), lanes,
                                       nkeys)
             before = dict(prov.stats)
             assert prov.verify_batch(items) == want
             assert _moved(prov, before).get(counter) == 1
         kinds = {e["kind"] for e in prov.device_cost.events}
-        assert {"qtab", "comb_digest", "ladder"} <= kinds
-        assert kinds <= {"qtab", "qtab16", "comb_digest", "comb",
-                         "ladder"}
+        assert {"qtab", "pool_write", "comb_digest", "ladder"} <= kinds
+        assert kinds <= {"qtab", "qtab16", "pool_write", "comb_digest",
+                         "comb", "ladder"}
         assert not [e for e in prov.device_cost.events if e["error"]]
         assert not [k for k in prov.stats if "fused" in k]

@@ -28,6 +28,11 @@ from fabric_tpu.common import faults
 _SW = SWProvider()
 _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 def _stubbed_provider(monkeypatch, **kw):
     kw.setdefault("min_batch", 1)
@@ -35,10 +40,11 @@ def _stubbed_provider(monkeypatch, **kw):
     tpu = TPUProvider(**kw)
     calls = {"premask": [], "key_idx": []}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["premask"].append(np.asarray(premask).copy())
             calls["key_idx"].append(np.asarray(key_idx).copy())
@@ -53,6 +59,8 @@ def _stubbed_provider(monkeypatch, **kw):
                 np.zeros(len(np.asarray(premask)), dtype=np.int32))
             return np.asarray(premask)
         return run
+
+    monkeypatch.setattr(tpu, "_slab_rows", lambda: SLAB_ROWS)
 
     monkeypatch.setattr(tpu, "_qtab_fn", fake_qtab_fn)
     monkeypatch.setattr(tpu, "_comb_pipeline_digest",

@@ -49,6 +49,11 @@ pytestmark = pytest.mark.chaos
 _SW = SWProvider()
 _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(3)]
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 class _StepClock:
     """Injectable monotonic clock for the breaker's clock seam
@@ -110,16 +115,17 @@ def _stubbed_provider(monkeypatch, **kw):
     tpu = TPUProvider(**kw)
     calls = {"premask": []}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["premask"].append(np.asarray(premask).copy())
             return np.asarray(premask)
         return run
 
-    def fake_pipeline(K, q16=False):
+    def fake_pipeline():
         def run(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
                 premask, digests, has_digest):
             calls["premask"].append(np.asarray(premask).copy())
@@ -132,6 +138,8 @@ def _stubbed_provider(monkeypatch, **kw):
             calls["premask"].append(np.asarray(premask).copy())
             return np.asarray(premask)
         return run
+
+    monkeypatch.setattr(tpu, "_slab_rows", lambda: SLAB_ROWS)
 
     monkeypatch.setattr(tpu, "_qtab_fn", fake_qtab_fn)
     monkeypatch.setattr(tpu, "_comb_pipeline", fake_pipeline)
@@ -510,15 +518,59 @@ class TestTPUProviderDegradation:
         assert seen == [4]                             # probe bounded
         assert tpu.health() == "device"
 
+    @pytest.mark.parametrize("entry", ["verify_batch",
+                                       "verify_prepared"])
+    def test_failed_table_build_degrades_like_a_dispatch_failure(
+            self, monkeypatch, entry):
+        """A slab build (or a pool write) that fails degrades the batch
+        exactly as a table failure always did: the sw provider's
+        verdicts, one `sw_fallbacks`, the pool as it was — and the next
+        batch builds and is served by the device."""
+        faults.clear()
+        tpu, calls = _stubbed_provider(
+            monkeypatch, fallback=BreakerConfig(trip_threshold=5))
+        real = tpu._build_slab
+        state = {"fail": True}
+
+        def flaky(kb):
+            if state["fail"]:
+                raise RuntimeError("table build failed")
+            return real(kb)
+        monkeypatch.setattr(tpu, "_build_slab", flaky)
+        if entry == "verify_batch":
+            items, expected = _tile(_premask_pool(), 24)
+            keys = 2
+
+            def verify():
+                return tpu.verify_batch(items)
+        else:
+            digests, r, rpn, w, der_ok, key_idx, ks, sigs, expected = \
+                self._prepared_arrays(8)
+            keys = 1
+
+            def verify():
+                return tpu.verify_prepared(digests, r, rpn, w, der_ok,
+                                           key_idx, ks,
+                                           lambda i: sigs[i])
+
+        assert verify() == expected
+        assert tpu.stats["sw_fallbacks"] == 1
+        assert tpu.stats["key_slots_resident"] == 0
+        assert calls["premask"] == []          # nothing was dispatched
+        state["fail"] = False
+        assert verify() == expected
+        assert tpu.stats["sw_fallbacks"] == 1
+        assert tpu.stats["key_slots_resident"] == keys
+        assert len(calls["premask"]) == 1
+
     def test_persist_fault_surfaces_in_counter(self, tmp_path):
         faults.clear()
         faults.arm("tpu.table_persist", mode="error", count=1)
         tpu = TPUProvider(min_batch=4, warm_keys_dir=str(tmp_path))
-        tpu._persist_table((b"\x01" * 64,),
-                           np.zeros(4, dtype=np.int32), "qtab8")
+        tpu._persist_slab(b"\x01" * 64, np.zeros(4, dtype=np.int32))
         tpu.flush_warm_tables(timeout=5.0)
         assert tpu.stats["warm_table_persist_failures"] == 1
-        assert not list(tmp_path.glob("qtab8_*.npy"))
+        assert not list(tmp_path.glob("slab*.npy"))
 
     def test_flush_warm_tables_total_deadline(self):
         """N stuck writers must cost ONE timeout, not N timeouts."""

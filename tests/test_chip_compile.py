@@ -4,9 +4,10 @@ is the by-hand twin and supplies the program and its shapes).
 
 A CPU run cannot see what the TPU compiler refuses (PR 23 found three
 such refusals only this way). This compile guards the one P-256 program
-every ledger line has measured — `comb_digest`, K = 4, 16-bit windows
-on both bases, one 2,048-lane span — at no chip time: ~70 s here, one
-core.
+every ledger line has measured — `comb_digest` against the key-table
+pool at its shipped 13 slots, 16-bit windows on both bases, one
+2,048-lane span — and the donating pool write, at no chip time: ~60 s
+here, one core.
 
 The topology is described inside a module-scoped fixture (one process
 at a time may load libtpu; every xdist worker imports this file), with
@@ -63,16 +64,34 @@ def _chip_compile():
 
 def test_comb_digest_span_compiles(one_chip, no_cache):
     """`jit_comb_digest` at the shape a one-chip provider dispatches
-    for every block: K = 4 key slots, q16, SPAN_LANES_PER_DEVICE."""
+    for every block: a pool of 13 key slots, q16,
+    SPAN_LANES_PER_DEVICE."""
     table = _chip_compile().programs(
-        tpumod.SPAN_LANES_PER_DEVICE, 4, one_chip)
+        tpumod.SPAN_LANES_PER_DEVICE, 13, one_chip)
     fn, shapes = table["digest_q16"]()
     lowered = fn.lower(*shapes)
     assert "jit_comb_digest" in lowered.as_text()[:200]
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     # the resident 16-bit tables are the arguments' bulk: one for G
-    # and one a key slot for Q (~252 MB each)
-    table_bytes = comb.NWIN_G16 * comb.NENT_G16 * 3 * limb.L * 4
-    assert ma.argument_size_in_bytes >= 5 * table_bytes
+    # and one a key slot for Q, each as the chip lays it out — a
+    # 20-limb coordinate in 24 words, which is what the provider
+    # budgets (`_slab_bytes` on a TPU)
+    table_bytes = comb.NWIN_G16 * comb.NENT_G16 * 3 * 24 * 4
+    assert 14 * table_bytes <= ma.argument_size_in_bytes \
+        < 14 * table_bytes + (8 << 20)
     assert ma.temp_size_in_bytes < 1 << 30
+
+
+def test_pool_write_is_in_place(one_chip, no_cache):
+    """The write of one slab into the 13-slot pool: the pool is donated
+    and the output takes its place — no second 4 GB array, no
+    temporary."""
+    fn, shapes = _chip_compile().programs(
+        tpumod.SPAN_LANES_PER_DEVICE, 13, one_chip)["pool_write"]()
+    compiled = fn.lower(*shapes).compile()
+    ma = compiled.memory_analysis()
+    table_bytes = comb.NWIN_G16 * comb.NENT_G16 * 3 * limb.L * 4
+    assert ma.output_size_in_bytes >= 13 * table_bytes
+    assert ma.alias_size_in_bytes == ma.output_size_in_bytes
+    assert ma.temp_size_in_bytes < table_bytes

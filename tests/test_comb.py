@@ -53,10 +53,11 @@ class TestQTables:
         qy = jnp.asarray(limb.ints_to_limbs([p[1] for p in pts]))
         flat = np.asarray(jax.jit(comb.build_q_tables)(qx, qy))
         K = len(ks)
-        assert flat.shape == (comb.NWIN * K * comb.NENT, 3, limb.L)
+        assert flat.shape == (K * comb.NWIN * comb.NENT, 3, limb.L)
         for i, k_idx, j in [(0, 0, 0), (0, 1, 1), (3, 0, 2),
                             (31, 1, 255), (16, 0, 128)]:
-            row = (i * K + k_idx) * comb.NENT + j
+            # key-major: a key's table is one contiguous slab
+            row = (k_idx * comb.NWIN + i) * comb.NENT + j
             got = tuple(
                 limb.limbs_to_int(
                     np.asarray(p256.FP.canonical(jnp.asarray(flat[row, c]))))
@@ -86,7 +87,7 @@ class TestCombDoubleScalarMul:
         def run(u1, u2, idx, qx, qy):
             g = jnp.asarray(comb.g_tables())
             q = comb.build_q_tables(qx, qy)
-            return comb.comb_double_scalar_mul(u1, u2, idx, g, q, K)
+            return comb.comb_double_scalar_mul(u1, u2, idx, g, q)
 
         X, Y, Z = jax.jit(run)(
             u1, u2, jnp.asarray(key_idx, dtype=jnp.int32), qx, qy)
@@ -126,7 +127,7 @@ class TestG16Windows:
         def run(u1, u2, idx, qx, qy, g16):
             q = comb.build_q_tables(qx, qy)
             return comb.comb_double_scalar_mul(
-                u1, u2, idx, None, q, K, g16=g16)
+                u1, u2, idx, None, q, g16=g16)
 
         X, Y, Z = jax.jit(run)(
             u1, u2, jnp.asarray(key_idx, dtype=jnp.int32), qx, qy, g16)
@@ -160,12 +161,11 @@ class TestQ16Windows:
         qy = jnp.asarray(limb.ints_to_limbs([p[1] for p in key_pts]))
         g16 = comb.g16_tables()
         q8 = jax.jit(comb.build_q_tables)(qx, qy)
-        q16 = jax.jit(comb.build_q16_tables,
-                      static_argnums=1)(q8, K)
+        q16 = jax.jit(comb.build_q16_tables)(q8)
 
         def run(u1, u2, idx, q16, g16):
             return comb.comb_double_scalar_mul(
-                u1, u2, idx, None, q16, K, g16=g16, q16=True)
+                u1, u2, idx, None, q16, g16=g16, q16=True)
 
         X, Y, Z = jax.jit(run)(
             u1, u2, jnp.asarray(key_idx, dtype=jnp.int32), q16, g16)
@@ -228,8 +228,8 @@ class TestCombVerifyCore:
 class TestProvider16BitPath:
     def test_provider_g16_q16_matches_sw_and_caches(self):
         """TPUProvider(use_g16=True): the 32-point-tree product path
-        agrees with the sw oracle and reuses the cached per-key-set
-        Q tables on a second batch."""
+        agrees with the sw oracle and finds both keys' slabs in the
+        pool on a second batch."""
         from fabric_tpu.bccsp import bccsp as api
         from fabric_tpu.bccsp.sw import SWProvider
         from fabric_tpu.bccsp.tpu import TPUProvider
@@ -255,8 +255,9 @@ class TestProvider16BitPath:
 
         b1 = batch("one")
         assert tpu.verify_batch(b1) == sw.verify_batch(b1)
-        assert len(tpu._qflat_cache) == 1
-        b2 = batch("two")        # same keys: cached tables reused
+        assert tpu.stats["key_slot_builds"] == 2
+        b2 = batch("two")        # same keys: resident slabs reused
         assert tpu.verify_batch(b2) == sw.verify_batch(b2)
-        assert len(tpu._qflat_cache) == 1
+        assert (tpu.stats["key_slot_builds"],
+                tpu.stats["key_slot_hits"]) == (2, 2)
         assert tpu.stats["comb_batches"] == 2

@@ -51,6 +51,11 @@ SPAN8 = 1024     # aligned_span granule for an 8-way mesh
 
 _POOL: list = []
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 def _corpus(n):
     """Mixed valid/invalid lanes tiled from a 24-lane signed pool
@@ -85,10 +90,11 @@ def _stubbed_provider(mesh=None, dh_config=None, **kw):
     tpu = TPUProvider(mesh=mesh, device_health=dh_config, **kw)
     calls = {"premask": [], "dispatches": 0}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False, donate=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["premask"].append(np.asarray(premask).copy())
             calls["dispatches"] += 1
@@ -101,6 +107,8 @@ def _stubbed_provider(mesh=None, dh_config=None, **kw):
             calls["dispatches"] += 1
             return np.asarray(premask)
         return run
+
+    tpu._slab_rows = lambda: SLAB_ROWS
 
     tpu._qtab_fn = fake_qtab_fn
     tpu._comb_pipeline_digest = fake_pipeline_digest
@@ -441,28 +449,31 @@ class TestElasticMeshDeviceLoss:
         assert tpu.verify_batch(items) == expected
         assert tpu.stats["sw_fallbacks"] == 1
 
-    def test_cached_tables_rehosted_on_rebuild(self, mesh8):
-        """_resolve_tables stores REPLICATED table copies back into
-        the caches; after a mesh swap those old-mesh handles hold a
-        replica on the benched chip (poisoned on real hardware). The
-        rebuild re-materializes them on the host from a kept replica
-        so the next dispatch re-replicates clean bytes."""
+    def test_key_pool_dropped_on_rebuild_and_filled_again(self, mesh8):
+        """The key-table pool is REPLICATED over the serving mesh;
+        after a mesh swap the old-mesh handle holds a replica on the
+        benched chip (poisoned on real hardware). The rebuild drops
+        the pool, and the next dispatch allocates it over the
+        survivors and admits its keys again."""
         faults.clear()
         tpu, _ = _stubbed_provider(
             mesh=mesh8, dh_config=DeviceHealthConfig(cooldown_s=300.0))
         items, expected = _corpus(2048)
         assert tpu.verify_batch(items) == expected
-        cached = next(iter(tpu._q8_cache.values()))
-        shards = getattr(cached, "addressable_shards", None)
-        assert shards is not None and len(shards) == 8
+        keys = tpu.stats["key_slots_resident"]
+        assert keys >= 1 and len(tpu._pool.addressable_shards) == 8
+        builds = tpu.stats["key_slot_builds"]
         faults.arm("tpu.device_lost", mode="error", count=1, arg=1)
         assert tpu.verify_batch(items) == expected   # loss + rebuild
         assert tpu._mesh.size == 7
-        cached = next(iter(tpu._q8_cache.values()))
-        assert getattr(cached, "addressable_shards", None) is None, \
-            "old-mesh replicated handle survived the rebuild"
-        # the host copy re-replicates on the next dispatch
+        assert tpu._pool is None and not tpu._slot_of, \
+            "old-mesh replicated pool survived the rebuild"
+        assert tpu.stats["key_slots_resident"] == 0
+        # the next dispatch fills a pool over the seven survivors
         assert tpu.verify_batch(items) == expected
+        assert len(tpu._pool.addressable_shards) == 7
+        assert tpu.stats["key_slots_resident"] == keys
+        assert tpu.stats["key_slot_builds"] == builds + keys
         assert tpu.stats["pipeline_batches"] == 2
 
     def test_runtime_error_naming_a_device_attributes(self, mesh8):
@@ -476,8 +487,8 @@ class TestElasticMeshDeviceLoss:
         real = tpu._comb_pipeline_digest
         state = {"failed": False}
 
-        def failing_pipeline(K, q16=False, donate=False):
-            inner = real(K, q16, donate)
+        def failing_pipeline():
+            inner = real()
 
             def run(*a):
                 if not state["failed"]:

@@ -1,7 +1,8 @@
 """A restarted provider loads its verify programs, it does not trace
 them: `TPUProvider.prewarm` through the store of compiled executables
-(common/execstore.py), with the provider's REAL programs — `qtab` and
-the 74,000-operation `comb_digest` at the CPU bucket of 16 lanes.
+(common/execstore.py), with the provider's REAL programs — `qtab`,
+the donating `pool_write` and the 74,000-operation `comb_digest` at the
+CPU bucket of 16 lanes.
 
 One writer provider fills a store under a temporary directory (a cold
 compile, about a minute on the CPU backend: XLA:CPU cannot serialize
@@ -132,15 +133,15 @@ def filled(tmp_path_factory, fresh_compiles):
     return directory, items, want, got, traces, dict(prov.stats)
 
 
-def test_the_writer_traces_once_a_program_and_writes_both(filled):
+def test_the_writer_traces_once_a_program_and_writes_each(filled):
     _, _, want, got, traces, stats = filled
     assert got == want
     # prewarm traced each program once; the first batch traced nothing
-    assert sorted(traces) == ["comb_digest", "qtab"]
+    assert sorted(traces) == ["comb_digest", "pool_write", "qtab"]
     assert (stats["executable_store_misses"],
             stats["executable_store_hits"],
-            stats["executable_store_errors"]) == (2, 0, 0)
-    assert stats["compile_total"] == 2
+            stats["executable_store_errors"]) == (3, 0, 0)
+    assert stats["compile_total"] == 3
 
 
 @pytest.mark.parametrize("entry", ["verify_batch", "verify_prepared"])
@@ -162,26 +163,35 @@ def test_restarted_provider_traces_nothing(filled, entry):
     assert traces == []
     st = prov.stats
     assert (st["executable_store_hits"], st["executable_store_misses"],
-            st["executable_store_errors"]) == (2, 0, 0)
-    assert st["compile_total"] == 2 and st["compile_cold_total"] == 0
+            st["executable_store_errors"]) == (3, 0, 0)
+    assert st["compile_total"] == 3 and st["compile_cold_total"] == 0
     assert st["comb_batches"] == 1
+    # the loaded `pool_write` still donates: three keys admitted, one
+    # pool, and the sw provider's verdicts read through it
+    assert st["key_slot_builds"] == st["key_slots_resident"] == 3
     assert [st[c] for c in FALLBACK_COUNTERS] == [0] * 6
     assert {(e["kind"], e["source"], e["aot"])
             for e in prov.device_cost.events} == {
-        ("qtab", "store", True), ("comb_digest", "store", True)}
+        ("qtab", "store", True), ("pool_write", "store", True),
+        ("comb_digest", "store", True)}
 
 
-def test_a_provider_of_other_parameters_misses(filled):
-    """Another key-slot count is another program: the store must not
-    serve K = 4's executable for it (requested only, not compiled:
-    the request's entry is simply not there)."""
+def test_a_pool_of_another_capacity_misses(filled):
+    """The number of keys in a batch is no part of a program any more,
+    but the pool's capacity is its table argument's shape: the store
+    must not serve the 32-slot pool's executable to a provider whose
+    MaxKeys gives it 16 (requested only, not compiled: the request's
+    entry is simply not there)."""
     directory = filled[0]
     store = ExecutableStore(directory)
-    prov = TPUProvider(min_batch=4)
     sd = jax.ShapeDtypeStruct
-    for K, hit in ((4, True), (8, False)):
-        fn = prov._qtab_fn(K)
-        shapes = (sd((K, 20), np.int32), sd((K, 20), np.int32))
-        req = store.request("qtab", fn._params, shapes, (),
+    for slots, hit in ((32, True), (16, False)):
+        prov = TPUProvider(min_batch=4, max_keys=slots)
+        assert prov._key_capacity() == slots
+        rows = prov._slab_rows()
+        fn = prov._pool_write_fn()
+        shapes = (sd((slots * rows, 3, 20), np.int32),
+                  sd((rows, 3, 20), np.int32), sd((), np.int32))
+        req = store.request("pool_write", fn._params, shapes, (),
                             jax.devices()[:1])
         assert (store.load(req) is not None) == hit

@@ -401,16 +401,17 @@ def test_clean_tree_gate(chk):
     assert len(out["baselined"]) >= 1
 
 
-def test_reverting_qtab_lock_fix_fails_gate(chk):
-    """Surgically strip the q16 cache locking from the live tree
-    (overrides — no checkout) and the lockset rule must light up
-    again on the qtab-cache attributes, over and above the
-    committed baseline."""
+def test_reverting_pool_lock_fails_gate(chk):
+    """Surgically strip the key-table pool's locking from the live
+    tree (overrides — no checkout) and the lockset rule must light up
+    again on the pool's attributes (the restore thread and live
+    batches both write them), over and above the committed
+    baseline."""
     rel = "fabric_tpu/bccsp/tpu.py"
     with open(os.path.join(REPO, rel), encoding="utf-8") as f:
         src = f.read()
-    assert "with self._q16_lock:" in src
-    reverted = src.replace("with self._q16_lock:",
+    assert "with self._pool_lock:" in src
+    reverted = src.replace("with self._pool_lock:",
                            "if True:  # unlocked")
     findings, _ = chk.run_check(REPO, rules=("lockset",),
                                 overrides={rel: reverted})
@@ -419,5 +420,6 @@ def test_reverting_qtab_lock_fix_fails_gate(chk):
     assert err is None
     new = {f.fingerprint for f in findings} - set(baseline)
     assert ("lockset:fabric_tpu/bccsp/tpu.py::"
-            "TPUProvider._qflat_cache") in new, sorted(new)
-    assert any("::TPUProvider._q16_" in fp for fp in new)
+            "TPUProvider._pool") in new, sorted(new)
+    assert any("::TPUProvider._slot_of" in fp
+               or "::TPUProvider._g16_rep" in fp for fp in new)

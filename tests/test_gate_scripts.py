@@ -66,8 +66,8 @@ def test_chip_compile_lists_the_programs_the_provider_builds():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     table = mod.programs(2048, 4, None)
-    assert sorted(table) == ["digest_q16", "digest_q8", "g16", "qtab16",
-                             "qtab8"]
+    assert sorted(table) == ["digest_q16", "digest_q8", "g16",
+                             "pool_write", "qtab16", "qtab8"]
     for name, build in table.items():
         fn, shapes = build()
         assert callable(getattr(fn, "lower", None)), name

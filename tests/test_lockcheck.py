@@ -23,6 +23,11 @@ from fabric_tpu.bccsp.tpu import TPUProvider
 from fabric_tpu.common import faults, lockcheck
 from fabric_tpu.common.lockcheck import LockOrderError, LockSanitizer
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 def _acquire_ab(lock_a, lock_b):
     with lock_a:
@@ -219,16 +224,17 @@ class TestHeldAcrossBlocking:
                                     signature=sig, message=m))
         tpu = TPUProvider(min_batch=4, use_g16=False)
 
-        def fake_qtab_fn(K):
-            return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+        def fake_qtab_fn():
+            return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                               dtype=np.int32)
 
-        def fake_pipeline_digest(K, q16=False):
+        def fake_pipeline_digest():
             def run(key_idx, q_flat, g16, r8, rpn8, w8, premask,
                     digests):
                 return np.asarray(premask)
             return run
 
-        def fake_pipeline(K, q16=False):
+        def fake_pipeline():
             def run(blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
                     premask, digests, has_digest):
                 return np.asarray(premask)
@@ -239,6 +245,8 @@ class TestHeldAcrossBlocking:
                     digests, has_digest):
                 return np.asarray(premask)
             return run
+
+        monkeypatch.setattr(tpu, "_slab_rows", lambda: SLAB_ROWS)
 
         monkeypatch.setattr(tpu, "_qtab_fn", fake_qtab_fn)
         monkeypatch.setattr(tpu, "_comb_pipeline_digest",

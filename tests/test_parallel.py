@@ -230,8 +230,7 @@ class TestShardedComb:
             rpns.append(r + p256.N if r + p256.N < p256.P else r)
 
         q8 = jnp.asarray(comb.g_tables())       # REAL table for Q == G
-        q_flat = jax.jit(comb.build_q16_tables,
-                         static_argnums=1)(q8, 1)
+        q_flat = jax.jit(comb.build_q16_tables)(q8)
         g16 = comb.g16_tables()
         rep = NamedSharding(mesh8, P())
         s_ = NamedSharding(mesh8, P(BATCH_AXIS))

@@ -26,21 +26,26 @@ from fabric_tpu.common import faults
 _SW = SWProvider()
 _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 def _stubbed_provider(**kw):
     kw.setdefault("min_batch", 1)
     kw.setdefault("use_g16", False)
     tpu = TPUProvider(**kw)
-    calls = {"premask": [], "key_idx": [], "K": [], "ladder": 0}
+    calls = {"premask": [], "key_idx": [], "ladder": 0}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["premask"].append(np.asarray(premask).copy())
             calls["key_idx"].append(np.asarray(key_idx).copy())
-            calls["K"].append(K)
             return np.asarray(premask)
         return run
 
@@ -50,6 +55,8 @@ def _stubbed_provider(**kw):
             calls["ladder"] += 1
             return np.asarray(premask)
         return run
+
+    tpu._slab_rows = lambda: SLAB_ROWS
 
     tpu._qtab_fn = fake_qtab_fn
     tpu._comb_pipeline_digest = fake_pipeline_digest
@@ -182,9 +189,9 @@ class TestPipelineParity:
                 expected.append(True)
         assert tpu.verify_batch(items) == expected
         assert tpu.stats["pipeline_batches"] == 1
-        # the compiled pipeline saw a ONE-key table, as the
-        # whole-batch path would resolve for this batch
-        assert set(calls["K"]) == {1}
+        # every lane names the ONE key's slot, as the whole-batch path
+        # would resolve for this batch
+        assert tpu.stats["key_slots_resident"] == 1
         for kidx in calls["key_idx"]:
             assert (kidx == 0).all()
 

@@ -40,6 +40,11 @@ _P256 = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
 _ED = [_SW.key_gen(Ed25519KeyGenOpts(ephemeral=True)) for _ in range(2)]
 _BLS = _SW.key_gen(BLSKeyGenOpts(ephemeral=True))
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 class _NotP256(ECDSAPublicKey):
     """A P-256 key masquerading as an unknown curve: the device must
@@ -62,10 +67,11 @@ def _stubbed_provider(mesh=None, **kw):
     tpu = TPUProvider(mesh=mesh, **kw)
     calls = {"p256_premask": [], "ed_premask": [], "ed_chunks": 0}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False, donate=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["p256_premask"].append(np.asarray(premask).copy())
             return np.asarray(premask)
@@ -100,6 +106,8 @@ def _stubbed_provider(mesh=None, **kw):
                 out[i] = edh.pt_equal(acc, edh.from_affine(rx, ry))
             return out
         return run
+
+    tpu._slab_rows = lambda: SLAB_ROWS
 
     tpu._qtab_fn = fake_qtab_fn
     tpu._comb_pipeline_digest = fake_pipeline_digest

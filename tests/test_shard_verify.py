@@ -42,6 +42,11 @@ _KEYS = [_SW.key_gen(ECDSAKeyGenOpts(ephemeral=True)) for _ in range(2)]
 # aligned_span granule for an 8-way mesh (bccsp/tpu.py LANE_ALIGN=128)
 SPAN8 = 1024
 
+# rows of a stub key table: a pool of a few hundred bytes, so the
+# real slot bookkeeping and pool write run under the stubbed math
+SLAB_ROWS = 8
+
+
 
 def _stubbed_provider(mesh=None, **kw):
     kw.setdefault("min_batch", 1)
@@ -49,10 +54,11 @@ def _stubbed_provider(mesh=None, **kw):
     tpu = TPUProvider(mesh=mesh, **kw)
     calls = {"premask": [], "key_idx": [], "ladder": 0}
 
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
 
-    def fake_pipeline_digest(K, q16=False, donate=False):
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             calls["premask"].append(np.asarray(premask).copy())
             calls["key_idx"].append(np.asarray(key_idx).copy())
@@ -65,6 +71,8 @@ def _stubbed_provider(mesh=None, **kw):
             calls["ladder"] += 1
             return np.asarray(premask)
         return run
+
+    tpu._slab_rows = lambda: SLAB_ROWS
 
     tpu._qtab_fn = fake_qtab_fn
     tpu._comb_pipeline_digest = fake_pipeline_digest
@@ -194,8 +202,9 @@ class TestProgramInventory:
         math that accepts a lane where the premask does AND the digest
         in that lane is one the sw provider accepted — so lanes staged
         to the wrong device read wrong. After prewarm() and one batch
-        on each side of MaxKeys the seam has named nothing but the
-        programs a P-256 batch can need."""
+        on each side of the pool's capacity the seam has named nothing
+        but the programs a P-256 batch can need — the pool replicated
+        on all eight devices, as the shardings stated to `aot` say."""
         import jax.numpy as jnp
 
         from fabric_tpu.ops import comb, p256, sha256
@@ -224,8 +233,9 @@ class TestProgramInventory:
             lambda blocks, nblocks: jnp.zeros((blocks.shape[0], 8),
                                               jnp.uint32))
 
-        prov = TPUProvider(min_batch=16, use_g16=False, mesh=mesh8)
-        prov.prewarm(bounded=True)
+        prov = TPUProvider(min_batch=16, use_g16=False, mesh=mesh8,
+                           max_keys=16)
+        prov.prewarm(buckets=(len(few),), bounded=True)
         assert prov.verify_batch(few) == want_few == \
             _SW.verify_batch(few)
         assert prov.verify_batch(many) == want_many == \
@@ -236,10 +246,17 @@ class TestProgramInventory:
         assert st["shard_dispatches"] >= 1      # the comb side books them
         assert st["sw_fallbacks"] == st["degraded_batches"] == 0
         assert st["host_hashed_lanes"] == sum(want_few) + sum(want_many)
+        assert st["key_slots_resident"] == len(_KEYS)
+        assert len(prov._pool.addressable_shards) == 8
+        assert prov._pool.sharding.is_fully_replicated
+        # prewarm's executables served the dispatches: nothing was
+        # compiled for a shape or a sharding it had not stated
+        assert [e["kind"] for e in prov.device_cost.events
+                if not e["aot"]] == ["ladder"]
         kinds = {e["kind"] for e in prov.device_cost.events}
-        assert {"qtab", "comb_digest", "ladder"} <= kinds
-        assert kinds <= {"qtab", "qtab16", "comb_digest", "comb",
-                         "ladder"}
+        assert {"qtab", "pool_write", "comb_digest", "ladder"} <= kinds
+        assert kinds <= {"qtab", "qtab16", "pool_write", "comb_digest",
+                         "comb", "ladder"}
 
 
 class TestDevicesKnob:
@@ -409,11 +426,13 @@ from fabric_tpu.ops import sha256
 from fabric_tpu.parallel import BATCH_AXIS, batch_mesh
 
 res = {"devices": len(jax.devices())}
+SLAB_ROWS = 8
 
 def stub(tpu):
-    def fake_qtab_fn(K):
-        return lambda qx, qy: np.zeros((K,), dtype=np.int32)
-    def fake_pipeline_digest(K, q16=False, donate=False):
+    def fake_qtab_fn():
+        return lambda qx, qy: np.zeros((SLAB_ROWS, 3, 20),
+                                           dtype=np.int32)
+    def fake_pipeline_digest():
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
             return np.asarray(premask)
         return run
@@ -422,6 +441,7 @@ def stub(tpu):
                 has_digest):
             return np.asarray(premask)
         return run
+    tpu._slab_rows = lambda: SLAB_ROWS
     tpu._qtab_fn = fake_qtab_fn
     tpu._comb_pipeline_digest = fake_pipeline_digest
     tpu._pipeline = fake_ladder

@@ -114,15 +114,15 @@ def _stand_in(prov):
     accepted = _unique()[2]
     programs = {}
 
-    def fake_pipeline_digest(K, q16=False):
-        if (K, q16) not in programs:
-            programs[K, q16] = prov._jit(
+    def fake_pipeline_digest():
+        if not programs:
+            programs["digest"] = prov._jit(
                 "comb_digest",
                 lambda key_idx, q_flat, g16, r8, rpn8, w8, premask,
                 digests: premask)
 
         def run(key_idx, q_flat, g16, r8, rpn8, w8, premask, digests):
-            out = np.asarray(programs[K, q16](
+            out = np.asarray(programs["digest"](
                 key_idx, q_flat, g16, r8, rpn8, w8, premask, digests))
             shapes.append(out.shape[0])
             dg = np.asarray(digests)
@@ -130,7 +130,9 @@ def _stand_in(prov):
                                    for j in range(len(out))], dtype=bool)
         return run
 
-    prov._qtab_fn = lambda K: lambda qx, qy: np.zeros((K,), np.int32)
+    # a pool of a few hundred bytes: real slots, real pool writes
+    prov._slab_rows = lambda: 8
+    prov._qtab_fn = lambda: lambda qx, qy: np.zeros((8, 3, 20), np.int32)
     prov._comb_pipeline_digest = fake_pipeline_digest
     return shapes
 
@@ -298,4 +300,5 @@ def test_removed_switch_is_inert(switch, monkeypatch):
         for it in items)
     assert prov.stats["sw_fallbacks"] == prov.stats["ladder_batches"] == 0
     assert not [k for k in prov.stats if "fused" in k]
-    assert {e["kind"] for e in prov.device_cost.events} == {"comb_digest"}
+    assert {e["kind"] for e in prov.device_cost.events} == {
+        "pool_write", "comb_digest"}

@@ -5,7 +5,7 @@ sidecar; a load whose bytes no longer match falls back to a REBUILD.
 The failure mode this closes: the old loader only checked dtype and
 byte COUNT, so same-size corruption (bit rot, a torn write that
 survived rename) fed the verify kernel wrong curve points — silent
-verdict flips. Builders are stubbed (test_q16_cache idiom); the
+verdict flips. Builders are stubbed (test_key_pool idiom); the
 G-table path runs its real 2-second host build.
 """
 
@@ -17,26 +17,24 @@ import pytest
 from fabric_tpu.bccsp.tpu import TPUProvider
 from fabric_tpu.ops import comb
 
-EST = 1000
+ROWS = 4
 
 
 def _stub(monkeypatch):
     import jax.numpy as jnp
 
-    def fake_qtab_fn(self, K):
-        return lambda qx, qy: jnp.zeros((2,), jnp.int32)
-
-    def fake_q16_fn(self, K):
-        return lambda q8, k: jnp.arange(EST // 4, dtype=jnp.int32)
-
-    monkeypatch.setattr(TPUProvider, "_qtab_fn", fake_qtab_fn)
-    monkeypatch.setattr(TPUProvider, "_q16_fn", fake_q16_fn)
-    monkeypatch.setattr(TPUProvider, "_q16_est_bytes",
-                        lambda self, K: EST)
+    monkeypatch.setattr(TPUProvider, "_slab_rows", lambda self: ROWS)
+    monkeypatch.setattr(
+        TPUProvider, "_qtab_fn", lambda self: lambda qx, qy: jnp.arange(
+            ROWS * 60, dtype=jnp.int32).reshape(ROWS, 3, 20))
 
 
-_QX = np.zeros((1, 20), dtype=np.int32)
-_KEY = (bytes([7]) * 64,)
+_KEY = bytes([7]) * 64
+
+
+def _admit(prov, kb=_KEY):
+    with prov._pool_lock:
+        prov._key_slots({kb: 0}, np.zeros(1, np.int32))
 
 
 def _flip_one_payload_byte(path):
@@ -62,15 +60,14 @@ class TestSidecarHelpers:
         assert comb.verify_digest_sidecar(p) is None
 
 
-class TestQTableIntegrity:
+class TestKeyTableIntegrity:
     def test_persist_writes_sidecar(self, monkeypatch, tmp_path):
         _stub(monkeypatch)
         warm = str(tmp_path / "warm")
-        p1 = TPUProvider(use_g16=True, table_cache_bytes=3 * EST,
-                         warm_keys_dir=warm)
-        assert p1._q16_cached(_KEY, 1, _QX, _QX) is not None
+        p1 = TPUProvider(use_g16=False, max_keys=3, warm_keys_dir=warm)
+        _admit(p1)
         p1.flush_warm_tables()
-        path = p1._table_path(_KEY)
+        path = p1._slab_path(_KEY)
         assert os.path.exists(path)
         assert os.path.exists(path + ".sha256")
         assert comb.verify_digest_sidecar(path) is True
@@ -79,31 +76,34 @@ class TestQTableIntegrity:
                                            tmp_path):
         _stub(monkeypatch)
         warm = str(tmp_path / "warm")
-        p1 = TPUProvider(use_g16=True, table_cache_bytes=3 * EST,
-                         warm_keys_dir=warm)
-        assert p1._q16_cached(_KEY, 1, _QX, _QX) is not None
+        p1 = TPUProvider(use_g16=False, max_keys=3, warm_keys_dir=warm)
+        _admit(p1)
         p1.flush_warm_tables()
-        path = p1._table_path(_KEY)
+        path = p1._slab_path(_KEY)
         _flip_one_payload_byte(path)     # nbytes/dtype still "valid"
 
-        p2 = TPUProvider(use_g16=True, table_cache_bytes=3 * EST,
-                         warm_keys_dir=warm)
-        assert p2._prewarm_tables() == 1
-        assert p2.stats["q16_disk_loads"] == 0   # corrupt bytes refused
-        assert p2.stats["q16_builds"] == 1       # rebuilt instead
+        p2 = TPUProvider(use_g16=False, max_keys=3, warm_keys_dir=warm)
+        assert p2._restore_slabs() == 0          # corrupt bytes refused
+        _admit(p2)
+        assert p2.stats["key_slot_disk_loads"] == 0
+        assert p2.stats["key_slot_builds"] == 1  # rebuilt instead
+        p2.flush_warm_tables()                   # and written anew
+        assert comb.verify_digest_sidecar(path) is True
 
-    def test_reclaim_removes_sidecar(self, monkeypatch, tmp_path):
+    def test_eviction_removes_file_and_sidecar(self, monkeypatch,
+                                               tmp_path):
         _stub(monkeypatch)
         warm = str(tmp_path / "warm")
-        p1 = TPUProvider(use_g16=True, table_cache_bytes=EST,
-                         warm_keys_dir=warm)
-        assert p1._q16_cached(_KEY, 1, _QX, _QX) is not None
+        p1 = TPUProvider(use_g16=False, max_keys=1, warm_keys_dir=warm)
+        _admit(p1)
         p1.flush_warm_tables()
-        path = p1._table_path(_KEY)
+        path = p1._slab_path(_KEY)
         assert os.path.exists(path + ".sha256")
-        p1._drop_warm_keys(_KEY)
+        _admit(p1, bytes([8]) * 64)              # one slot: evicts _KEY
+        p1.flush_warm_tables()
         assert not os.path.exists(path)
         assert not os.path.exists(path + ".sha256")
+        assert os.path.exists(p1._slab_path(bytes([8]) * 64))
 
 
 class TestGTableIntegrity:

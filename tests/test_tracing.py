@@ -539,12 +539,14 @@ class TestProviderSpans:
 
         from fabric_tpu.bccsp.tpu import TPUProvider
         tpu = TPUProvider(min_batch=4, use_g16=False, **kw)
+        # a pool of a few hundred bytes: real slots, real pool writes
+        monkeypatch.setattr(tpu, "_slab_rows", lambda: 8)
         monkeypatch.setattr(
             tpu, "_qtab_fn",
-            lambda K: lambda qx, qy: np.zeros((K,), dtype=np.int32))
+            lambda: lambda qx, qy: np.zeros((8, 3, 20), dtype=np.int32))
         monkeypatch.setattr(
             tpu, "_comb_pipeline_digest",
-            lambda K, q16=False: lambda key_idx, q_flat, g16, r8, rpn8,
+            lambda: lambda key_idx, q_flat, g16, r8, rpn8,
             w8, premask, digests: premask)
         return tpu
 
@@ -574,17 +576,29 @@ class TestProviderSpans:
                 with tracing.span("validate.flags"):
                     out = resolve()
             assert out == [i != 1 for i in range(n)]
-            evs = [e for e in tracing.snapshot() if e[2] == root.trace_id]
+            # (the pool write's first use is a compile: the compile
+            # seam's own spans, tests/test_devicecost.py)
+            evs = [e for e in tracing.snapshot()
+                   if e[2] == root.trace_id and e[1] != "tpu.compile"]
             by_id = {e[3]: e[1] for e in evs}
             parents = {}
             for e in evs:
                 parents.setdefault(e[1], set()).add(by_id.get(e[4]))
-            # the same spans whatever the batch holds
+            # the same spans whatever the batch holds, and one
+            # `tpu.table_build` a key it brings into the pool (each
+            # batch here signs with a key of its own)
             assert sorted(e[1] for e in evs) == sorted(
                 ["commit.validate", "validate.flags", "tpu.verify",
                  "tpu.verify", "tpu.stage", "tpu.comb_digest",
-                 "tpu.tables", "tpu.h2d", "tpu.enqueue", "tpu.wait",
-                 "tpu.readback", "tpu.readback"])
+                 "tpu.tables", "tpu.table_build", "tpu.h2d",
+                 "tpu.enqueue", "tpu.wait", "tpu.readback",
+                 "tpu.readback"])
+            assert parents["tpu.table_build"] == {"tpu.tables"}
+            tables = _events("tpu.tables")[0][8]
+            assert (tables["keys"], tables["hits"], tables["built"],
+                    tables["evicted"]) == (1, 0, 1, 0)
+            build = _events("tpu.table_build")[0][8]
+            assert build["source"] == "build" and build["bytes"] > 0
             assert parents["tpu.stage"] == {"tpu.verify"}
             assert parents["tpu.comb_digest"] == {"tpu.verify"}
             for name in ("tpu.tables", "tpu.h2d", "tpu.enqueue"):

@@ -1,43 +1,47 @@
-"""Warm-restart wiring: Q-table key-set persistence → prewarm rebuild.
+"""Warm-restart wiring: key-table persistence -> prewarm restore.
 
 Round-3 verdict: the warm-keys machinery existed but was unreachable
-(no `WarmKeysDir` in the factory, `prewarm()` never called
-`_prewarm_tables()`). These tests pin the WIRING end to end — config →
-factory → provider, build → persist, fresh provider → prewarm →
-cache hit — with the table builders stubbed (the real 16-bit comb
-build is a multi-minute device job, not a unit concern).
+(no `WarmKeysDir` in the factory, `prewarm()` never reached the
+restore). These tests pin the WIRING end to end — config -> factory ->
+provider, build -> a file a key, fresh provider -> prewarm -> the key
+in its slot — with the table builders stubbed (the real 16-bit comb
+build is a device job, not a unit concern). Slot bookkeeping:
+tests/test_key_pool.py.
 """
 
-import json
 import os
 
 import numpy as np
 
 from fabric_tpu.bccsp import factory
 from fabric_tpu.bccsp.tpu import TPUProvider
-from fabric_tpu.ops import limb
 
-
-def _limbs(kb: bytes):
-    qk = np.frombuffer(kb, dtype=np.uint8).reshape(1, 64).copy()
-    return (limb.be_bytes_to_limbs(qk[:, :32]),
-            limb.be_bytes_to_limbs(qk[:, 32:]))
+ROWS = 4
 
 
 def _stub_builders(monkeypatch, builds):
     import jax.numpy as jnp
 
-    def fake_qtab_fn(self, K):
+    def fake_qtab_fn(self):
         return lambda qx, qy: jnp.zeros((2, 3, 20), jnp.int32)
 
-    def fake_q16_fn(self, K):
-        def build(q8, k):
-            builds.append(k)
-            return jnp.zeros((4, 3, 20), jnp.int32)
+    def fake_q16_fn(self):
+        def build(q8):
+            builds.append(1)
+            return jnp.ones((ROWS, 3, 20), jnp.int32)
         return build
 
+    from fabric_tpu.ops import comb
+    monkeypatch.setattr(comb, "g16_tables",
+                        lambda: jnp.zeros((0, 3, 20), jnp.int32))
+    monkeypatch.setattr(TPUProvider, "_slab_rows", lambda self: ROWS)
     monkeypatch.setattr(TPUProvider, "_qtab_fn", fake_qtab_fn)
     monkeypatch.setattr(TPUProvider, "_q16_fn", fake_q16_fn)
+
+
+def _admit(prov, kb):
+    with prov._pool_lock:
+        return prov._key_slots({kb: 0}, np.zeros(1, np.int32))[0][0]
 
 
 def test_factory_passes_warm_keys_dir(tmp_path):
@@ -59,48 +63,54 @@ def test_build_persists_and_fresh_provider_prewarms(tmp_path,
     warm = str(tmp_path / "warm")
     kb = bytes(range(64))
 
-    prov = TPUProvider(warm_keys_dir=warm, use_g16=True)
-    qx, qy = _limbs(kb)
-    assert prov._q16_cached((kb,), 1, qx, qy) is not None
-    assert prov.stats["q16_builds"] == 1
-    # table bytes land asynchronously; prewarm only restores sets
-    # whose bytes exist on disk (stub bytes fail the size check, so
-    # the fresh provider below exercises the REBUILD fallback)
+    prov = TPUProvider(warm_keys_dir=warm, use_g16=True, max_keys=4)
+    _admit(prov, kb)
+    assert prov.stats["key_slot_builds"] == 1 and len(builds) == 1
+    # table bytes land asynchronously, a file a key
     prov.flush_warm_tables()
+    assert sorted(os.listdir(warm)) == [
+        f"slab16_{kb.hex()}.npy", f"slab16_{kb.hex()}.npy.sha256"]
 
-    # the key set was persisted (MRU first, hex encoded)
-    sets = json.load(open(os.path.join(warm, "warm_keysets.json")))
-    assert sets == [[kb.hex()]]
-
-    # "restarted peer": a fresh provider over the same dir rebuilds the
-    # persisted set during prewarm, so the first block's table lookup
-    # is a cache HIT — zero builds on the serving path
-    prov2 = TPUProvider(warm_keys_dir=warm, use_g16=True)
-    assert prov2._prewarm_tables() == 1
-    assert prov2.stats["q16_builds"] == 1
-    before = prov2.stats["q16_builds"]
-    assert prov2._q16_cached((kb,), 1, qx, qy) is not None
-    assert prov2.stats["q16_builds"] == before  # served from cache
+    # "restarted peer": a fresh provider over the same dir reads the
+    # slab back during prewarm, so the first block's lookup is a HIT —
+    # zero builds on the serving path
+    prov2 = TPUProvider(warm_keys_dir=warm, use_g16=True, max_keys=4)
+    prov2.prewarm(buckets=(), wait_restore=True)
+    assert prov2.stats["key_slot_disk_loads"] == 1
+    assert prov2.stats["key_slots_resident"] == 1
+    _admit(prov2, kb)
+    assert prov2.stats["key_slot_hits"] == 1
+    assert prov2.stats["key_slot_builds"] == 0 and len(builds) == 1
 
 
-def test_prewarm_invokes_table_rebuild(monkeypatch):
+def test_prewarm_invokes_the_restore(monkeypatch):
     """prewarm() (the node-assembly entry point) must reach
-    _prewarm_tables when the 16-bit path is enabled."""
+    _restore_slabs when the 16-bit path is enabled."""
     from fabric_tpu.ops import comb
     called = []
-    monkeypatch.setattr(TPUProvider, "_prewarm_tables",
+    monkeypatch.setattr(TPUProvider, "_restore_slabs",
                         lambda self: called.append(True) or 0)
     monkeypatch.setattr(comb, "g16_tables", lambda: None)
     prov = TPUProvider(use_g16=True)
-    prov.prewarm(buckets=(), key_counts=(), wait_restore=True)
+    prov.prewarm(buckets=(), wait_restore=True)
     assert called
 
 
-def test_corrupt_warm_file_ignored(tmp_path):
+def test_files_that_are_no_slabs_are_ignored(tmp_path, monkeypatch):
+    _stub_builders(monkeypatch, [])
     warm = str(tmp_path / "warm")
     os.makedirs(warm)
-    with open(os.path.join(warm, "warm_keysets.json"), "w") as f:
-        f.write("{not json")
-    prov = TPUProvider(warm_keys_dir=warm, use_g16=True)
-    assert prov._load_warm_keys() == []
-    assert prov._prewarm_tables() == 0
+    kb = bytes(range(64))
+    for name, body in (
+            ("warm_keysets.json", b"{not json"),        # an old index
+            ("slab16_zz.npy", b"junk"),                 # no key in it
+            (f"slab16_{kb.hex()}.npy", b"not an array"),
+            (f"slab8_{kb.hex()}.npy", b"another width")):
+        with open(os.path.join(warm, name), "wb") as f:
+            f.write(body)
+    prov = TPUProvider(warm_keys_dir=warm, use_g16=True, max_keys=4)
+    assert prov._restore_slabs() == 0
+    assert prov.stats["key_slots_resident"] == 0
+    # and the key still builds when a batch asks for it
+    _admit(prov, kb)
+    assert prov.stats["key_slot_builds"] == 1

@@ -48,7 +48,8 @@ def _provider():
 def programs(lanes: int, K: int, dev):
     """name -> (jitted fn, argument shapes). Shapes mirror what
     `TPUProvider._dispatch_comb_digest` stages for one span of `lanes`
-    signatures over a K-slot key set."""
+    signatures against a key-table pool of K slots (the table builders
+    work at one key whatever K)."""
     import numpy as np
 
     from fabric_tpu.ops import comb, limb
@@ -62,7 +63,9 @@ def programs(lanes: int, K: int, dev):
     g0 = s((0, 3, L), i32)
 
     def digest(q16):
-        fn = _provider()._comb_pipeline_digest(K, q16)
+        prov = _provider()
+        prov._use_g16 = q16
+        fn = prov._comb_pipeline_digest()
         ent = ent16 if q16 else ent8
         return (fn, (s((lanes,), i32), s((ent * K, 3, L), i32),
                      g16 if q16 else g0, s((lanes, 32), u8),
@@ -72,18 +75,22 @@ def programs(lanes: int, K: int, dev):
     def qtab():
         import jax
         return (jax.jit(comb.build_q_tables),
-                (s((K, L), i32), s((K, L), i32)))
+                (s((1, L), i32), s((1, L), i32)))
 
     def g16tab():
         import jax
-        return (jax.jit(comb._combine_windows, static_argnums=2),
-                (s((ent8, 3, L), i32), s((comb.NWIN_G16,), i32),
-                 comb.NENT))
+        return (jax.jit(comb._combine_windows),
+                (s((ent8, 3, L), i32), s((comb.NWIN_G16,), i32)))
 
     def qtab16():
         import jax
-        return (jax.jit(comb.build_q16_tables, static_argnums=1),
-                (s((ent8 * K, 3, L), i32), K))
+        return (jax.jit(comb.build_q16_tables),
+                (s((ent8, 3, L), i32),))
+
+    def pool_write():
+        return (_provider()._pool_write_fn(),
+                (s((ent16 * K, 3, L), i32), s((ent16, 3, L), i32),
+                 s((), i32)))
 
     return {
         "digest_q16": lambda: digest(True),
@@ -91,6 +98,7 @@ def programs(lanes: int, K: int, dev):
         "g16": g16tab,
         "qtab8": qtab,
         "qtab16": qtab16,
+        "pool_write": pool_write,
     }
 
 
@@ -99,8 +107,9 @@ def main() -> int:
     ap.add_argument("names", nargs="*")
     ap.add_argument("--list", action="store_true")
     ap.add_argument("--lanes", type=int, default=32768)
-    ap.add_argument("--keys", type=int, default=4,
-                    help="key-slot bucket K (3 keys -> 4)")
+    ap.add_argument("--keys", type=int, default=13,
+                    help="slots of the key-table pool (13 = the "
+                         "shipped TableCacheMB at 16-bit windows)")
     ap.add_argument("--topology", default="v5e:2x2")
     args = ap.parse_args()
 

@@ -148,7 +148,8 @@ for _path, _funcs in {
     "fabric_tpu/ledger/blkstorage.py": ("add_block",),
     "fabric_tpu/core/fastvalidate.py": ("validate_fast",),
     "fabric_tpu/bccsp/tpu.py": ("_verify_prepared_device",
-                                "_dispatch_chunks"),
+                                "_dispatch_chunks", "_key_slots",
+                                "_admit_key"),
 }.items():
     REQUIRED_SPANS[_path] = REQUIRED_SPANS.get(_path, ()) + _funcs
 
