@@ -44,6 +44,9 @@ CONTROLS = ("accept_high_s", "skip_mvcc")
 TRACE_SECONDS = 4.0          # the profiler takes the window's last seconds
 REFERENCE_SIGNATURES = 31000  # OpenSSL re-verifies about this many
 STATE_READS = 200
+SUPPLY_WARN_SHARE = 0.85     # of a closed loop's backlog, used by one window
+SUPPLY_RULE = ("a benchmark PR sets its supply_tx_per_s to at least 1.5 x the "
+               "newest accepted median of the fastest cell that reads it")
 WORKERS = 8
 JOB_TXS = 512
 
@@ -128,9 +131,26 @@ def dispatches(before: dict, after: dict) -> int:
     return d["comb_batches"] - d["pipeline_batches"] + d["pipeline_chunks"]
 
 
-def run_window(intake, blocks, loop: dict, seconds: float, tracer=None):
+class WindowRanDry(RuntimeError):
+    """A closed loop handed in its last block before the window was
+    over: without a backlog there is no sustained rate to report, and
+    a window cut short would read a younger, faster chain."""
+
+    def __init__(self, supplied: int, txs: int, elapsed_s: float,
+                 seconds: float, source: str):
+        super().__init__(
+            f"the window ran dry: the {supplied} blocks ({txs} txs) that "
+            f"{source} supplies were done {elapsed_s:.2f} s into a "
+            f"{seconds:g} s window, {txs / elapsed_s:.1f} tx/s. No result: "
+            f"{SUPPLY_RULE}")
+
+
+def run_window(intake, blocks, loop: dict, seconds: float, tracer=None,
+               source: str = "the traffic file"):
     """Hand `blocks` over until the window closes. Returns the records
-    of the blocks handed in (or due), the window's start and end."""
+    of the blocks handed in (or due), the window's start and end. A
+    block that crosses the window's end closes it, the last of the
+    supply too; `WindowRanDry` where that one is done before the end."""
     records = []
     closed = loop["kind"] == "closed"
     interval = None if closed else loop["interval_ms"] / 1000.0
@@ -139,9 +159,8 @@ def run_window(intake, blocks, loop: dict, seconds: float, tracer=None):
     i = 0
     while True:
         if i >= len(blocks):
-            raise RuntimeError(
-                f"the window ran dry after {i} blocks: raise the traffic "
-                "file's supply")
+            raise WindowRanDry(i, sum(r.n_tx for r in records),
+                               time.perf_counter() - t0, seconds, source)
         block = blocks[i]
         rec = BlockRecord(block.header.number, len(block.data.data))
         due = time.perf_counter() if closed else t0 + i * interval
@@ -184,6 +203,20 @@ def run_window(intake, blocks, loop: dict, seconds: float, tracer=None):
             records.append(rec)
             i += 1
     return records, t0, max(t1, t0 + (0 if closed else seconds))
+
+
+def supply_used(supplied: int, handed: int, source: str) -> dict:
+    """How near a closed loop came to the end of its backlog, for the
+    result's `info`; past `SUPPLY_WARN_SHARE` a warning that names the
+    file and its rule."""
+    share = handed / supplied
+    if share > SUPPLY_WARN_SHARE:
+        log(f"WARNING: the window used {handed} of the {supplied} "
+            f"blocks that {source} supplies ({share:.2f} of them, over "
+            f"{SUPPLY_WARN_SHARE}); at 1.0 a run prints no result: "
+            f"{SUPPLY_RULE}")
+    return {"supply_blocks": supplied, "window_blocks": handed,
+            "supply_used_share": share}
 
 
 def end_to_end(manifest, cell_name: str, records, t0, t1, loop: dict,
@@ -398,6 +431,7 @@ def execute(manifest, cell, config, traffic, args):
     warm = synth.preload_blocks(traffic["transactions"], block_txs) + \
         int(traffic["warmup_blocks"])
     n_blocks = warm + blocks_needed(traffic, block_txs, args.seconds)
+    traffic_file = f"benchmark/traffic/{cell['traffic']}.json"
     phases = {}
 
     data_root = os.path.join(ROOT, ".cache", "bench-data")
@@ -536,7 +570,7 @@ def execute(manifest, cell, config, traffic, args):
         # -- the window
         host0 = host_snapshot()
         records, t0, t1 = run_window(intake, blocks[warm:], traffic["loop"],
-                                     args.seconds, tracer)
+                                     args.seconds, tracer, traffic_file)
         log("host before/after the window " + json.dumps(
             [host0, host_snapshot()]))
         took = [r.done - r.start for r in records if r.done is not None]
@@ -596,6 +630,9 @@ def execute(manifest, cell, config, traffic, args):
                                 tuple(c for c in args.control.split(",")
                                       if c))
         info["reference_s"] = round(time.perf_counter() - t, 3)
+        if traffic["loop"]["kind"] == "closed":
+            info.update(supply_used(n_blocks - warm, len(records),
+                                    traffic_file))
         correct = all(n <= limit for n, limit in numbers.values())
         result.update(correct=correct, metrics=metrics, device=device)
         result["info"] = info

@@ -59,6 +59,19 @@ def test_a_sound_run_is_correct():
     assert r["info"]["signatures_reverified"] > 0
 
 
+def test_a_run_says_how_much_of_its_supply_it_used():
+    r = drive(seed=2 ** 31 + 35)
+    info = r["info"]
+    # 6,000 tx/s of 48-transaction blocks over 0.3 s
+    assert info["supply_blocks"] == run.blocks_needed(
+        tiny_cell()[3], 48, 0.3) == 39
+    assert info["window_blocks"] == r["attempted"] >= 2
+    assert info["supply_used_share"] == \
+        info["window_blocks"] / info["supply_blocks"]
+    assert 0 < info["supply_used_share"] <= 1
+    assert list(r)[-2:] == ["info", "compared"]
+
+
 @pytest.mark.parametrize("control", run.CONTROLS)
 def test_the_control_is_not_correct(control):
     r = drive(control=control)

@@ -19,6 +19,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 from benchmark import identities, reference, synth, tracered, work  # noqa: E402
 from benchmark.readers import spans as span_readers  # noqa: E402
 from benchmark.readers import trace as trace_readers  # noqa: E402
+from benchmark import run  # noqa: E402
 from benchmark.run import BlockRecord, blocks_needed, percentile  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
@@ -150,7 +151,8 @@ def test_peaks_table_is_published_peaks_only():
 # ---- the synthesiser -------------------------------------------------------
 
 with open(os.path.join(BENCH, "traffic", "catchup-default-cut.json")) as _f:
-    MIX = dict(json.load(_f)["transactions"], keys=30, tampered_share=0.1)
+    TRAFFIC = json.load(_f)
+MIX = dict(TRAFFIC["transactions"], keys=30, tampered_share=0.1)
 
 
 def small_chain(seed: int, n_blocks: int = 2, block_txs: int = 24):
@@ -259,6 +261,91 @@ def test_blocks_needed_covers_the_window():
                          500, 10) == 41
 
 
+def test_the_shipped_supply_is_321_blocks_a_window():
+    assert TRAFFIC["loop"] == {"kind": "closed", "supply_tx_per_s": 4000}
+    assert blocks_needed(TRAFFIC, 500, MANIFEST["run_seconds"]) == 321
+    assert "1.5 x" in TRAFFIC["why"] and "1.5 x" in run.SUPPLY_RULE
+
+
+class Clock:
+    """`run`'s clock, moved only by the stand-in intake."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+
+class SteadyIntake:
+    """Every hand-over takes `block_s` on the clock it is given."""
+
+    def __init__(self, clock, block_s):
+        self.clock, self.block_s, self.handed = clock, block_s, []
+
+    def stats(self):
+        return {"comb_batches": len(self.handed)}
+
+    def hand_over(self, block):
+        self.handed.append(block.header.number)
+        self.clock.now += self.block_s
+
+
+def backlog(n, n_tx=500):
+    from types import SimpleNamespace as NS
+    return [NS(header=NS(number=23 + i), data=NS(data=[b""] * n_tx))
+            for i in range(n)]
+
+
+CLOSED = {"kind": "closed", "supply_tx_per_s": 4000}
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = Clock()
+    monkeypatch.setattr(run, "time", c)
+    return c
+
+
+def test_a_window_that_outruns_its_backlog_raises_window_ran_dry(clock):
+    # 4 blocks of 500 at 0.2 s a block are done 0.8 s into a 1 s window
+    with pytest.raises(run.WindowRanDry) as e:
+        run.run_window(SteadyIntake(clock, 0.2), backlog(4), CLOSED, 1.0,
+                       source="benchmark/traffic/catchup-default-cut.json")
+    assert isinstance(e.value, RuntimeError)
+    msg = str(e.value)
+    assert "the 4 blocks (2000 txs)" in msg and "0.80 s into a 1 s" in msg
+    assert "2500.0 tx/s" in msg and run.SUPPLY_RULE in msg
+    assert "benchmark/traffic/catchup-default-cut.json" in msg
+
+
+@pytest.mark.parametrize("n_blocks, block_s, handed", [
+    (6, 0.2, 5),      # one block to spare: the fifth ends the window
+    (5, 0.2, 5),      # the last of the supply ends ON the window's end
+    (4, 0.3, 4),      # the last of the supply CROSSES the end: a result
+])
+def test_a_window_that_its_backlog_outlasts_returns_normally(
+        clock, n_blocks, block_s, handed):
+    intake = SteadyIntake(clock, block_s)
+    records, t0, t1 = run.run_window(intake, backlog(n_blocks), CLOSED, 1.0)
+    assert len(records) == len(intake.handed) == handed
+    assert t1 - t0 == pytest.approx(handed * block_s) and t1 - t0 >= 1.0
+    assert all(r.served and r.dispatches == 1 for r in records)
+    report = run.supply_used(n_blocks, len(records), "f.json")
+    assert report == {"supply_blocks": n_blocks, "window_blocks": handed,
+                      "supply_used_share": handed / n_blocks}
+
+
+def test_a_window_near_the_end_of_its_backlog_warns(capsys):
+    run.supply_used(20, 17, "traffic/x.json")      # 0.85: not past it
+    assert "WARNING" not in capsys.readouterr().err
+    assert run.supply_used(20, 18, "traffic/x.json")[
+        "supply_used_share"] == pytest.approx(0.9)
+    err = capsys.readouterr().err
+    assert "WARNING" in err and "18 of the 20 blocks" in err
+    assert "traffic/x.json" in err and run.SUPPLY_RULE in err
+
+
 def records():
     out = []
     for i, lanes in enumerate((1500, 1500, 30720)):
@@ -356,6 +443,35 @@ def test_every_account_is_created_before_the_operations_start():
         assert [k for k, _ in p.writes] == [synth.key_name(i)]
         assert json.loads(p.writes[0][1])["checking_balance"] == 10 ** 6
     assert all(p.fn != "create_account" and p.reads for p in txs[300:])
+
+
+def test_a_chain_of_343_blocks_is_the_mix_the_traffic_file_states():
+    """The guard that a longer plan is the same traffic: the 343 blocks
+    a 40 s window at 4,000 tx/s plans (20 creating + 2 warm-up + 321),
+    as shipped."""
+    mix = TRAFFIC["transactions"]
+    pre = synth.preload_blocks(mix, 500)
+    n_blocks = pre + TRAFFIC["warmup_blocks"] + blocks_needed(TRAFFIC, 500, 40)
+    assert (pre, n_blocks) == (20, 343)
+    plans = synth.plan_chain(2 ** 31 + 35, n_blocks, 500, mix, 3)
+    assert [p.number for p in plans] == list(range(1, 344))
+    created = [p.args[0] for plan in plans[:pre] for p in plan.txs]
+    assert created == [synth.key_name(i) for i in range(mix["keys"])]
+    assert all(p.fn == "create_account" for plan in plans[:pre]
+               for p in plan.txs)
+    ops = plans[pre:]
+    assert not any(p.fn == "create_account" for plan in ops for p in plan.txs)
+    # the mix holds from the chain's head to its tail: by fifths
+    for k in range(5):
+        fifth = ops[k * len(ops) // 5:(k + 1) * len(ops) // 5]
+        n = 500 * len(fifth)
+        assert 0.03 <= sum(p.conflicts for p in fifth) / n <= 0.07
+        assert 0.005 <= sum(1 for plan in fifth for p in plan.txs
+                            if p.tamper) / n <= 0.015
+        fns = [p.fn for plan in fifth for p in plan.txs]
+        for fn in mix["functions"]:
+            assert fns.count(fn) / n == pytest.approx(0.2, abs=0.02)
+    assert all(0 < p.conflicts < 50 for p in ops)
 
 
 def test_smallbank_calls_read_and_write_the_accounts_they_name():

@@ -61,10 +61,17 @@ def test_configuration_states_the_consortium(cell):
     assert cut["deployment"] and cut["why_cut"]
 
 
-def test_the_provider_options_the_configuration_states_are_the_factorys():
+@pytest.mark.parametrize("name, keys", [("default-cut.catchup", 4),
+                                        (CELL, 5)])
+def test_the_provider_options_the_configuration_states_are_the_factorys(
+        name, keys):
     """`bccsp_assumed` is read by nobody: hold it to the program."""
     from fabric_tpu.bccsp import factory
-    config = run.load_cell(CELL)[2]
+    config = run.load_cell(name)[2]
+    assert config["channel"]["distinct_p256_keys_per_block"] == keys
+    assert f"this channel's {keys} keys fill {keys}" in \
+        config["bccsp_assumed"]["note"]
+    assert "2,048 lanes" in config["bccsp_assumed"]["note"]
     opts = factory.FactoryOpts.from_config(config["bccsp"])
     assumed = config["bccsp_assumed"]
     assert opts.tpu.max_keys == assumed["MaxKeys"]
@@ -137,6 +144,10 @@ def test_a_rehearsal_of_the_cell_is_correct(cell):
     assert all(v["value"] == 0 for v in r["compared"].values())
     assert set(r["metrics"]) == {"commit_tx_per_s", "setup_s"}
     assert r["info"]["signatures_reverified"] >= 1500
+    # 4,000 tx/s of the shipped traffic file over the 2 s window
+    assert r["info"]["supply_blocks"] == 17
+    assert r["info"]["window_blocks"] == r["attempted"]
+    assert 0 < r["info"]["supply_used_share"] <= 1
 
 
 @needs_native
