@@ -11,14 +11,20 @@ When and how bytes reach the disk — `journal_mode=WAL`,
 `synchronous=NORMAL`, sqlite's default `wal_autocheckpoint` and
 `page_size` — is the deployment's durability setting (the benchmark
 configuration's `ledger` group states it), and a change of speed is no
-reason to touch it. The page cache is not of that kind — it is only how
-much of the file a store keeps in memory — and it is left at sqlite's
-default (2 MB) for now: against a ledger's 17-20 MB file after 180
-default blocks that makes every block re-read ~3,400 leaves and spill
-~1,350 dirty pages to the WAL before its commit, and 64 MiB a store
-was measured to take 50 ms off a 280 ms block on the chip host's 9p
-root (PERF.md, Findings PR 31, which also says why it waits for the
-benchmark's block supply to be raised first).
+reason to touch it. The page cache is not of that kind: it is memory,
+not durability — how much of the file a store keeps by it, not what a
+commit writes or when it is synced — and every store asks for
+`CACHE_KIB` (64 MiB). A ledger's `index.db` is 17-20 MB after 180
+default blocks (block index, history and state are prefixes of the one
+file), so the cache holds all of it, where sqlite's default 2 MB made
+every block re-read ~3,400 leaves and spill ~1,350 dirty pages to the
+WAL before its commit (PERF.md, Findings PR 31 and PR 36). The bound on
+memory is 64 MiB a `KVStore`, allocated page by page as touched, so a
+small store pays for the pages it has: one store a channel's ledger,
+one an orderer channel, one the transient store. On a chain whose
+tx-id and history leaves have outgrown the cache (~600 default blocks)
+those leaves miss again, while the state table's leaves and every
+interior page stay hot.
 """
 
 from __future__ import annotations
@@ -26,6 +32,9 @@ from __future__ import annotations
 import sqlite3
 import threading
 from typing import Iterator, Optional
+
+# page cache of one store's connection, in KiB (sqlite's default is 2,000)
+CACHE_KIB = 65536
 
 
 class WriteBatch:
@@ -51,6 +60,7 @@ class KVStore:
         cur = self._conn.cursor()
         cur.execute("PRAGMA journal_mode=WAL")
         cur.execute("PRAGMA synchronous=NORMAL")
+        cur.execute(f"PRAGMA cache_size=-{CACHE_KIB}")
         cur.execute("CREATE TABLE IF NOT EXISTS kv "
                     "(k BLOB PRIMARY KEY, v BLOB NOT NULL) WITHOUT ROWID")
         self._conn.commit()

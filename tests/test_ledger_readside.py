@@ -9,6 +9,7 @@ codes, the same `UpdateBatch`, the same rows.
 
 import hashlib
 import random
+import shutil
 
 import pytest
 
@@ -17,7 +18,7 @@ from fabric_tpu.common import tracing
 from fabric_tpu.ledger import KVLedger
 from fabric_tpu.ledger import pvtdata as pvt
 from fabric_tpu.ledger.history import HistoryDB
-from fabric_tpu.ledger.kvdb import DBHandle, KVStore
+from fabric_tpu.ledger.kvdb import CACHE_KIB, DBHandle, KVStore, WriteBatch
 from fabric_tpu.ledger.statedb import (
     Height,
     StateDB,
@@ -566,6 +567,123 @@ class TestStatementCount:
         finally:
             tracing.configure(enabled=True, ring_size=4096)
             tracing.reset()
+
+
+class TestPageCache:
+    """A store keeps its working set in memory (`CACHE_KIB`): how much
+    of the file it holds, not what a commit writes or when."""
+
+    def test_cache_size_moves_and_the_durability_settings_do_not(
+            self, tmp_path):
+        def pragma(store, name):
+            return store._conn.execute("PRAGMA " + name).fetchone()[0]
+        store = KVStore(str(tmp_path / "index.db"))
+        store.put(b"k", b"v")
+        assert CACHE_KIB == 65536
+        assert pragma(store, "cache_size") == -CACHE_KIB
+        assert pragma(store, "journal_mode") == "wal"
+        assert pragma(store, "synchronous") == 1
+        assert pragma(store, "wal_autocheckpoint") == 1000
+        assert pragma(store, "page_size") == 4096
+        assert pragma(store, "locking_mode") == "normal"
+        store.close()
+        mem = KVStore(":memory:")
+        mem.put(b"k", b"v")
+        assert mem.get(b"k") == b"v"
+        assert pragma(mem, "cache_size") == -CACHE_KIB
+        mem.close()
+
+    @staticmethod
+    def _key(i: int) -> bytes:
+        return hashlib.sha256(b"%d" % i).digest()
+
+    def _rounds(self, store: KVStore, rows: int):
+        """Ten rounds of what a block asks of the file: one 500-key
+        `get_many` and one 700-row `write_batch` on random keys.
+        -> (what the reads answered, the thread's syscr a round)."""
+        rng = random.Random(36)
+        answers = []
+        sp = tracing.span("commit.commit")
+        with sp, tracing.thread_io(sp):
+            for rnd in range(10):
+                got = store.get_many(
+                    [self._key(rng.randrange(rows)) for _ in range(500)])
+                answers.append(sorted(got.items()))
+                batch = WriteBatch()
+                for _ in range(700):
+                    batch.put(self._key(rng.randrange(rows)),
+                              b"r%02d" % rnd + bytes(196))
+                store.write_batch(batch)
+        ev = [e for e in tracing.snapshot() if e[1] == "commit.commit"][-1]
+        syscr = (ev[8] or {}).get("syscr")
+        return answers, None if syscr is None else syscr / 10
+
+    def test_working_set_is_read_once_not_every_round(self, tmp_path):
+        """The mechanism by its counter: on a file past 8 MB, once
+        scanned, the rounds' read system calls stay under a third of
+        what the same rounds cost a connection left at sqlite's default
+        2 MB (what is left is the auto-checkpoint reading the WAL's
+        frames back)."""
+        rows, per_batch = 33000, 1375
+        seed = KVStore(str(tmp_path / "seed.db"))
+        for lo in range(0, rows, per_batch):
+            batch = WriteBatch()
+            for i in range(lo, lo + per_batch):
+                batch.put(self._key(i), bytes(200))
+            seed.write_batch(batch)
+        seed.close()        # the last connection checkpoints the WAL
+        assert (tmp_path / "seed.db").stat().st_size > 8 << 20
+        shutil.copy(tmp_path / "seed.db", tmp_path / "small.db")
+        cached = KVStore(str(tmp_path / "seed.db"))
+        small = KVStore(str(tmp_path / "small.db"))
+        small._conn.execute("PRAGMA cache_size=-2000")
+        assert list(cached.iterate()) == list(small.iterate())
+        tracing.configure(enabled=True, ring_size=4096, sample_every=1)
+        tracing.reset()
+        try:
+            got_small, reads_small = self._rounds(small, rows)
+            got_cached, reads_cached = self._rounds(cached, rows)
+        finally:
+            tracing.configure(enabled=True, ring_size=4096)
+            tracing.reset()
+        assert got_cached == got_small
+        if tracing._thread_io() is None:
+            assert reads_cached is None and reads_small is None
+        else:
+            assert reads_small > 1000
+            assert reads_cached < reads_small / 3
+        cached.close()
+        small.close()
+
+    def test_second_connection_writes_are_seen_through_the_cache(
+            self, tmp_path):
+        """`ledgerutil` / `nodeops` open a running node's `index.db`
+        from a second process: what the second store commits, the
+        first reads, though its cache held the old pages."""
+        path = str(tmp_path / "index.db")
+        node = KVStore(path)
+        batch = WriteBatch()
+        for i in range(2000):
+            batch.put(self._key(i), b"old")
+        node.write_batch(batch)
+        keys = [self._key(i) for i in range(0, 2000, 4)]
+        assert set(node.get_many(keys).values()) == {b"old"}
+        tool = KVStore(path)
+        batch = WriteBatch()
+        for k in keys:
+            batch.put(k, b"new")
+        batch.delete(self._key(1))
+        batch.put(b"added", b"by the tool")
+        tool.write_batch(batch)
+        assert set(node.get_many(keys).values()) == {b"new"}
+        assert node.get(self._key(1)) is None
+        assert node.get(self._key(2)) == b"old"
+        assert node.get(b"added") == b"by the tool"
+        node.put(b"added", b"by the node")
+        assert tool.get(b"added") == b"by the node"
+        assert list(tool.iterate()) == list(node.iterate())
+        tool.close()
+        node.close()
 
 
 def _kv(txrw: rwpb.TxReadWriteSet) -> rwpb.KVRWSet:
