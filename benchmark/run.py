@@ -41,7 +41,8 @@ from benchmark import identities, reference, sut, synth  # noqa: E402
 from benchmark.readers.spans import percentile  # noqa: E402,F401
 
 CONTROLS = ("accept_high_s", "skip_mvcc")
-TRACE_SECONDS = 4.0          # the profiler takes the window's last seconds
+TRACE_SECONDS = 4.0          # the profiler takes the window's last seconds,
+TRACE_BLOCKS = 2             # or this many blocks where they take longer
 REFERENCE_SIGNATURES = 31000  # OpenSSL re-verifies about this many
 STATE_READS = 200
 SUPPLY_WARN_SHARE = 0.85     # of a closed loop's backlog, used by one window
@@ -555,7 +556,8 @@ def execute(manifest, cell, config, traffic, args):
         if args.trace:
             from benchmark import spans
             tracer = spans.Tracer(intake, os.path.join(data_dir, "trace"),
-                                  min(TRACE_SECONDS, args.seconds))
+                                  min(TRACE_SECONDS, args.seconds),
+                                  TRACE_BLOCKS)
         # the chain, the plans and the peer's start-up objects are here
         # to stay: keep them out of the collector's later passes, so
         # that a pass inside the window walks the window's garbage only
@@ -578,13 +580,13 @@ def execute(manifest, cell, config, traffic, args):
                   for k in range(5)]
         log("seconds per block over the window, by fifths: " + json.dumps(
             [round(sum(f) / len(f), 4) for f in fifths if f]))
-        t = time.perf_counter()
-        trace = tracer.finish() if tracer is not None else None
+        trace = None
         if tracer is not None:
+            trace = tracer.finish(need_device=not args.rehearse)
             log(f"trace of the last {tracer.traced_blocks} blocks stopped "
                 f"in {tracer.trace_stop_s:.1f}s, read in "
-                f"{tracer.trace_load_s:.1f}s")
-            del t
+                f"{tracer.trace_load_s:.1f}s; the profiler started "
+                f"{tracer.trace_lead_s:.2f}s before the window's end")
         after = intake.stats()
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices[:int(cell["chips"])])
@@ -612,8 +614,8 @@ def execute(manifest, cell, config, traffic, args):
                    "trace": trace, "device_kind": dev.device_kind,
                    "provider": csp}
             from benchmark import tracered
-            bw = tracered.busy_and_window(trace) if trace else None
-            if bw is not None:
+            bw = tracer.busy_and_window
+            if bw is not None:      # a rehearsal's CPU has no device plane
                 device["busy_s"], device["window_s"] = bw
                 result["breakdown"] = {
                     "device_ops": tracered.op_times(trace),
