@@ -1045,7 +1045,6 @@ class TPUProvider(api.BCCSP):
             # transfer-minimal digest pipeline — compact u8 scalars,
             # on-device limb conversion, no SHA stage at all
             if 0 < len(key_map) <= self._key_capacity():
-                self.stats["comb_batches"] += 1
                 out = self._dispatch_comb_digest(
                     bucket, key_map, key_idx, r_b, rpn_b, w_b,
                     premask, digests)
@@ -1110,7 +1109,6 @@ class TPUProvider(api.BCCSP):
         from fabric_tpu.ops import limb
 
         if 0 < len(key_map) <= self._key_capacity():
-            self.stats["comb_batches"] += 1
             thunk = self._dispatch_comb(
                 bucket, key_map, key_idx, blocks, nblocks, r_l, rpn_l,
                 w_l, premask, digests, has_digest, async_out=True)
@@ -1622,9 +1620,7 @@ class TPUProvider(api.BCCSP):
         t_done = _time.perf_counter()
         device_s = dispatch_s + (t_done - t0)
 
-        self.stats["comb_batches"] += 1
-        self.stats["pipeline_batches"] += 1
-        self.stats["pipeline_chunks"] += nspans
+        self._book_comb(nspans)
         self.stats["pipeline_host_s"] = round(host_s, 6)
         self.stats["pipeline_transfer_s"] = round(transfer_s, 6)
         self.stats["pipeline_device_s"] = round(device_s, 6)
@@ -1847,7 +1843,6 @@ class TPUProvider(api.BCCSP):
             stage.set(keys=len(key_map))
 
         if comb:
-            self.stats["comb_batches"] += 1
             thunk = self._dispatch_comb_digest(
                 bucket, key_map, lane_slot, *scalars, premask, dg,
                 async_out=True)
@@ -2407,29 +2402,44 @@ class TPUProvider(api.BCCSP):
             chunk = span
         return chunk
 
+    def _book_comb(self, spans: int) -> None:
+        """Book one batch of a comb program that ran `spans`
+        executions of it, as counted by the loop that enqueued them.
+        Every path books so, whatever cut the batch:
+        `comb_batches` - `pipeline_batches` + `pipeline_chunks` is the
+        executions (on each chip of a mesh), which the benchmark holds
+        to the device trace."""
+        self.stats["comb_batches"] += 1
+        if spans > 1:
+            self.stats["pipeline_batches"] += 1
+            self.stats["pipeline_chunks"] += spans
+
     @hot_path
-    @tracing.traced("tpu.comb_digest")
     def _dispatch_comb_digest(self, bucket, key_map, key_idx, r8, rpn8,
                               w8, premask, digests, async_out=False):
         """Digest-lane comb dispatch: compact u8 scalar operands, limb
         conversion ON DEVICE, no SHA stage (_comb_pipeline_digest) —
         the transfer-minimal shape for the host-hash default and the
         prepared-block fast path."""
-        lockcheck.note_blocking("tpu.dispatch")
-        faults.check("tpu.dispatch")
         # above the span this is the overlapped item path's span
         # shape: one compiled program serves both paths
         chunk = self._mesh_chunk(bucket)
-        # the pool lock from the slot lookup to the last enqueue: a
-        # concurrent batch's table write donates the array in hand
-        with self._pool_lock:
-            key_idx, table, g16 = self._key_slots(key_map, key_idx)
-            fn = self._comb_pipeline_digest()
-            thunk = self._dispatch_chunks(
-                bucket, chunk,
-                (key_idx, r8, rpn8, w8, premask, digests),
-                lambda c: fn(c[0], table, g16, *c[1:]))
-        return thunk if async_out else thunk()
+        sp = tracing.span("tpu.comb_digest")
+        with sp:
+            lockcheck.note_blocking("tpu.dispatch")
+            faults.check("tpu.dispatch")
+            # the pool lock from the slot lookup to the last enqueue: a
+            # concurrent batch's table write donates the array in hand
+            with self._pool_lock:
+                key_idx, table, g16 = self._key_slots(key_map, key_idx)
+                fn = self._comb_pipeline_digest()
+                thunk, spans = self._dispatch_chunks(
+                    bucket, chunk,
+                    (key_idx, r8, rpn8, w8, premask, digests),
+                    lambda c: fn(c[0], table, g16, *c[1:]))
+            self._book_comb(spans)
+            sp.set(chunks=spans)
+            return thunk if async_out else thunk()
 
     @hot_path
     def _dispatch_chunks(self, bucket, chunk, operands, run):
@@ -2441,7 +2451,7 @@ class TPUProvider(api.BCCSP):
         program on one chunk's staged operands. One `tpu.h2d` and one
         `tpu.enqueue` span a chunk; the thunk returned holds `tpu.wait`
         (the host blocked on the device, nothing else) and
-        `tpu.readback`.
+        `tpu.readback`; it is returned with the executions enqueued.
         The spans' own clock readings feed the prepared_* gauges."""
         import jax
 
@@ -2503,7 +2513,7 @@ class TPUProvider(api.BCCSP):
             self.stats["prepared_device_s"] = round(
                 dispatch_s + readback.t1 - wait.t0, 6)
             return out
-        return thunk
+        return thunk, len(outs)
 
     @hot_path
     @tracing.traced("tpu.comb")
@@ -2533,6 +2543,7 @@ class TPUProvider(api.BCCSP):
                     stage(w_l[lo:hi]), stage(premask[lo:hi]),
                     stage(digests[lo:hi]),
                     stage(has_digest[lo:hi])))
+        self._book_comb(len(outs))
         thunk = lambda: np.concatenate(  # noqa: E731
             # ftpu-lint: allow-host-sync(deliberate materialization)
             [np.asarray(o) for o in outs])

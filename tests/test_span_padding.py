@@ -186,13 +186,96 @@ def test_every_batch_size_runs_whole_spans_of_one_shape(n, path):
     if path == "prepared":
         assert booked["lanes_real"] == n
         assert booked["lanes_padded"] == spans * SPAN
-        # one batch, however many spans: the span counters are the
-        # overlapped item path's alone
-        assert booked["pipeline_batches"] == booked["pipeline_chunks"] == 0
-    else:
-        # executions as the benchmark reads them off the counters
-        assert (booked["comb_batches"] - booked["pipeline_batches"]
-                + booked["pipeline_chunks"]) == spans
+    # executions as the benchmark reads them off the counters, on both
+    # paths
+    assert (booked["comb_batches"] - booked["pipeline_batches"]
+            + booked["pipeline_chunks"]) == spans
+
+
+@pytest.mark.parametrize("path", ["prepared", "item", "device_hash"])
+@pytest.mark.parametrize("n, spans", [(1500, 1), (3000, 2), (30000, 15)])
+def test_the_counters_book_every_execution_of_the_program(n, spans, path):
+    """What the benchmark holds to the device trace: `comb_batches` -
+    `pipeline_batches` + `pipeline_chunks` is the number of calls of
+    the jitted program, for one span, two, and the north-star block's
+    fifteen (30,000 signatures: the last span 1,328 real lanes) — on
+    the prepared path, the item path, and the item path that hashes
+    on the device (`HashOnHost: false`, the `comb` program, whose
+    stand-in here reads only the premask: its verdicts are not
+    compared)."""
+    prov, _ = _provider(hash_on_host=path != "device_hash")
+    prov._comb_pipeline = lambda: prov._jit(
+        "comb", lambda blocks, nblocks, key_idx, q_flat, g16, r, rpn, w,
+        premask, digests, has_digest: premask)
+    called = []
+    jit = prov._jit
+
+    def counting_jit(kind, fn, *a, **kw):
+        program = jit(kind, fn, *a, **kw)
+        if kind not in ("comb_digest", "comb"):
+            return program
+
+        def counted(*args):
+            called.append(len(args[0]))
+            return program(*args)
+        return counted
+
+    prov._jit = counting_jit
+    items, want = _batch(n)
+    got = _run(prov, "prepared" if path == "prepared" else "item", items)
+    assert len(got) == n
+    if path != "device_hash":
+        assert got == want
+    booked = (prov.stats["comb_batches"] - prov.stats["pipeline_batches"]
+              + prov.stats["pipeline_chunks"])
+    assert booked == len(called) == spans
+    assert called == [SPAN] * spans
+    assert prov.stats["pipeline_batches"] == (spans > 1)
+
+
+TAMPERED = {3: "message", 7: "wrong_key", 11: "high_s", 15: "bad_der"}
+
+
+def test_three_spans_of_the_real_program_agree_with_openssl_lane_by_lane():
+    """The provider's own program on the CPU backend, at spans of 128
+    lanes: a prepared batch of 300 signatures runs 3 executions, the
+    third 44 real lanes and 84 padded, with tampered lanes of every
+    kind in the first span and in the last. Its verdicts are the
+    benchmark's plain reference's (OpenSSL, strict DER, low-S), lane
+    by lane."""
+    from cryptography.hazmat.primitives.asymmetric import ec
+
+    from benchmark import reference
+
+    faults.clear()
+    span, n = 128, 300
+    prov = TPUProvider(min_batch=MIN_BATCH, pipeline_chunk=span)
+    entries = _unique()[0]
+    items = [entries[j % PERIOD] for j in range(n)]
+    for lanes in (range(0, span), range(2 * span, n)):
+        assert {TAMPERED.get(j % PERIOD % 16) for j in lanes} >= \
+            set(TAMPERED.values())
+
+    got = prov.verify_prepared(*_prepared_args(items))
+
+    verifier = reference.Verifier({})
+
+    def openssl(it):
+        pub = ec.EllipticCurvePublicNumbers(
+            int.from_bytes(it.key.x_bytes().tobytes(), "big"),
+            int.from_bytes(it.key.y_bytes().tobytes(), "big"),
+            ec.SECP256R1()).public_key()
+        return verifier.signature_ok(pub, it.signature, it.message)
+
+    want = [openssl(it) for it in items]
+    assert got == want
+    assert not any(want[j] for j in range(n)
+                   if j % PERIOD % 16 in TAMPERED)
+    assert sum(want) > n // 2
+    assert prov.stats["lanes_padded"] == 3 * span
+    assert (prov.stats["comb_batches"], prov.stats["pipeline_batches"],
+            prov.stats["pipeline_chunks"]) == (1, 1, 3)
+    assert prov.stats["sw_fallbacks"] == prov.stats["ladder_batches"] == 0
 
 
 def test_buckets_above_the_span_are_whole_spans_not_powers_of_two():
