@@ -47,8 +47,9 @@ EPILOGUE = """\
   `tpu.enqueue`, `tpu.wait`, `tpu.readback`, `intake.rwsets`,
   `intake.txids`, `commit.commit`, `commit.pvt`, `commit.notify`,
   `ledger.mvcc`, `ledger.blockstore`, `blockstore.append`,
-  `blockstore.index`, `ledger.history`, `ledger.state`, `runtime.gc`.
-  Once JAX is imported every span is also a
+  `blockstore.index`, `ledger.settle`, `ledger.history`,
+  `ledger.state`, `runtime.gc`; and on the ledger store's checkpoint
+  thread, outside any block's tree, `ledger.checkpoint`. Once JAX is imported every span is also a
   `jax.profiler.TraceAnnotation`, so a `/debug/jax/trace` capture
   carries them on a host line beside the device operations.
 """

@@ -131,11 +131,14 @@ class BlockStore:
 
     # -- writes --
 
-    def add_block(self, block: common.Block, tx_ids=None) -> int:
+    def add_block(self, block: common.Block, tx_ids=None,
+                  before_index=None) -> int:
         """`tx_ids` optionally reuses the intake path's single tx-id
         scan (`block_tx_ids`) so the index build does not re-scan
         every envelope — the measured commit floor at 10k-tx blocks.
-        Returns the bytes appended to the block file."""
+        `before_index`, if given, is called between the block file's
+        fsync and the index write. Returns the bytes appended to the
+        block file."""
         if block.header.number != self._height:
             raise BlockStoreError(
                 f"expected block {self._height}, got {block.header.number}")
@@ -159,6 +162,8 @@ class BlockStore:
             append.set(bytes=4 + len(raw))
         self._height = block.header.number + 1
         self._last_hash = pu.block_header_hash(block.header)
+        if before_index is not None:
+            before_index()
         index = tracing.span("blockstore.index")
         with index:
             index.set(rows=self._index_block(

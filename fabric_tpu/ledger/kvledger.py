@@ -73,6 +73,10 @@ class KVLedger:
         self._dir = ledger_dir
         os.makedirs(ledger_dir, exist_ok=True)
         self._kv = KVStore(os.path.join(ledger_dir, "index.db"))
+        # the WAL is checkpointed behind the committer and settled
+        # before each block's first write (`_settle`)
+        self._kv.checkpoint_behind()
+        self._settled = True
         self.block_store = BlockStore(
             ledger_dir, DBHandle(self._kv, "blkindex"))
         # pluggable state DB (reference statedb.go VersionedDB): the
@@ -304,6 +308,7 @@ class KVLedger:
         validation codes."""
         n = len(block.data.data)
         block_num = block.header.number
+        self._settled = False
         # one span per stretch, each boundary's clock read once: the
         # spans' own readings feed the histograms and the log line
         # below (with tracing disabled `timed` still reads the clock)
@@ -348,7 +353,7 @@ class KVLedger:
         store = tracing.timed("ledger.blockstore")
         with store:
             store.set(bytes=self.block_store.add_block(
-                block, tx_ids=tx_ids))
+                block, tx_ids=tx_ids, before_index=self._settle))
             self._commit_hash = new_commit_hash
 
         state = tracing.timed("ledger.state")
@@ -411,7 +416,9 @@ class KVLedger:
         its recorded TRANSACTIONS_FILTER as upstream flags. Private
         cleartext is replayed from the pvt store (written before the
         state apply, so it survives the crash being recovered from)."""
+        self._settled = False
         if self._is_config_block(block) or block.header.number == 0:
+            self._settle()
             self.state_db.apply_updates(
                 UpdateBatch(), Height(block.header.number, 0))
             return
@@ -432,11 +439,23 @@ class KVLedger:
             block_num, rwsets, flags, pvt_data)
         # same history/listener-before-savepoint ordering as
         # commit_block
+        self._settle()
         self.history_db.commit_block(block, codes, parsed)
         self._notify_state_listeners(block_num, batch)
         self.state_db.apply_updates(
             batch, Height(block_num, max(len(rwsets) - 1, 0)))
         self._drop_expired_bookkeeping(block_num)
+
+    def _settle(self) -> None:
+        """Before a block's first write to the store, once a block:
+        every checkpoint the earlier commits requested has finished
+        (`KVStore.checkpoint_behind`). The block's own reads, MVCC's
+        bulk read among them, ran beside the checkpoint."""
+        if self._settled:
+            return
+        self._settled = True
+        with tracing.span("ledger.settle"):
+            self._kv.settle()
 
     def _validate_and_prepare(
             self, block_num: int, rwsets, flags, pvt_data: dict
@@ -524,6 +543,7 @@ class KVLedger:
             self.pvt_store.record_expiry(store_batch, exp_block,
                                          block_num, expiry[exp_block])
         if store_batch.ops:
+            self._settle()
             self.pvt_store._db.write_batch(store_batch)
 
         # fold purges of entries that expire AT this block into the

@@ -409,6 +409,7 @@ INTAKE_TREE = {
     "ledger.blockstore": "commit.commit",
     "blockstore.append": "ledger.blockstore",
     "blockstore.index": "ledger.blockstore",
+    "ledger.settle": "ledger.blockstore",
     "ledger.history": "commit.commit",
     "ledger.state": "commit.commit",
     "commit.notify": "commit.commit",
