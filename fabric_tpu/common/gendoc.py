@@ -48,8 +48,10 @@ EPILOGUE = """\
   `intake.txids`, `commit.commit`, `commit.pvt`, `commit.notify`,
   `ledger.mvcc`, `ledger.blockstore`, `blockstore.append`,
   `blockstore.index`, `ledger.settle`, `ledger.history`,
-  `ledger.state`, `runtime.gc`; and on the ledger store's checkpoint
-  thread, outside any block's tree, `ledger.checkpoint`. Once JAX is imported every span is also a
+  `ledger.state`, `kvdb.write` and its `kvdb.commit` (one each a
+  commit of a store: three a block), `runtime.gc`; and on the ledger
+  store's checkpoint thread, outside any block's tree,
+  `ledger.checkpoint`. Once JAX is imported every span is also a
   `jax.profiler.TraceAnnotation`, so a `/debug/jax/trace` capture
   carries them on a host line beside the device operations.
 """

@@ -509,19 +509,32 @@ def timed(name: str, **attrs):
     return _Span(name, None, attrs or None)
 
 
-def _thread_io() -> Optional[tuple[int, int]]:
-    """(syscr, syscw) of the calling thread: the read and write system
-    calls it has made. None where the kernel keeps no such account."""
+def _thread_io() -> Optional[tuple[int, int, int]]:
+    """(syscr, syscw, wchar) of the calling thread: the read and write
+    system calls it has made and the bytes it has handed to the
+    writes. None where the kernel keeps no such account. One `read`
+    call, counted on the thread (`_tls.io_reads`) so that `thread_io`
+    can take the tracer's own reads out of a span's `syscr`: the
+    kernel books a read after it has filled the buffer, so each
+    reading shows every read before it but its own."""
     try:
-        with open("/proc/thread-self/io", "rb") as f:
-            fields = dict(line.split(b": ") for line in f)
-        return int(fields[b"syscr"]), int(fields[b"syscw"])
+        fd = os.open("/proc/thread-self/io", os.O_RDONLY)
+    except OSError:
+        return None
+    try:
+        _tls.io_reads = getattr(_tls, "io_reads", 0) + 1
+        f = os.read(fd, 512).split()
+        fields = dict(zip(f[::2], f[1::2]))
+        return (int(fields[b"syscr:"]), int(fields[b"syscw:"]),
+                int(fields[b"wchar:"]))
     except (OSError, KeyError, ValueError):
         return None
+    finally:
+        os.close(fd)
 
 
 @contextlib.contextmanager
-def thread_io(sp):
+def thread_io(sp, book=None):
     """Book on span `sp`, as `syscr` / `syscw`, the read and write
     system calls the calling thread makes inside the block:
 
@@ -531,14 +544,23 @@ def thread_io(sp):
 
     What a stretch asks of the file system, where the time it takes
     there depends on the host (a network file system under sqlite).
-    Nothing is read with tracing disabled."""
+    `syscr` leaves out the reads of `/proc` that this span and every
+    `thread_io` nested in it made. `book(sp, syscr, syscw, wchar)`, if
+    given, books the deltas instead (`wchar`: the bytes handed to the
+    writes). Nothing is read with tracing disabled."""
+    reads0 = getattr(_tls, "io_reads", 0)
     io0 = _thread_io() if _state.enabled else None
     try:
         yield
     finally:
+        own = getattr(_tls, "io_reads", 0) - reads0
         io1 = _thread_io() if io0 is not None else None
         if io1 is not None:
-            sp.set(syscr=io1[0] - io0[0], syscw=io1[1] - io0[1])
+            d = (io1[0] - io0[0] - own, io1[1] - io0[1], io1[2] - io0[2])
+            if book is None:
+                sp.set(syscr=d[0], syscw=d[1])
+            else:
+                book(sp, *d)
 
 
 def traced(name: str):

@@ -55,6 +55,7 @@ interior page stay hot.
 
 from __future__ import annotations
 
+import contextlib
 import sqlite3
 import threading
 import weakref
@@ -197,6 +198,9 @@ class KVStore:
             return
         with self._lock:
             self._conn.execute("PRAGMA wal_autocheckpoint=0")
+            # a WAL frame: a 24-byte header and a page
+            self._frame_bytes = self._conn.execute(
+                "PRAGMA page_size").fetchone()[0] + 24
         ckpt = self._ckpt = _Checkpointer(self._path, self._lock)
         # a store dropped without close() takes its thread with it
         self._stop_ckpt = weakref.finalize(self, ckpt.stop)
@@ -232,20 +236,42 @@ class KVStore:
                     out[bytes(k)] = bytes(v)
         return out
 
+    def _book_frames(self, sp, syscr: int, syscw: int, wchar: int) -> None:
+        sp.set(frames=wchar // self._frame_bytes)
+
+    @contextlib.contextmanager
+    def _writing(self, ops: int) -> Iterator[sqlite3.Connection]:
+        """The one commit path of the store (`put`, `delete`,
+        `write_batch`): the body's statements under the writer's lock,
+        then the commit. A `kvdb.write` span covers the whole — `ops`,
+        the rows put or deleted, and, on a store that checkpoints
+        behind, `frames`: the WAL frames the commit appended (the
+        thread's bytes written over the span, a cache spill during the
+        statements included, over a frame's size). A store that
+        checkpoints inline reads nothing: a commit there may copy pages
+        into the file as well. Its child `kvdb.commit` is sqlite's
+        writing of the frames; the span's self time is the lock, the
+        statements, the B-tree work in the page cache and, where
+        `frames` is booked, the two readings of it."""
+        sp = tracing.span("kvdb.write", ops=ops)
+        io = tracing.thread_io(sp, self._book_frames) \
+            if self._ckpt is not None else contextlib.nullcontext()
+        with sp, io, self._lock:
+            yield self._conn
+            with tracing.span("kvdb.commit"):
+                self._conn.commit()
+        self._committed()
+
     def put(self, key: bytes, value: bytes) -> None:
-        with self._lock:
-            self._conn.execute(
+        with self._writing(1) as conn:
+            conn.execute(
                 "INSERT INTO kv(k, v) VALUES(?, ?) "
                 "ON CONFLICT(k) DO UPDATE SET v = excluded.v",
                 (key, value))
-            self._conn.commit()
-        self._committed()
 
     def delete(self, key: bytes) -> None:
-        with self._lock:
-            self._conn.execute("DELETE FROM kv WHERE k = ?", (key,))
-            self._conn.commit()
-        self._committed()
+        with self._writing(1) as conn:
+            conn.execute("DELETE FROM kv WHERE k = ?", (key,))
 
     def write_batch(self, batch: WriteBatch, sync: bool = True) -> None:
         """Atomic multi-op commit (leveldb WriteBatch semantics).
@@ -254,9 +280,9 @@ class KVStore:
         Python→SQLite call per run, not per op (a 10k-tx block's index
         batch is ~10k puts; per-op execute was a measured slice of the
         commit floor). Runs preserve put/delete ordering per key."""
-        with self._lock:
-            cur = self._conn.cursor()
-            ops = batch.ops
+        ops = batch.ops
+        with self._writing(len(ops)) as conn:
+            cur = conn.cursor()
             i, n = 0, len(ops)
             while i < n:
                 j = i
@@ -272,8 +298,6 @@ class KVStore:
                         "ON CONFLICT(k) DO UPDATE SET v = excluded.v",
                         ops[i:j])
                 i = j
-            self._conn.commit()
-        self._committed()
 
     def iterate(self, start: bytes = b"", end: Optional[bytes] = None
                 ) -> Iterator[tuple[bytes, bytes]]:

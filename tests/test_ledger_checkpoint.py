@@ -134,6 +134,61 @@ def test_the_committer_reads_no_wal_frames_back(fed):
     assert sum(inline[-10:]) > 10 * 100, inline[-10:]
 
 
+def _commits(events) -> dict:
+    """{`commit.commit` span id: [(`kvdb.write` event, its parent's
+    name)]}, and every `kvdb.commit` checked to be a child of a
+    `kvdb.write`."""
+    by_id = {e[3]: e for e in events}
+    out = {e[3]: [] for e in events if e[1] == "commit.commit"}
+    for e in events:
+        if e[1] == "kvdb.commit":
+            assert by_id[e[4]][1] == "kvdb.write"
+        if e[1] != "kvdb.write":
+            continue
+        up = by_id.get(e[4])
+        parent = up[1] if up is not None else None
+        while up is not None and up[1] != "commit.commit":
+            up = by_id.get(up[4])
+        if up is not None:
+            out[up[3]].append((e, parent))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["behind", "inline"])
+def test_every_commit_of_a_block_is_booked(fed, mode):
+    """Each block's three commits are one `kvdb.write` each, under the
+    span that names the keyspace, with one `kvdb.commit` child."""
+    events = fed[mode]["events"]
+    commits = _commits(events)
+    assert len(commits) == BLOCKS
+    for writes in commits.values():
+        assert sorted(p for _, p in writes) == [
+            "blockstore.index", "ledger.history", "ledger.state"]
+        assert all(e[8]["ops"] > 0 for e, _ in writes)
+    n = sum(len(w) for w in commits.values())
+    assert len([e for e in events if e[1] == "kvdb.commit"]) >= n
+
+
+def test_a_blocks_frames_are_its_writes(fed):
+    """With the checkpoint behind, the committer writes nothing but WAL
+    frames, a header and a page each: the `frames` of a block's
+    `kvdb.write` spans are `commit.commit`'s `syscw` / 2, and the
+    tracer's own reads of `/proc` are not in its `syscr`."""
+    if tracing._thread_io() is None:
+        pytest.skip("the kernel keeps no per-thread I/O account here")
+    events = fed["behind"]["events"]
+    by_id = {e[3]: e for e in events}
+    for span_id, writes in _commits(events).items():
+        io = by_id[span_id][8]
+        frames = sum(e[8]["frames"] for e, _ in writes)
+        assert frames > 0
+        assert abs(frames - io["syscw"] / 2) <= 5, (frames, io)
+    # a block reads at most one: the process's first blocks may load
+    # code the path meets for the first time
+    reads = fed["behind"]["syscr"]
+    assert sum(r > 1 for r in reads) <= 2, reads
+
+
 @pytest.mark.parametrize("mode", ["behind", "inline"])
 def test_close_leaves_no_thread_and_no_wal(fed, mode):
     got = fed[mode]

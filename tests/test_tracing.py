@@ -414,6 +414,12 @@ INTAKE_TREE = {
     "ledger.state": "commit.commit",
     "commit.notify": "commit.commit",
 }
+# every commit of the ledger's store (`KVStore._writing`): one
+# `kvdb.write` under each span that commits a keyspace, each with its
+# `kvdb.commit`
+STORE_TREE = {"kvdb.write": {"blockstore.index", "ledger.history",
+                             "ledger.state"},
+              "kvdb.commit": {"kvdb.write"}}
 # Depth > 0: stage A verifies, scans and parses inside its own span
 PIPELINED_TREE = dict(INTAKE_TREE, **{
     "peer.verify_block": "commit.validate",
@@ -506,7 +512,8 @@ class TestBlockIntakeTree:
             # below the parent the registry names
             got = {name: set(parents) for name, parents in tree.items()
                    if name not in ("test.deliver", "runtime.gc")}
-            assert got == {k: {v} for k, v in want.items()}
+            assert got == dict({k: {v} for k, v in want.items()},
+                               **STORE_TREE)
 
     def test_spans_per_block_do_not_grow_with_its_transactions(
             self, monkeypatch):
@@ -517,7 +524,8 @@ class TestBlockIntakeTree:
             assert len(counts) == 1, counts
             return counts.pop()
         small, large = per_block(8), per_block(64)
-        assert small == large == len(INTAKE_TREE) + 1
+        commits = len(STORE_TREE["kvdb.write"])
+        assert small == large == len(INTAKE_TREE) + 1 + 2 * commits
 
     def test_disabled_intake_records_nothing(self, monkeypatch):
         assert _intake_run(monkeypatch, 8, enabled=False) == []
@@ -526,7 +534,8 @@ class TestBlockIntakeTree:
         """Both land in the same profiler trace: a shared name would
         put the program's span into the benchmark's own metrics."""
         from benchmark import tracered
-        names = set(INTAKE_TREE) | set(PROVIDER_SPANS) | {"runtime.gc"}
+        names = set(INTAKE_TREE) | set(STORE_TREE) | \
+            set(PROVIDER_SPANS) | {"runtime.gc"}
         assert not names & set(tracered.HOST_SPANS)
 
 

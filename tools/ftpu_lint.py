@@ -146,6 +146,9 @@ for _path, _funcs in {
     "fabric_tpu/peer/peer.py": ("process_block", "commit_validated"),
     "fabric_tpu/ledger/kvledger.py": ("commit_block",),
     "fabric_tpu/ledger/blkstorage.py": ("add_block",),
+    # every commit of a store (`kvdb.write` / `kvdb.commit`): what
+    # the ledger-commit metrics split into statements and WAL frames
+    "fabric_tpu/ledger/kvdb.py": ("_writing",),
     "fabric_tpu/core/fastvalidate.py": ("validate_fast",),
     "fabric_tpu/bccsp/tpu.py": ("_verify_prepared_device",
                                 "_dispatch_chunks", "_key_slots",
